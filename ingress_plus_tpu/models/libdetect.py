@@ -282,6 +282,14 @@ def _load_native():
 _NATIVE = _load_native()
 
 
+def twin() -> str:
+    """Which strict-grammar twin serves in this process: ``"native"``
+    (native/confirm/libiptdetect.so, a build product git does not
+    carry) or ``"python"`` — reported at /healthz so the quiet
+    fallback is visible."""
+    return "native" if _NATIVE is not None else "python"
+
+
 def detect_sqli(data: bytes, max_len: int = 4096) -> bool:
     """Strict-grammar SQLi check (native C++ when available)."""
     window = data[:max_len]  # only the scanned window matters for the guard

@@ -425,8 +425,8 @@ class DetectionPipeline:
         # entries — the batcher's hot-swap replay warms each lane's
         # device-bound executables too
         self.seen_lane_shapes: set = set()
-        # underlying executable shapes (per-(B, L) scan jits + the
-        # pow2-padded mapping jit, keyed per lane device — XLA
+        # underlying executable shapes (per-(B, L) scan, per-(B, Q)
+        # fold and per-Q expansion jits, keyed per lane device — XLA
         # executables are device-bound) — the recompile gauge's ground
         # truth
         self._seen_exec: set = set()
@@ -553,13 +553,77 @@ class DetectionPipeline:
         self.rule_stats.reset()
         self.stats.reset_efficiency()
 
+    def warm_signatures(self, max_batch: int) -> List[tuple]:
+        """``(buckets, Q_pad)`` dispatch signatures whose executables
+        cover every shape a cycle of up to ``max_batch`` requests can
+        dispatch — derived from shapes alone, never from what a sample
+        corpus happens to contain: one scan executable per (pow2 row
+        tier B x L tier), one fold executable per (B x Q-pad tier), one
+        expansion per Q-pad tier (DetectionEngine.detect_device_multi).
+        A request yields at most one scan row per needed (stream,
+        variant), which bounds the row tier a Q-pad tier can fill.
+        Feed each to ``warm_shape`` / ``warm_lane_shape`` (they add the
+        head-sliced twin)."""
+        rpr = max(1, sum(len(v) for v in self._variants_for.values()))
+        q_tiers = sorted({self._pad_q(n) for n in warm_sizes(max_batch)})
+        sigs: List[tuple] = []
+        B = 8
+        while B <= self._pad_q(max_batch * rpr, floor=8):
+            # the request tiers whose cycles can fill this row tier
+            qs = [q for q in q_tiers
+                  if self._pad_q(min(q, max_batch) * rpr, floor=8) >= B]
+            # every L tier once and every such Q tier once, in step
+            for i in range(max(len(self.L_BUCKETS), len(qs))):
+                sigs.append((
+                    ((B, self.L_BUCKETS[i % len(self.L_BUCKETS)]),),
+                    qs[i % len(qs)]))
+            B *= 2
+        return sigs
+
+    def warm_grid(self, max_batch: int, lanes: Sequence = ()) -> int:
+        """Compile and run once every executable of
+        :meth:`warm_signatures` — on the default device, or on each of
+        ``lanes``' devices (XLA executables are device-bound).  The
+        compiles are seconds each on a chip and there are hundreds, so
+        they fan out over a thread pool (XLA compiles outside the GIL);
+        a shape that fails to compile raises here and the caller does
+        not start.  Returns the number of dispatches made."""
+        import os
+        from concurrent.futures import ThreadPoolExecutor
+
+        sigs = self.warm_signatures(max_batch)
+        if lanes:
+            slicing = getattr(self.engine, "head_slicing_active", None)
+            heads = ((False, True) if slicing is not None and slicing()
+                     else (False,))
+
+            def jobs(sig):
+                return [(self.warm_lane_shape, (*sig, head, lane))
+                        for lane in lanes for head in heads]
+        else:
+            def jobs(sig):
+                return [(self.warm_shape, sig)]
+        # the first signature runs inline: it builds the engine's lazy
+        # per-device state (table replicas, scanners) before the pool
+        # could race to build it twice
+        first = jobs(sigs[0])
+        for fn, args in first:
+            fn(*args)
+        rest = [j for sig in sigs[1:] for j in jobs(sig)]
+        with ThreadPoolExecutor(
+                max_workers=max(1, min(32, os.cpu_count() or 1)),
+                thread_name_prefix="ipt-warm") as pool:
+            for fut in [pool.submit(fn, *args) for fn, args in rest]:
+                fut.result()
+        return len(first) + len(rest)
+
     def _count_new_executables(self, bucket_shapes, Q_pad: int,
                                head_ok: bool, fused: bool = True,
                                lane_key=None) -> int:
         """How many REAL jit executables a dispatch of this bucket set
         will compile fresh.  Fused engines (detect_device_multi): one
-        per unseen (B, L) scan shape plus one for an unseen (pow2-padded
-        total rows, Q) mapping shape.  Legacy per-bucket engines
+        per unseen (B, L) scan shape, one per unseen (B, Q) fold shape,
+        one for an unseen Q expansion.  Legacy per-bucket engines
         (MeshEngine): one per unseen (B, L, Q) fused executable — their
         programs key on the request pad too and have no separate
         mapping pass.  ``lane_key`` scopes the keys to one serve lane's
@@ -582,17 +646,15 @@ class DetectionPipeline:
         shape_fn = getattr(self.engine, "scan_exec_shape", None)
         for B, L in bucket_shapes:
             kb, kl = shape_fn(B, L) if shape_fn is not None else (B, L)
-            key = ("scan", kb, kl, head_ok, lane_key)
-            if key not in self._seen_exec:
-                new += 1
-                self._seen_exec.add(key)
-        from ingress_plus_tpu.models.engine import map_pad_total
-
-        total = sum(B for B, _ in bucket_shapes)
-        mkey = ("map", map_pad_total(total), Q_pad, head_ok, lane_key)
-        if mkey not in self._seen_exec:
+            for key in (("scan", kb, kl, head_ok, lane_key),
+                        ("fold", B, Q_pad, head_ok, lane_key)):
+                if key not in self._seen_exec:
+                    new += 1
+                    self._seen_exec.add(key)
+        ekey = ("expand", Q_pad, head_ok, lane_key)
+        if ekey not in self._seen_exec:
             new += 1
-            self._seen_exec.add(mkey)
+            self._seen_exec.add(ekey)
         return new
 
     def warm_lane_shape(self, buckets, Q_pad: int, head_ok: bool,
@@ -1084,9 +1146,9 @@ class DetectionPipeline:
             multi = getattr(self.engine, "detect_device_multi", None)
             shape = (bucket_shapes, self._pad_q(Q), head_ok)
             # recompile gauge counts REAL executables, not bucket-set
-            # signatures: one per unseen (B, L) scan shape plus one for
-            # an unseen mapping shape (total rows pow2-padded x Q) — a
-            # novel combination of already-warm executables is free
+            # signatures: per unseen (B, L) scan / (B, Q) fold shape
+            # plus an unseen Q expansion — a novel combination of
+            # already-warm executables is free
             stats.engine_compiles += self._count_new_executables(
                 bucket_shapes, self._pad_q(Q), head_ok,
                 fused=multi is not None)

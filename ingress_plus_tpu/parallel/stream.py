@@ -25,8 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ingress_plus_tpu.parallel._compat import shard_map
 
 from ingress_plus_tpu.ops.scan import ScanTables, scan_bytes
 

@@ -468,14 +468,12 @@ class Batcher:
         # PR 4 (the pool's primary breaker IS self.breaker); n_lanes > 1
         # shards each cycle across per-chip lanes behind this one
         # admission queue.  lane_devices defaults to the local jax
-        # devices when the pool is actually multi-lane.
+        # devices when the pool is actually multi-lane; too few of them
+        # is an error (LanePool), never a silent pile-up on one chip.
         if n_lanes > 1 and lane_devices is None:
-            try:
-                import jax
+            import jax
 
-                lane_devices = jax.devices()
-            except Exception:
-                lane_devices = None
+            lane_devices = jax.devices()
         self.lanes = LanePool(n_lanes=n_lanes, devices=lane_devices,
                               failure_threshold=breaker_failures,
                               cooldown_s=breaker_cooldown_s)
@@ -1914,58 +1912,46 @@ class Batcher:
         live on a real chip" is one probe, not a checkpoint read."""
         import jax
 
+        from ingress_plus_tpu.utils.platform import device_block
+
         eng = self.pipeline.engine
         impl = getattr(eng, "scan_impl", "?")
+        dev = device_block()
+        bakeoff = getattr(eng, "bakeoff", None)
+        if bakeoff is not None:   # inf (no timing signal) is not JSON
+            bakeoff = {k: (v if v != float("inf") else None)
+                       for k, v in bakeoff.items()}
         return {
             "scan_impl": impl,
             "scan_contract": ("raw-bytes" if impl == "pallas3"
                               else "prepped-rows"),
+            # {impl: seconds} of the start-up bake-off; None when
+            # --scan-impl named the implementation
+            "bakeoff": bakeoff,
             "backend": jax.default_backend(),
+            "device_kind": dev["device_kind"],
+            "device_count": dev["device_count"],
             "lane_devices": [
                 str(lane.device) if lane.device is not None
                 else "default" for lane in self.lanes.lanes],
         }
 
     def warm_lanes(self, max_batch: Optional[int] = None) -> None:
-        """Pre-compile every per-lane executable an all-healthy mesh
-        dispatch can hit (the mesh twin of server.warmup_pipeline):
-        every lane warms EVERY Q-pad tier up to max_batch (not just its
-        1/N share of an all-healthy split — when siblings die, the
-        rebalanced shares grow toward max_batch, and a serve-time
-        compile past the hang budget would read as a HANG and trip the
-        recovering lane's breaker; observed on the first cut of this
-        path).  Each tier dispatches on all lanes CONCURRENTLY —
-        detect_launch is async on each lane's own worker, so an 8-lane
-        start pays ONE overlapped compile pass per tier, not 8 serial
-        full-corpus warmups, and each device-bound executable compiles
-        exactly once (the recompile gauge keys on (lane, shape), so
-        serve-time recompiles stay 0 — asserted in the e2e test).
-        Head-sliced twins (docs/SCAN_KERNEL.md) are warmed by a
-        bodyless pass when the pack is word-tiered."""
-        from ingress_plus_tpu.utils.corpus import generate_corpus
-
-        import dataclasses
-
-        if max_batch is None:
-            max_batch = self.max_batch
-        reqs = [lr.request for lr in generate_corpus(n=max_batch, seed=1)]
-        variants = [reqs]
-        slicing = getattr(self.pipeline.engine, "head_slicing_active",
-                          None)
-        if slicing is not None and slicing():
-            variants.append([dataclasses.replace(r, body=b"")
-                             for r in reqs])
-        from ingress_plus_tpu.models.pipeline import warm_sizes
-
-        for corpus in variants:
-            for size in warm_sizes(max_batch):
-                jobs = []
-                with self._swap_lock:
-                    for lane in self.lanes.lanes:
-                        jobs.append((lane, self.pipeline.detect_launch(
-                            corpus[:size], lane=lane)))
-                    for _lane, job in jobs:
-                        self.pipeline.detect_collect(job, timeout=None)
+        """Pre-compile every per-lane executable a mesh dispatch can
+        hit (the mesh twin of server.warmup_pipeline): every lane warms
+        the WHOLE shape grid up to max_batch (not just its 1/N share of
+        an all-healthy split — when siblings die, the rebalanced shares
+        grow toward max_batch, and a serve-time compile past the hang
+        budget would read as a HANG and trip the recovering lane's
+        breaker).  The compiles overlap across lanes and shapes
+        (DetectionPipeline.warm_grid), each device-bound executable
+        compiles exactly once (the recompile gauge keys on (lane,
+        shape), so serve-time recompiles stay 0 — asserted in the e2e
+        test), and a shape that fails to compile raises: the server
+        does not start."""
+        self.pipeline.warm_grid(
+            self.max_batch if max_batch is None else max_batch,
+            lanes=self.lanes.lanes)
         # warmup traffic must not pollute the detection telemetry
         # (under the swap lock, like reset_latency_observations)
         with self._swap_lock:

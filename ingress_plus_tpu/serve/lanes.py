@@ -312,24 +312,26 @@ class LanePool:
     """N per-device lanes behind one admission queue
     (docs/MESH_SERVING.md).  ``devices`` are the jax devices of the
     ``("batch",)`` serve mesh — one lane each, sigpack tables replicated
-    per device by the engine (``DetectionEngine.tables_for``).  With
-    ``devices=None`` (or a single lane) every lane dispatches to the
-    default device — the machinery still isolates faults, only the
-    physical parallelism is absent."""
+    per device by the engine (``DetectionEngine.tables_for``).  A single
+    lane may ride the default device (``devices=None``); more than one
+    lane needs a distinct device each — lanes sharing a chip would
+    serialize on it while reporting N-way capacity."""
 
     def __init__(self, n_lanes: int = 1,
                  devices: Optional[Sequence[Any]] = None,
                  failure_threshold: int = 3, cooldown_s: float = 5.0):
         if n_lanes < 1:
             raise ValueError("n_lanes must be >= 1, got %d" % n_lanes)
-        self.lanes: List[Lane] = []
-        for i in range(n_lanes):
-            dev = None
-            if devices:
-                dev = devices[i % len(devices)]
-            self.lanes.append(Lane(i, device=dev,
-                                   failure_threshold=failure_threshold,
-                                   cooldown_s=cooldown_s))
+        devices = list(devices or ())
+        if n_lanes > 1 and len(set(devices[:n_lanes])) < n_lanes:
+            raise ValueError(
+                "%d lanes need %d distinct devices, got %s"
+                % (n_lanes, n_lanes, [str(d) for d in devices] or "none"))
+        self.lanes: List[Lane] = [
+            Lane(i, device=devices[i] if devices else None,
+                 failure_threshold=failure_threshold,
+                 cooldown_s=cooldown_s)
+            for i in range(n_lanes)]
 
     @property
     def n(self) -> int:

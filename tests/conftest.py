@@ -2,8 +2,7 @@
 
 This is the kind-cluster analog from SURVEY.md §4: multi-chip sharding
 logic is exercised on a virtual 8-device CPU mesh so CI needs no TPU.
-The platform-forcing recipe (and why env vars alone don't work on this
-machine) lives in ingress_plus_tpu/utils/platform.py.
+The platform-forcing recipe lives in ingress_plus_tpu/utils/platform.py.
 """
 
 import os

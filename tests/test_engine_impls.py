@@ -50,6 +50,60 @@ def test_autoselect_installs_fastest(ruleset):
     assert all(t > 0 for t in timings.values())
 
 
+def test_autoselect_candidate_that_raises_propagates(ruleset, monkeypatch):
+    """A kernel the backend refuses stops the bake-off (and with it the
+    server's start-up): it is never scored out of the race so that
+    `pair` serves in silence."""
+    import pytest
+
+    from ingress_plus_tpu.ops import pallas_scan
+
+    class Refused(pallas_scan.PallasScanner):
+        def __call__(self, *a, **kw):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pallas_scan, "PallasScanner", Refused)
+    eng = DetectionEngine(ruleset)
+    eng.pallas_interpret = True
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        eng.autoselect_scan_impl(B=8, L=64, k=2, n=1, include_pallas=True)
+    assert eng.bakeoff is None
+
+
+def test_server_startup_fails_on_a_refused_kernel(tmp_path, monkeypatch):
+    """--scan-impl auto on a backend that runs the kernels: a candidate
+    raising at compile fails build_default_batcher instead of serving
+    `pair`."""
+    import pytest
+
+    from ingress_plus_tpu.ops import pallas_scan
+    from ingress_plus_tpu.serve import server
+    from ingress_plus_tpu.utils import platform
+
+    (tmp_path / "tiny.conf").write_text(
+        'SecRule ARGS "@rx (?i)union\\s+select" "id:1,phase:2,block,'
+        "severity:CRITICAL,tag:'attack-sqli'\"\n")
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pallas_scan, "_pallas_scan", refused)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        server.build_default_batcher(rules_dir=str(tmp_path),
+                                     warmup=False, scan_impl="auto")
+
+
+def test_autoselect_placeable_only_skips_unplaceable_kernels(ruleset):
+    eng = DetectionEngine(ruleset)
+    eng.pallas_interpret = True
+    timings = eng.autoselect_scan_impl(B=8, L=64, k=2, n=1,
+                                       include_pallas=True,
+                                       placeable_only=True)
+    assert set(timings) == {"pair", "take", "pallas3"}
+    assert eng.bakeoff == timings
+
+
 def test_scan_impl_survives_hot_swap(ruleset):
     from ingress_plus_tpu.serve.batcher import Batcher
 

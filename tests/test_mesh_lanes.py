@@ -87,8 +87,28 @@ def _mk(cr, n_lanes, **kw):
 
 # ------------------------------------------------------------- units
 
+def test_lane_pool_needs_a_distinct_device_per_lane():
+    """More than one lane with no distinct device each is a start-up
+    error — never N lanes quietly piled on the first chip."""
+    import jax
+
+    devs = jax.devices()
+    with pytest.raises(ValueError, match="distinct devices"):
+        LanePool(n_lanes=3)                        # no devices at all
+    with pytest.raises(ValueError, match="distinct devices"):
+        LanePool(n_lanes=3, devices=devs[:2])      # lanes outnumber chips
+    with pytest.raises(ValueError, match="distinct devices"):
+        LanePool(n_lanes=2, devices=[devs[0], devs[0]])
+    LanePool(n_lanes=1).close()                    # default device: fine
+    pool = LanePool(n_lanes=3, devices=devs)
+    assert [ln.device for ln in pool.lanes] == list(devs[:3])
+    pool.close()
+
+
 def test_lane_pool_split_balances_by_weight_and_caps_canary():
-    pool = LanePool(n_lanes=3)
+    import jax
+
+    pool = LanePool(n_lanes=3, devices=jax.devices())
     targets = [(pool.lane(0), "device"), (pool.lane(1), "device"),
                (pool.lane(2), "canary")]
     items = list(range(30))

@@ -517,55 +517,39 @@ SecRule ARGS "@rx (?:;|\\|)\\s*(?:cat|ls|id)\\b" "id:5,phase:2,block,severity:ER
 """
 
 
-def _devicegate_batches(n_batches: int = 3, n_rows: int = 13):
-    """Deterministic ragged batches: random printable rows with planted
-    payloads at varying offsets, empty rows, and odd lengths."""
-    import numpy as np
-
-    from ingress_plus_tpu.ops.scan import pad_rows
-
-    attacks = [b"1 union  select password from users",
-               b"<script>alert(1)</script>", b"../../etc/passwd",
-               b"; cat /etc/hosts", b"sleep(5) or benchmark(9,1)"]
-    batches = []
-    for seed in range(n_batches):
-        rng = np.random.default_rng(seed)
-        rows = []
-        for i in range(n_rows):
-            body = bytes(rng.integers(
-                32, 127, size=int(rng.integers(0, 300))))
-            if i % 3 == 0 and body:
-                a = attacks[(seed + i) % len(attacks)]
-                pos = int(rng.integers(0, max(1, len(body) - len(a))))
-                body = body[:pos] + a + body[pos + len(a):]
-            rows.append(body)
-        tokens, lengths = pad_rows(rows, round_to=64)
-        batches.append((seed, tokens, lengths))
-    return batches
-
-
 def run_devicegate(write_report: bool) -> dict:
     """Pallas device-path parity gate (ISSUE 13): interpret-mode
-    kernels — the code path the JAX_PLATFORMS!=cpu lowering compiles —
-    vs the ops/scan.py XLA reference, bit-identical match words over
-    seeded ragged batches, on both the compact fixture pack and the
-    bundled pack's real multi-tile geometry.  Writes
-    reports/DEVICEGATE.json; any divergence fails the build."""
+    kernels — the code path the TPU lowering compiles — vs the
+    ops/scan.py XLA reference, bit-identical match words over seeded
+    ragged batches (ops/parity.py, the comparison chip_smoke.py runs
+    compiled on the chip): the three kernels at small tilings on the
+    compact fixture pack, then every member of SCAN_IMPLS through the
+    engine's own dispatch at the bundled pack's real multi-tile
+    geometry.  Writes reports/DEVICEGATE.json; any divergence fails
+    the build."""
     t0 = time.time()
-    from ingress_plus_tpu.utils.platform import force_cpu_devices
+    from ingress_plus_tpu.utils.platform import (
+        device_block,
+        force_cpu_devices,
+    )
 
     force_cpu_devices(1)
-    import numpy as np
-
     from ingress_plus_tpu.compiler.ruleset import compile_ruleset
     from ingress_plus_tpu.compiler.seclang import parse_seclang
     from ingress_plus_tpu.compiler.sigpack import load_bundled_rules
+    from ingress_plus_tpu.models.engine import DetectionEngine
+    from ingress_plus_tpu.ops.parity import (
+        compare_scan,
+        engine_parity,
+        failed,
+        ragged_batch,
+    )
     from ingress_plus_tpu.ops.pallas_scan import (
         PallasByteScanner,
         PallasPairScanner,
         PallasScanner,
     )
-    from ingress_plus_tpu.ops.scan import ScanTables, scan_bytes
+    from ingress_plus_tpu.ops.scan import ScanTables
 
     tables = ScanTables.from_bitap(
         compile_ruleset(parse_seclang(_DEVICEGATE_RULES)).tables)
@@ -575,62 +559,50 @@ def run_devicegate(write_report: bool) -> dict:
         "pallas3": PallasByteScanner(tables, TB=8, CL=16, MR=8),
     }
     cases = []
-    for seed, tokens, lengths in _devicegate_batches():
-        want_m, want_s = scan_bytes(tables, tokens, lengths)
-        want_m = np.asarray(want_m)
+    for seed, (B, L) in enumerate(((13, 320), (13, 192), (5, 64))):
+        tokens, lengths = ragged_batch(B, L, seed)
         for name, sc in kernels.items():
-            got_m, got_s = sc(tokens, lengths, interpret=True)
-            case = {
-                "pack": "fixture", "kernel": name, "seed": seed,
-                "B": int(tokens.shape[0]), "L": int(tokens.shape[1]),
-                "match_equal": bool(
-                    np.array_equal(np.asarray(got_m), want_m)),
-            }
-            if name == "pallas":
+            case = compare_scan(
+                lambda t, ln, sc=sc: sc(t, ln, interpret=True),
+                tables, tokens, lengths,
                 # the byte kernel preserves the full scan_bytes state
                 # contract; the pair kernels' dead-padding state is a
                 # documented difference (only match is consumed)
-                case["state_equal"] = bool(np.array_equal(
-                    np.asarray(got_s), np.asarray(want_s)))
+                check_state=name == "pallas")
+            case.update(pack="fixture", impl=name, seed=seed)
             cases.append(case)
     # bundled-pack geometry: the real serving width (multi-tile Wp,
-    # K1p padding) through the raw-byte kernel — the shapes a first
-    # TPU run would compile
+    # K1p padding) — the shapes and scanners the chip compiles
     cr = compile_ruleset(load_bundled_rules())
-    bt = ScanTables.from_bitap(cr.tables)
-    rng = np.random.default_rng(7)
-    toks = rng.integers(32, 127, (8, 128)).astype(np.uint8)
-    atk = b"1' union select password from users -- "
-    toks[0, :len(atk)] = np.frombuffer(atk, np.uint8)
-    lens = np.asarray([128, 37, 0, 128, 5, 64, 127, 128], np.int32)
-    want_m = np.asarray(scan_bytes(bt, toks, lens)[0])
-    got_m, _ = PallasByteScanner(bt)(toks, lens, interpret=True)
-    cases.append({
-        "pack": "bundled (%d rules, %d words)" % (cr.n_rules,
-                                                  bt.n_words),
-        "kernel": "pallas3", "seed": 7, "B": 8, "L": 128,
-        "match_equal": bool(np.array_equal(np.asarray(got_m), want_m)),
-        "non_vacuous": bool(want_m[0].any()),
-    })
-    bad = [c for c in cases
-           if not c["match_equal"] or c.get("state_equal") is False]
+    pack = "bundled (%d rules, %d words)" % (cr.n_rules, cr.tables.n_words)
+    for impl_cases in engine_parity(DetectionEngine(cr), [(8, 128)],
+                                    interpret=True).values():
+        for case in impl_cases:
+            case.update(pack=pack, seed=7)
+            cases.append(case)
+    bad = failed(cases) + [c for c in cases if not c["non_vacuous"]]
+    dev = device_block()
     report = {
         "passed": not bad,
+        **dev,
         "cases": cases,
         "divergent": bad,
         "note": "interpret mode executes the same Mosaic kernel "
-                "program the TPU lowering compiles — this gate is the "
-                "CI-run exercise of the JAX_PLATFORMS!=cpu code path",
+                "program the TPU lowering compiles; chip_smoke.py runs "
+                "this comparison compiled, on the chip",
     }
     result = {
         "status": "OK" if not bad else "FAIL",
         "seconds": round(time.time() - t0, 2),
         "cases": len(cases),
-        "detail": "; ".join(
-            "%s/%s seed %s DIVERGED" % (c["pack"], c["kernel"],
+        **dev,
+        "detail": ("; ".join(
+            "%s/%s seed %s DIVERGED" % (c["pack"], c["impl"],
                                         c["seed"]) for c in bad) or
             "%d interpret-vs-reference cases bit-identical (incl. "
-            "bundled-pack geometry)" % len(cases),
+            "bundled-pack geometry)" % len(cases))
+        + " [platform=%(platform)s device_kind=%(device_kind)s "
+          "device_count=%(device_count)d]" % dev,
     }
     if write_report:
         out = REPO / "reports" / "DEVICEGATE.json"
