@@ -96,7 +96,7 @@ def render_latency(metrics_text: str, slow: dict,
     """Terminal tables for `dbg latency` (separated from main so tests
     can drive it on real endpoint output without a TTY)."""
     from ingress_plus_tpu.utils.trace import (
-        STAGES, stage_breakdown_from_metrics)
+        STAGES, SUBSTAGES, stage_breakdown_from_metrics)
 
     lines = []
     sb = stage_breakdown_from_metrics(metrics_text)
@@ -104,13 +104,16 @@ def render_latency(metrics_text: str, slow: dict,
         lines.append("stage histograms: MISSING or malformed in /metrics"
                      " (server predates the latency-attribution layer?)")
     else:
-        lines.append("%-8s %10s %12s %12s %12s"
+        lines.append("%-12s %10s %12s %12s %12s"
                      % ("stage", "count", "p50_us", "p90_us", "p99_us"))
+        # the stages decompose a request's latency; the sub-stages lie
+        # INSIDE scan and confirm (or beside the cycle) and follow them
         order = [s for s in STAGES if s in sb] \
-            + sorted(set(sb) - set(STAGES))
+            + [s for s in SUBSTAGES if s in sb] \
+            + sorted(set(sb) - set(STAGES) - set(SUBSTAGES))
         for stage in order:
             e = sb[stage]
-            lines.append("%-8s %10d %12.1f %12.1f %12.1f"
+            lines.append("%-12s %10d %12.1f %12.1f %12.1f"
                          % (stage, e["count"], e["p50_us"], e["p90_us"],
                             e["p99_us"]))
     ex = slow.get("slowest", [])
@@ -156,7 +159,7 @@ def render_timeline(trace: dict, max_cycles: int = 6,
     """Terminal Gantt for `dbg timeline` (ISSUE 12): per cycle, one bar
     row per recorded span — thread, span name, duration, and its
     position inside the cycle's window, so the cross-thread overlap
-    structure (device busy vs confirm shares vs the next drain) is
+    structure (scan dispatch vs confirm walks vs the next drain) is
     visible without leaving the terminal.  Input is the /debug/trace
     Chrome-trace JSON (the same bytes Perfetto loads)."""
     events = trace.get("traceEvents", [])
@@ -199,10 +202,10 @@ def render_timeline(trace: dict, max_cycles: int = 6,
             bar += "." * (width - len(bar))
             tag = s.get("args", {}).get("tag", 0)
             label = s["name"]
-            if s["name"] in ("lane_launch", "device_busy",
+            if s["name"] in ("lane_launch", "scan_dispatch",
                              "lane_collect"):
                 label += "[%s]" % tag
-            elif s["name"] == "confirm_share":
+            elif s["name"] == "confirm_walk":
                 label += "[w%s]" % tag
             lines.append("  %-22s %-16s %9dus |%s|"
                          % (tname, label, int(s["dur"]), bar))

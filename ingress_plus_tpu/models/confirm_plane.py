@@ -379,6 +379,8 @@ class _ConfirmWorker(LaneWorker):
     (submit/wait/abandon) with confirm-plane fault attribution —
     ``slow_confirm:worker=K`` plans target exactly one of these."""
 
+    HANDOFF_SPANS = False    # lane_handoff is the device lanes' span
+
     def __init__(self, seq: int, worker_index: int):
         self.worker_index = worker_index
         super().__init__(seq=seq, lane_index=None, name="ipt-confirm")
@@ -496,20 +498,20 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
         # worker id 0 stamped around the inline walk so worker-targeted
         # fault plans behave identically at --confirm-workers 1
         faults.set_current_confirm_worker(0)
-        flight.begin(EV_CONFIRM, cycle=trace_cycle, tag=0,
-                     arg=len(requests))
         try:
-            faults.sleep_if("slow_confirm")
-            for qi, req in enumerate(requests):
-                if tt:
-                    faults.set_current_tenant(req.tenant)
-                    faults.sleep_if("slow_confirm")
-                job.results[qi] = confirm_one(pl, req, rule_hits[qi], memo)
+            with flight.span(EV_CONFIRM, cycle=trace_cycle, tag=0,
+                             arg=len(requests)):
+                faults.sleep_if("slow_confirm")
+                for qi, req in enumerate(requests):
+                    if tt:
+                        faults.set_current_tenant(req.tenant)
+                        faults.sleep_if("slow_confirm")
+                    job.results[qi] = confirm_one(pl, req, rule_hits[qi],
+                                                  memo)
         finally:
             if tt:
                 faults.set_current_tenant(None)
             faults.set_current_confirm_worker(None)
-            flight.end(EV_CONFIRM, cycle=trace_cycle, tag=0)
     else:
         n = pool.n_workers
         for wi in range(n):
@@ -519,21 +521,21 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
 
             def _share(idxs=idxs, tt=tt, wi=wi):
                 flight.set_cycle(trace_cycle)
-                flight.begin(EV_CONFIRM, cycle=trace_cycle, tag=wi,
-                             arg=len(idxs))
-                faults.sleep_if("slow_confirm")
                 out = []
                 try:
-                    for i in idxs:
-                        if tt:
-                            faults.set_current_tenant(requests[i].tenant)
-                            faults.sleep_if("slow_confirm")
-                        out.append((i, confirm_one(pl, requests[i],
-                                                   rule_hits[i], memo)))
+                    with flight.span(EV_CONFIRM, cycle=trace_cycle,
+                                     tag=wi, arg=len(idxs)):
+                        faults.sleep_if("slow_confirm")
+                        for i in idxs:
+                            if tt:
+                                faults.set_current_tenant(
+                                    requests[i].tenant)
+                                faults.sleep_if("slow_confirm")
+                            out.append((i, confirm_one(
+                                pl, requests[i], rule_hits[i], memo)))
                 finally:
                     if tt:
                         faults.set_current_tenant(None)
-                    flight.end(EV_CONFIRM, cycle=trace_cycle, tag=wi)
                 return out
 
             job.pending.append((wi, idxs, pool.submit(wi, _share)))

@@ -39,6 +39,7 @@ from ingress_plus_tpu.ops.scan import (
     scan_pairs_jit,
 )
 from ingress_plus_tpu.utils import faults
+from ingress_plus_tpu.utils.trace import EV_SCAN_LAUNCH, flight, named_lock
 
 
 @jax.tree_util.register_pytree_node_class
@@ -336,6 +337,10 @@ class DetectionEngine:
         # sigpack rides to each serve lane's chip ONCE, at first use —
         # {device: (tables, head_tables|None)}
         self._device_tables: dict = {}
+        #: device programs enqueued by detect_device_multi
+        #: (ipt_device_launches_total); carried across hot swaps
+        self.device_launches = 0
+        self._launch_lock = named_lock("DetectionEngine._launch_lock")
 
     def rebuilt(self, cr: CompiledRuleset) -> "DetectionEngine":
         """Fresh engine of the SAME kind on a new ruleset — the batcher
@@ -345,6 +350,7 @@ class DetectionEngine:
         eng = type(self)(cr, scan_impl=self.scan_impl)
         eng.pallas_interpret = self.pallas_interpret
         eng.bakeoff = self.bakeoff
+        eng.device_launches = self.device_launches
         return eng
 
     def device_info(self) -> dict:
@@ -547,6 +553,16 @@ class DetectionEngine:
         scanner replicas (NamedSharding placement)."""
         faults.sleep_if("dispatch_hang")
         faults.raise_if("dispatch_raise")
+        # scan_launch: the host→device transfers and the enqueue of
+        # every program, to the return of the last one (nothing blocks)
+        with flight.span(EV_SCAN_LAUNCH, arg=len(buckets)):
+            return self._enqueue_multi(buckets, num_requests, head_only,
+                                       device)
+
+    def _enqueue_multi(self, buckets, num_requests: int, head_only: bool,
+                       device):
+        """The body of :meth:`detect_device_multi` (its ``scan_launch``
+        span): transfers and program launches, counted as they go."""
         pallas = self.scan_impl in ("pallas", "pallas2", "pallas3")
         # pallas3 is device-aware: its packed tables replicate per chip
         # like the sigpack, so mesh lanes keep the raw-byte path
@@ -560,6 +576,7 @@ class DetectionEngine:
                 and not pallas else full_tabs)
         if not buckets:
             R = self.ruleset.n_rules
+            self._count_launches(2)
             return jnp.zeros((num_requests, max(R, 1)), bool)
 
         def _dev(x):
@@ -578,7 +595,19 @@ class DetectionEngine:
             req_fh, req_sv = fold_rows_jit(
                 tabs, m, _dev(np.asarray(rr)), _dev(np.asarray(rs)),
                 req_fh, req_sv)
+        # programs enqueued: each jnp.zeros is two (the scalar's dtype
+        # conversion and its broadcast: jit_convert_element_type and
+        # jit_broadcast_in_dim in a device trace), each bucket a scan
+        # and a fold, and the one expansion below
+        self._count_launches(2 * 2 + 2 * len(buckets) + 1)
         return expand_requests_jit(tabs, req_fh, req_sv)[0]
+
+    def _count_launches(self, n: int) -> None:
+        """``ipt_device_launches_total``: device programs enqueued by
+        :meth:`detect_device_multi` (lane workers call it concurrently
+        in mesh serving, hence the lock)."""
+        with self._launch_lock:
+            self.device_launches += n
 
     # ------------------------------------------------- impl auto-select
 

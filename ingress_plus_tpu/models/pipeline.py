@@ -41,9 +41,12 @@ from ingress_plus_tpu.models.engine import DetectionEngine
 from ingress_plus_tpu.models.rule_stats import RuleStats
 from ingress_plus_tpu.utils import faults
 from ingress_plus_tpu.utils.trace import (
+    EV_CONFIRM_FOLD,
     EV_DEVICE,
     EV_FINALIZE,
     EV_PREP,
+    EV_SCAN_PACK,
+    EV_SCAN_WAIT,
     Ewma,
     flight,
     named_lock,
@@ -96,6 +99,10 @@ class Verdict:
     #: satellite: /debug/slow names the worker): 0 = the inline serial
     #: walk, -1 = no confirm ran (fail-open, prefilter-only, streams)
     confirm_worker: int = -1
+    #: ``time.monotonic_ns()`` when the batcher resolved this verdict's
+    #: future (0 = flight recorder off): the start of the ``reply`` span
+    #: that the event loop closes when the frame is written
+    resolved_ns: int = 0
 
 
 @dataclass
@@ -874,25 +881,28 @@ class DetectionPipeline:
         def _dispatch():
             tb0 = time.perf_counter()
             flight.set_cycle(trace_cycle)
-            flight.begin(EV_DEVICE, cycle=trace_cycle, tag=trace_lane,
-                         arg=len(requests))
             try:
-                if multi is not None:
-                    return np.asarray(multi(
-                        tuple(buckets), Q_pad, head_only=head_ok,
-                        device=device))
-                acc = None
-                for tok, lens, rreq, rsv in buckets:
-                    rh = np.asarray(engine.detect_device(
-                        tok, lens, rreq, rsv, Q_pad))
-                    acc = rh if acc is None else np.logical_or(acc, rh)
-                return acc
+                with flight.span(EV_DEVICE, tag=trace_lane,
+                                 arg=len(requests)):
+                    if multi is not None:
+                        # the launch (its own span, in the engine)
+                        # returns without blocking; the wait is here
+                        rh_dev = multi(tuple(buckets), Q_pad,
+                                       head_only=head_ok, device=device)
+                        with flight.span(EV_SCAN_WAIT, tag=trace_lane):
+                            return np.asarray(rh_dev)
+                    acc = None
+                    for tok, lens, rreq, rsv in buckets:
+                        rh = np.asarray(engine.detect_device(
+                            tok, lens, rreq, rsv, Q_pad))
+                        acc = rh if acc is None else np.logical_or(acc, rh)
+                    return acc
             finally:
-                # device busy time measured INSIDE the worker: the
-                # overlap design means launch→collect wall includes a
-                # whole drain window — that must not book as scan time
+                # launch + wait on the host's clock, measured INSIDE
+                # the worker: the overlap design means launch→collect
+                # wall includes a whole drain window — that must not
+                # book as scan time
                 job.busy_us = int((time.perf_counter() - tb0) * 1e6)
-                flight.end(EV_DEVICE, cycle=trace_cycle, tag=trace_lane)
 
         if lane is not None:
             job.pending = lane.submit(_dispatch)
@@ -1034,34 +1044,50 @@ class DetectionPipeline:
         (``bucket_us``) rides the scan stage — the caller adds it to
         engine_us (docs/OBSERVABILITY.md)."""
         tp0 = time.perf_counter()
-        flight.begin(EV_PREP)
-        if faults.fire("recompile_storm"):
-            # injected executable loss: forget every warm shape and drop
-            # the compiled programs — the following dispatches pay
-            # serve-time compiles (ipt_engine_recompiles_total)
-            self.seen_shapes.clear()
-            self.seen_lane_shapes.clear()
-            self._seen_exec.clear()
-            self.engine.drop_compiled()
-        # one-pass normalize+merge (ISSUE 13 host-prep offload): shared
-        # decode intermediates + identity-first dedup, byte-identical
-        # to merge_rows(rows_for_requests(...)) — pinned by test
-        data_list, req_list, sv_list = merged_rows_for_requests(
-            requests, variants_for=self._variants_for)
-        Q = len(requests)
-        # MeasuredProfile byte axis (docs/RETUNE.md): fold the scanned
-        # bytes into the sampled histogram — budgeted, so this is a
-        # no-op once a few MiB of traffic shape have been observed
-        self.rule_stats.observe_bytes(data_list)
+        with flight.span(EV_PREP, arg=len(requests)):
+            if faults.fire("recompile_storm"):
+                # injected executable loss: forget every warm shape and
+                # drop the compiled programs — the following dispatches
+                # pay serve-time compiles (ipt_engine_recompiles_total)
+                self.seen_shapes.clear()
+                self.seen_lane_shapes.clear()
+                self._seen_exec.clear()
+                self.engine.drop_compiled()
+            # one-pass normalize+merge (ISSUE 13 host-prep offload):
+            # shared decode intermediates + identity-first dedup, byte-
+            # identical to merge_rows(rows_for_requests(...)) — pinned
+            # by test
+            data_list, req_list, sv_list = merged_rows_for_requests(
+                requests, variants_for=self._variants_for)
+            Q = len(requests)
+            # MeasuredProfile byte axis (docs/RETUNE.md): fold the
+            # scanned bytes into the sampled histogram — budgeted, so
+            # this is a no-op once a few MiB of traffic shape have been
+            # observed
+            self.rule_stats.observe_bytes(data_list)
         stats = self.stats
         # stage attribution: everything up to here is host prep (the
-        # per-bucket pad/pack below is interleaved with async dispatch
-        # and rides the scan stage — documented in docs/OBSERVABILITY.md)
+        # per-bucket pad/pack below rides the scan stage — documented
+        # in docs/OBSERVABILITY.md)
         stats.prep_us += int((time.perf_counter() - tp0) * 1e6)
-        flight.end(EV_PREP, arg=len(requests))
         if not data_list:
             return [], (), False, 0, 0, 0
         te0 = time.perf_counter()
+        with flight.span(EV_SCAN_PACK, arg=len(data_list)):
+            buckets, head_ok, live_rows, padded_rows = self._pack_buckets(
+                data_list, req_list, sv_list, Q)
+        bucket_shapes = tuple((b[0].shape[0], b[0].shape[1])
+                              for b in buckets)
+        bucket_us = int((time.perf_counter() - te0) * 1e6)
+        return (buckets, bucket_shapes, head_ok, bucket_us,
+                live_rows, padded_rows)
+
+    def _pack_buckets(self, data_list, req_list, sv_list, Q: int):
+        """Pad/pack the merged rows into L-tier buckets (the
+        ``scan_pack`` span of :meth:`_build_scan_buckets`) and book the
+        device-efficiency accounting.  Returns ``(buckets, head_ok,
+        live_rows, padded_rows)``."""
+        stats = self.stats
         n_sv = len(STREAMS) * len(VARIANTS)
         # Shape stability: jit caches one executable per bucket-set
         # signature, so rows bucket into fixed L tiers, row counts
@@ -1110,11 +1136,7 @@ class DetectionPipeline:
                 stats.bucket_padded_rows.get(L, 0) + B_pad
             live_rows += len(idxs)
             padded_rows += B_pad
-        bucket_shapes = tuple((b[0].shape[0], b[0].shape[1])
-                              for b in buckets)
-        bucket_us = int((time.perf_counter() - te0) * 1e6)
-        return (buckets, bucket_shapes, head_ok, bucket_us,
-                live_rows, padded_rows)
+        return buckets, head_ok, live_rows, padded_rows
 
     def prefilter(self, requests: List[Request]) -> np.ndarray:
         """Scan stage: requests → masked (Q, R) prefilter rule hits.
@@ -1136,7 +1158,6 @@ class DetectionPipeline:
             # host thread with no lane (warmup, library callers)
             _lane = faults.current_lane()
             _ltag = _lane if _lane is not None else -1
-            flight.begin(EV_DEVICE, tag=_ltag, arg=Q)
             # Single-mapping dispatch (docs/SCAN_KERNEL.md): each bucket
             # scans in its own jit program, the rule-count-scaling
             # factor→rule mapping runs once per batch.  Engines that
@@ -1153,21 +1174,24 @@ class DetectionPipeline:
                 bucket_shapes, self._pad_q(Q), head_ok,
                 fused=multi is not None)
             self.seen_shapes.add(shape)
-            if multi is not None:
-                rh_dev = multi(tuple(buckets), self._pad_q(Q),
-                               head_only=head_ok)
-                rule_hits |= np.asarray(rh_dev)
-            else:
-                # legacy engine: per-bucket dispatch, async then OR
-                dispatched = [
-                    self.engine.detect_device(tok, lens, rreq, rsv,
-                                              self._pad_q(Q))
-                    for tok, lens, rreq, rsv in buckets]
-                for rh_dev in dispatched:
-                    rule_hits |= np.asarray(rh_dev)
+            # scan_dispatch: launch + wait on the host's clock; the
+            # launch (in the engine) and the wait are its sub-spans
+            with flight.span(EV_DEVICE, tag=_ltag, arg=Q):
+                if multi is not None:
+                    rh_dev = multi(tuple(buckets), self._pad_q(Q),
+                                   head_only=head_ok)
+                    with flight.span(EV_SCAN_WAIT, tag=_ltag):
+                        rule_hits |= np.asarray(rh_dev)
+                else:
+                    # legacy engine: per-bucket dispatch, async then OR
+                    dispatched = [
+                        self.engine.detect_device(tok, lens, rreq, rsv,
+                                                  self._pad_q(Q))
+                        for tok, lens, rreq, rsv in buckets]
+                    for rh_dev in dispatched:
+                        rule_hits |= np.asarray(rh_dev)
             stats.engine_us += bucket_us + int(
                 (time.perf_counter() - te0) * 1e6)
-            flight.end(EV_DEVICE, tag=_ltag)
         rule_hits = self.mask_hits(requests, rule_hits[:Q])
         stats.prefilter_rule_hits += int(rule_hits.sum())
         return rule_hits
@@ -1215,8 +1239,38 @@ class DetectionPipeline:
         verdict."""
         stats = self.stats
         tc0 = time.perf_counter()
-        flight.begin(EV_FINALIZE, arg=len(cjob.requests))
-        results = join_confirm(self, cjob)
+        with flight.span(EV_FINALIZE, arg=len(cjob.requests)):
+            results = join_confirm(self, cjob)
+            with flight.span(EV_CONFIRM_FOLD, arg=len(cjob.requests)):
+                verdicts = self._fold_verdicts(cjob, results,
+                                               observe_rules)
+        # confirm stage wall = launch window + this join (share waits +
+        # fold).  On the overlapped mesh path the wall BETWEEN launch
+        # and join is the double buffer's window, not confirm cost —
+        # excluded by construction; the per-rule confirm_ns telemetry
+        # (RuleStats) carries the true CPU cost either way.
+        stats.confirm_us += cjob.launch_us + int(
+            (time.perf_counter() - tc0) * 1e6)
+
+        elapsed = int((time.perf_counter() - t0) * 1e6)
+        # worker attribution (ISSUE 12 satellite): the pool round-robins
+        # request qi onto worker qi % N (confirm_plane.launch_confirm),
+        # so the stamp is derivable without threading state through the
+        # walk; 0 = the inline serial walk, wedged shares keep -1
+        nw = self.confirm_pool.n_workers
+        for qi, v in enumerate(verdicts):
+            v.elapsed_us = elapsed
+            v.generation = self.generation_tag
+            if not v.fail_open:
+                v.confirm_worker = (qi % nw) if nw > 1 else 0
+        return verdicts
+
+    def _fold_verdicts(self, cjob, results,
+                       observe_rules: bool) -> List[Verdict]:
+        """The single-threaded fold of :meth:`finalize_join` (its
+        ``confirm_fold`` span): per-rule telemetry, scoring, ACL,
+        Verdict assembly over the joined confirm results."""
+        stats = self.stats
         requests, rule_hits = cjob.requests, cjob.rule_hits
         verdicts: List[Verdict] = []
         rs = self.ruleset
@@ -1335,25 +1389,5 @@ class DetectionPipeline:
         if cjob.memo is not None:
             stats.confirm_memo_hits += cjob.memo.hits
             stats.confirm_memo_misses += cjob.memo.misses
-        # confirm stage wall = launch window + this join (share waits +
-        # fold).  On the overlapped mesh path the wall BETWEEN launch
-        # and join is the double buffer's window, not confirm cost —
-        # excluded by construction; the per-rule confirm_ns telemetry
-        # (RuleStats) carries the true CPU cost either way.
-        stats.confirm_us += cjob.launch_us + int(
-            (time.perf_counter() - tc0) * 1e6)
         stats.confirmed_rule_hits += sum(len(v.rule_ids) for v in verdicts)
-        flight.end(EV_FINALIZE)
-
-        elapsed = int((time.perf_counter() - t0) * 1e6)
-        # worker attribution (ISSUE 12 satellite): the pool round-robins
-        # request qi onto worker qi % N (confirm_plane.launch_confirm),
-        # so the stamp is derivable without threading state through the
-        # walk; 0 = the inline serial walk, wedged shares keep -1
-        nw = self.confirm_pool.n_workers
-        for qi, v in enumerate(verdicts):
-            v.elapsed_us = elapsed
-            v.generation = self.generation_tag
-            if not v.fail_open:
-                v.confirm_worker = (qi % nw) if nw > 1 else 0
         return verdicts

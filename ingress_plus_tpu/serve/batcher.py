@@ -85,13 +85,16 @@ from ingress_plus_tpu.utils.trace import (
     EV_SUBMIT,
     EV_VERDICT,
     EV_WATCHDOG,
+    PER_DISPATCH,
     STAGES,
+    SUBSTAGES,
     BatchTrace,
     Ewma,
     Histogram,
     SlowRing,
     TraceRing,
     flight,
+    gc_watch,
     install_thread_excepthook,
     named_lock,
     request_tag,
@@ -112,6 +115,9 @@ def _safe_set(fut: "Future", value) -> None:
     kill the dispatch thread — that would hang every future verdict."""
     try:
         if not fut.done():
+            if flight.enabled:
+                # where the `reply` span starts (server.py closes it)
+                value.resolved_ns = time.monotonic_ns()
             fut.set_result(value)
     except Exception:
         pass
@@ -315,6 +321,9 @@ class _MeshCycle:
         "pending_fins", "done", "cand_verdicts",
         "collect_d_engine", "collect_d_confirm", "collect_d_prep",
         "collect_d_compiles",
+        # the cycle's flight-recorder envelope span and the process's
+        # GC pause total when it began
+        "span", "gc_us0",
     )
 
     def __init__(self):
@@ -435,6 +444,13 @@ class Batcher:
         # rendered at /metrics as ipt_stage_us{stage=...}, a batch-size
         # distribution, and the K slowest requests served at /debug/slow
         self.hist: dict = {s: Histogram() for s in STAGES}
+        # sub-stages (flight.span accumulators folded once per dispatch;
+        # drain_idle per drain, reply per request): the same metric
+        # family, their own dict — a sum over STAGES must not meet them
+        self.subhist: dict = {s: Histogram() for s in SUBSTAGES}
+        # µs the dispatch thread has waited for work since the last
+        # cycle's trace was cut (dispatch thread only)
+        self._drain_idle_us = 0
         self.batch_size_hist = Histogram(bounds=BATCH_SIZE_BUCKETS)
         self.slow = SlowRing(capacity=32)
         # fail-safe plane (docs/ROBUSTNESS.md): BOUNDED admission queue,
@@ -549,7 +565,7 @@ class Batcher:
         per-lane stats that the dispatch thread mutates under this same
         lock — a bare reset raced a mid-cycle fold (concheck
         conc.unguarded-mutation, ISSUE 11)."""
-        for h in self.hist.values():
+        for h in (*self.hist.values(), *self.subhist.values()):
             h.reset()
         self.batch_size_hist.reset()
         self.slow.reset()
@@ -1092,6 +1108,27 @@ class Batcher:
                 break
         return batch
 
+    def _drain_idle(self, first_timeout: float = 0.05) -> List:
+        """:meth:`_drain` under its ``drain_idle`` span: the dispatch
+        thread waiting for work (and out its batching window).  The
+        waits add up until a dispatch follows (:meth:`_sub_spans`): an
+        idle server's empty drains count for the dispatch that ends
+        them, never for a window that has closed."""
+        with flight.span(EV_DRAIN) as sp:
+            batch = self._drain(first_timeout)
+        self._drain_idle_us += sp.us
+        return batch
+
+    def _sub_spans(self, cid: int) -> Dict[str, int]:
+        """Cycle ``cid``'s sub-spans for its :class:`BatchTrace`: what
+        the flight recorder's spans accumulated under the cycle id, and
+        the loop's idle time before it.  Empty with the recorder off."""
+        sub = flight.take(cid)
+        if flight.enabled:
+            sub["drain_idle"] = self._drain_idle_us
+        self._drain_idle_us = 0
+        return sub
+
     def _stream_step_guarded(self, begins, chunks, finishes,
                              route: str, lane: Optional[Lane] = None) -> List:
         """Stream scan work rides ONE watchdogged lane (the primary, or
@@ -1366,9 +1403,7 @@ class Batcher:
             return
         while not self._stop.is_set():
             flight.set_cycle(0)
-            flight.begin(EV_DRAIN)
-            batch = self._drain()
-            flight.end(EV_DRAIN)
+            batch = self._drain_idle()
             if not batch:
                 # idle drain: feed the brownout ladder a zero so the
                 # queue-delay EWMA decays and the ladder can step back
@@ -1376,6 +1411,7 @@ class Batcher:
                 self.pipeline.load_controller.observe(0.0)
                 continue
             t0 = time.perf_counter()
+            gc_us0 = gc_watch.pause_us()
             # prologue + arm the monitor: if THIS cycle wedges past
             # every budget, the watchdog releases its futures fail-open
             reqs, deg_reqs, begins, chunks, finishes, guard = \
@@ -1384,8 +1420,8 @@ class Batcher:
             # stitches to this id (stats.batches, the cycle counter)
             cid = self.stats.batches
             flight.set_cycle(cid)
-            flight.begin(EV_CYCLE, cycle=cid,
-                         arg=len(reqs) + len(deg_reqs))
+            cycle_span = flight.span(
+                EV_CYCLE, cycle=cid, arg=len(reqs) + len(deg_reqs)).begin()
             # one breaker decision per cycle: requests AND stream scan
             # work follow it (a wedged device must not be probed twice)
             route = self.breaker.route()
@@ -1471,7 +1507,7 @@ class Batcher:
                     ro.observe_canary(len(cand_items), cand_verdicts)
                 ro.tick()
             self._clear_guard(guard)
-            flight.end(EV_CYCLE, cycle=cid)
+            cycle_span.end()
             t_end = time.perf_counter()
             took = t_end - t0
             # fail-safe plane signals: cycle-time EWMA feeds the
@@ -1509,6 +1545,8 @@ class Batcher:
                 engine_us=d_engine,
                 confirm_us=d_confirm,
                 prep_us=d_prep,
+                sub_us=self._sub_spans(cid),
+                gc_us=gc_watch.pause_us() - gc_us0,
                 # only requests this batch actually scanned (`normal` +
                 # the tenant-degraded prefilter-only share + stream
                 # finishes): an oversized-rerouted id here would make
@@ -1547,9 +1585,7 @@ class Batcher:
         while not self._stop.is_set():
             if pending is None and confirming is None:
                 flight.set_cycle(0)
-                flight.begin(EV_DRAIN)
-                batch = self._drain()
-                flight.end(EV_DRAIN)
+                batch = self._drain_idle()
                 if not batch:
                     # idle drain: decay the brownout ladder's signal
                     self.pipeline.load_controller.observe(0.0)
@@ -1558,9 +1594,7 @@ class Batcher:
                 td0 = time.perf_counter()
                 # the interleaved drain IS the double-buffer overlap
                 # window — the flight recorder's drain-occupancy signal
-                flight.begin(EV_DRAIN)
-                batch = self._drain(first_timeout=self.max_delay_s)
-                flight.end(EV_DRAIN)
+                batch = self._drain_idle(first_timeout=self.max_delay_s)
                 # the interleaved drain wait is the double buffer's
                 # idle window, not the in-flight cycles' service time —
                 # excluded from their clocks so the queue-math EWMA and
@@ -1618,9 +1652,10 @@ class Batcher:
         reqs, deg_reqs, begins, chunks, finishes, c.guard = \
             self._classify_batch(batch, t0)
         c.cid = self.stats.batches
+        c.gc_us0 = gc_watch.pause_us()
         flight.set_cycle(c.cid)
-        flight.begin(EV_CYCLE, cycle=c.cid,
-                     arg=len(reqs) + len(deg_reqs))
+        c.span = flight.span(EV_CYCLE, cycle=c.cid,
+                             arg=len(reqs) + len(deg_reqs)).begin()
         c.n_reqs = len(reqs) + len(deg_reqs)
         c.n_finishes = len(finishes)
         c.n_stream_items = len(begins) + len(chunks) + len(finishes)
@@ -1867,7 +1902,7 @@ class Batcher:
                 ro.observe_canary(len(c.cand_items), c.cand_verdicts)
             ro.tick()
         self._clear_guard(c.guard)
-        flight.end(EV_CYCLE, cycle=c.cid)
+        c.span.end()
         t_end = time.perf_counter()
         took = max(t_end - c.t0 - c.overlap_drain_s, 0.0)
         if d_compiles == 0:
@@ -1887,6 +1922,8 @@ class Batcher:
             engine_us=d_engine,
             confirm_us=d_confirm,
             prep_us=d_prep,
+            sub_us=self._sub_spans(c.cid),
+            gc_us=gc_watch.pause_us() - c.gc_us0,
             request_ids=[r.request_id for _ts, r, _v, _l in done]
             + [h.request.request_id for h, _ in c.finish_verdicts])
         self.traces.record(trace)
@@ -2031,6 +2068,11 @@ class Batcher:
         h["prep"].observe(trace.prep_us)
         h["scan"].observe(trace.engine_us)
         h["confirm"].observe(trace.confirm_us)
+        if flight.enabled:
+            # the cycle's sub-spans, once per dispatch like the stages
+            # they lie in (a dispatch without one observes 0)
+            for name in PER_DISPATCH:
+                self.subhist[name].observe(trace.sub_us.get(name, 0))
         if trace.n_requests:
             self.batch_size_hist.observe(trace.n_requests)
         stages = None                 # built only if something IS slow

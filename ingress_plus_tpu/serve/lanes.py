@@ -37,7 +37,12 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from ingress_plus_tpu.utils import faults
-from ingress_plus_tpu.utils.trace import flight, named_lock
+from ingress_plus_tpu.utils.trace import (
+    EV_HANDOFF,
+    EV_LANE_CALL,
+    flight,
+    named_lock,
+)
 
 
 class DeviceHang(Exception):
@@ -60,9 +65,18 @@ class LanePending:
         return self._ev.is_set()
 
     def wait(self, timeout: Optional[float]):
+        t_wait = time.monotonic_ns()
         if not self._ev.wait(timeout):
             raise DeviceHang("device dispatch exceeded %.3fs"
                              % (timeout if timeout is not None else -1.0))
+        handoff = self._box.get("handoff")
+        if handoff is not None:
+            # the hand-off BACK (tag 1): from the closure's end — or
+            # from when this thread began to wait, if that was later —
+            # to this thread awake again
+            cycle, t_end = handoff
+            flight.span_at(EV_HANDOFF, max(t_end, t_wait),
+                           time.monotonic_ns(), cycle=cycle, tag=1)
         if "error" in self._box:
             raise self._box["error"]
         return self._box.get("result")
@@ -81,6 +95,13 @@ class LaneWorker:
     observability, never in verdicts (its batch's futures were already
     resolved fail-open, and the batcher's ``_safe_set`` tolerates the
     late duplicate set)."""
+
+    #: device lanes stamp the two thread hand-offs around a call as
+    #: ``lane_handoff`` spans (put → closure start on the worker's ring,
+    #: closure end → caller awake on the caller's): the caller's wait
+    #: minus the closure's run time.  The confirm pool's workers reuse
+    #: this machinery and turn the stamps off.
+    HANDOFF_SPANS = True
 
     def __init__(self, seq: int = 0, lane_index: Optional[int] = None,
                  name: str = "ipt-device"):
@@ -109,14 +130,24 @@ class LaneWorker:
             if item is None:
                 return
             fn, box, ev = item
+            stamp = box.get("put")
+            if stamp is not None:
+                # the hand-off TO this worker (tag 0)
+                flight.span_at(EV_HANDOFF, stamp[1], time.monotonic_ns(),
+                               cycle=stamp[0], tag=0)
             try:
                 box["result"] = fn()
             except BaseException as e:  # noqa: BLE001 — relayed to the caller
                 box["error"] = e
+            if stamp is not None:
+                box["handoff"] = (stamp[0], time.monotonic_ns())
             ev.set()
 
     def submit(self, fn: Callable) -> LanePending:
         box: dict = {}
+        if self.HANDOFF_SPANS and flight.enabled:
+            # (the caller's cycle id, the moment of the put)
+            box["put"] = (flight.cycle(), time.monotonic_ns())
         ev = threading.Event()
         self._q.put((fn, box, ev))
         return LanePending(box, ev)
@@ -280,7 +311,11 @@ class Lane:
         single-lane semantics, now per chip)."""
         self.stats.dispatches += 1
         try:
-            return self.worker.call(fn, timeout)
+            # lane_call: this thread blocked while the worker runs fn —
+            # in a profiler trace, what of it no span of the worker's
+            # covers is the hand-off (and the worker's unspanned code)
+            with flight.span(EV_LANE_CALL, tag=self.index):
+                return self.worker.call(fn, timeout)
         except DeviceHang:
             self.abandon_worker()
             raise

@@ -50,8 +50,19 @@ from ingress_plus_tpu.serve.protocol import (
     encode_response,
 )
 from ingress_plus_tpu.serve.websocket import DIR_C2S, DIR_S2C, WSStream
-from ingress_plus_tpu.utils.platform import backend_compiles
-from ingress_plus_tpu.utils.trace import thread_uncaught_counts
+from ingress_plus_tpu.utils.platform import (
+    backend_compiles,
+    device_memory_peak_bytes,
+)
+from ingress_plus_tpu.utils.trace import (
+    EV_REPLY,
+    ProfilerBusy,
+    ProfilerSwitch,
+    flight,
+    gc_watch,
+    request_tag,
+    thread_uncaught_counts,
+)
 
 
 MAX_STREAMS_PER_CONN = 256  # bounded per-connection stream state
@@ -81,7 +92,15 @@ METRIC_HELP = {
     "ipt_watchdog_hangs_total": "device dispatches past the hang budget",
     "ipt_cpu_fallback_batches_total":
         "batches served on the CPU confirm-only fallback",
-    "ipt_stage_us": "per-stage latency histogram (log2 us buckets)",
+    "ipt_stage_us":
+        "per-stage and per-sub-stage latency histogram (log2 us buckets)",
+    "ipt_device_launches_total":
+        "device programs enqueued by the scan dispatch",
+    "ipt_device_memory_peak_bytes":
+        "peak device memory in use, highest over local devices",
+    "ipt_gc_pause_us_total":
+        "interpreter collection pauses by generation (us)",
+    "ipt_gc_collections_total": "interpreter collections by generation",
     "ipt_batch_size": "batch-size distribution (pow2 buckets)",
     "ipt_rule_family_hits_total": "confirmed hits per CRS family",
     "ipt_rule_family_candidates_total":
@@ -111,7 +130,8 @@ METRIC_HELP = {
     "ipt_lane_requests_total": "requests dispatched per lane",
     "ipt_lane_rows_total": "scan rows dispatched per lane",
     "ipt_lane_errors_total": "dispatch errors per lane",
-    "ipt_lane_busy_us_sum": "device-busy wall time per lane (us)",
+    "ipt_lane_busy_us_sum":
+        "host clock around launch and wait per lane (us)",
     "ipt_ruleset_info": "live ruleset version/size (info joint)",
     "ipt_scorer_active": "1 while a learned scoring head is installed",
     "ipt_scorer_diff_total":
@@ -139,8 +159,12 @@ def _with_help(lines):
 class ServeLoop:
     def __init__(self, batcher: Batcher, socket_path: str,
                  http_port: int = 0, post=None,
-                 sidecar_status: Optional[str] = None):
+                 sidecar_status: Optional[str] = None,
+                 trace_dir: Optional[str] = None):
         self.batcher = batcher
+        # the program's profiler switch (POST /debug/profile) traces
+        # into --trace-dir; without the directory the route says so
+        self.profiler = ProfilerSwitch(trace_dir)
         self.socket_path = socket_path
         self.http_port = http_port
         self.post = post  # PostChannel | None — postanalytics write side
@@ -189,6 +213,17 @@ class ServeLoop:
             try:
                 async with write_lock:
                     writer.write(data)
+                    t0_ns = verdict.resolved_ns
+                    if t0_ns:
+                        # the `reply` span: verdict resolved on the
+                        # dispatch thread → this loop awake, record,
+                        # encode, frame handed to the transport
+                        t1_ns = time.monotonic_ns()
+                        flight.span_at(
+                            EV_REPLY, t0_ns, t1_ns, cycle=0,
+                            tag=request_tag(verdict.request_id))
+                        self.batcher.subhist["reply"].observe(
+                            (t1_ns - t0_ns) // 1000)
                     await writer.drain()
             except (ConnectionError, RuntimeError):
                 pass  # client went away mid-verdict; nothing to deliver to
@@ -690,6 +725,25 @@ class ServeLoop:
         lines.append("# TYPE ipt_stage_us histogram")
         for stage, hist in self.batcher.hist.items():
             lines += hist.prometheus("ipt_stage_us", {"stage": stage})
+        if flight.enabled:
+            # sub-stages (flight.span accumulators): inside scan and
+            # confirm or beside the cycle, never part of a stage sum
+            for stage, hist in self.batcher.subhist.items():
+                lines += hist.prometheus("ipt_stage_us", {"stage": stage})
+        lines += [
+            "# TYPE ipt_device_launches_total counter",
+            "ipt_device_launches_total %d"
+            % getattr(pipeline.engine, "device_launches", 0),
+            "# TYPE ipt_gc_pause_us_total counter"]
+        lines += ['ipt_gc_pause_us_total{generation="%d"} %d' % (g, us)
+                  for g, us in enumerate(gc_watch.pause_us_by_gen)]
+        lines.append("# TYPE ipt_gc_collections_total counter")
+        lines += ['ipt_gc_collections_total{generation="%d"} %d' % (g, n)
+                  for g, n in enumerate(gc_watch.collections_by_gen)]
+        peak = device_memory_peak_bytes()
+        if peak is not None:
+            lines += ["# TYPE ipt_device_memory_peak_bytes gauge",
+                      "ipt_device_memory_peak_bytes %d" % peak]
         lines.append("# TYPE ipt_batch_size histogram")
         lines += self.batcher.batch_size_hist.prometheus("ipt_batch_size")
         lines += [
@@ -980,7 +1034,6 @@ class ServeLoop:
             # submit→verdict.  Save the body and load it straight into
             # https://ui.perfetto.dev.  ?cycles=N (default 64).
             from urllib.parse import parse_qs, urlsplit
-            from ingress_plus_tpu.utils.trace import flight
             q = parse_qs(urlsplit(path).query, keep_blank_values=True)
             try:
                 n = int((q.get("cycles") or ["64"])[0])
@@ -994,6 +1047,36 @@ class ServeLoop:
             body = await loop.run_in_executor(
                 None, lambda: json.dumps(flight.chrome_trace(cycles=n)))
             return "200 OK", "application/json", body.encode()
+        if path.startswith("/debug/profile"):
+            # the program's profiler switch (docs/OBSERVABILITY.md "XProf
+            # device traces"): POST /debug/profile?seconds=<s> starts a
+            # jax.profiler session now (Python tracer off), stops it
+            # after s seconds and answers once the file is written —
+            # one session at a time, 409 while one runs
+            from urllib.parse import parse_qs, urlsplit
+            if method != "POST":
+                return ("405 Method Not Allowed", "application/json",
+                        b'{"error": "POST /debug/profile?seconds=<s>"}')
+            if not self.profiler.trace_dir:
+                return ("400 Bad Request", "application/json",
+                        b'{"error": "start the server with --trace-dir"}')
+            q = parse_qs(urlsplit(path).query)
+            try:
+                seconds = float((q.get("seconds") or ["1"])[0])
+            except ValueError:
+                seconds = -1.0
+            if not 0 < seconds <= self.profiler.MAX_SECONDS:
+                return ("400 Bad Request", "application/json", json.dumps(
+                    {"error": "seconds must be in (0, %g]"
+                     % self.profiler.MAX_SECONDS}).encode())
+            try:
+                written = await loop.run_in_executor(
+                    None, self.profiler.run, seconds)
+            except ProfilerBusy:
+                return ("409 Conflict", "application/json",
+                        b'{"error": "a profiler session is running"}')
+            return ("200 OK", "application/json",
+                    json.dumps(written).encode())
         if path.startswith("/debug/slow"):
             # the K slowest requests since startup: full span breakdown,
             # truncated input sizes, rules hit (exemplar capture)
@@ -1707,8 +1790,10 @@ def main(argv=None) -> None:
                     help="watch this dir for compiled-ruleset artifacts "
                          "and hot-swap (sync-node analog)")
     ap.add_argument("--trace-dir", default=None,
-                    help="collect a jax.profiler (XProf) trace of the "
-                         "serve loop into this dir until shutdown")
+                    help="directory for jax.profiler (XProf) traces: "
+                         "POST /debug/profile?seconds=<s> on the HTTP "
+                         "plane writes a trace of the next s seconds "
+                         "there (docs/OBSERVABILITY.md)")
     ap.add_argument("--sidecar-status", default=None,
                     help="host:port of the native sidecar's --status-port"
                          " listener; /traces/request then includes the "
@@ -1848,10 +1933,10 @@ def main(argv=None) -> None:
     # cycle flight recorder knobs (docs/OBSERVABILITY.md): configure
     # BEFORE the batcher's threads start so every ring carries the
     # chosen cap and the escape hatch truly zeroes the surface
-    from ingress_plus_tpu.utils.trace import flight
-
     flight.configure(ring_kb=args.trace_ring_kb,
                      enabled=not args.no_flight_recorder)
+    # interpreter collection pauses: ring spans + ipt_gc_* counters
+    gc_watch.install()
 
     import jax
 
@@ -1918,11 +2003,10 @@ def main(argv=None) -> None:
         watcher.start()
 
     loop = ServeLoop(batcher, args.socket, args.http_port, post=post,
-                     sidecar_status=args.sidecar_status)
-    from ingress_plus_tpu.utils.trace import profiled
+                     sidecar_status=args.sidecar_status,
+                     trace_dir=args.trace_dir)
     try:
-        with profiled(args.trace_dir):
-            asyncio.run(loop.run_forever())
+        asyncio.run(loop.run_forever())
     finally:
         if watcher is not None:
             watcher.close()

@@ -7,10 +7,12 @@ confirm overlaps cycle N+1's scan.  Until now both were asserted by
 construction; this module MEASURES them from the recorded timeline:
 
 * ``scan↔confirm overlap fraction`` — the share of confirm wall time
-  during which some device scan was simultaneously busy (the PR 9
-  claim, measured);
-* ``per-lane idle-gap share`` — 1 − device-busy / measurement window
-  per lane (where the chips wait on the host);
+  during which some lane's scan dispatch was open (the PR 9 claim,
+  measured);
+* ``per-lane idle-gap share`` — 1 − scan-dispatch time / measurement
+  window per lane: the HOST's clock around launch and wait
+  (``EV_DEVICE``), an upper bound of what the chip was given to do —
+  the device's own busy time comes only from a profiler trace;
 * ``drain occupancy`` — the dispatch thread's share of the window spent
   in the double-buffer drain wait (PR 7's overlap window: high under
   load means the host keeps up, ~0 means the dispatch thread never

@@ -84,6 +84,18 @@ def enable_compile_cache() -> Optional[str]:
     return env_dir or str(REPO_CACHE_DIR)
 
 
+def device_memory_peak_bytes() -> Optional[int]:
+    """``memory_stats()["peak_bytes_in_use"]``, the highest over the
+    local devices, read now (``ipt_device_memory_peak_bytes``); None
+    where the backend reports none (CPU)."""
+    import jax
+
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    known = [p for p in peaks if p is not None]
+    return max(known) if known else None
+
+
 def on_tpu() -> bool:
     """Whether the Mosaic kernels compile on the default backend — the
     one place this is decided."""

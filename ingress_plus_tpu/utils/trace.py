@@ -24,13 +24,22 @@ profiles the Go side with pprof.  The TPU-native equivalents:
     text back into per-stage p50/p99 (bench.py emits this as the
     ``stage_breakdown`` object in BENCH json, decomposing the latency
     leg by stage).
-  * ``profiled`` — wraps a region in ``jax.profiler`` trace collection
-    (XProf/TensorBoard — the device-side flamegraph the CUDA world gets
-    from nsys); enabled on the serve loop with ``--trace-dir``.
+  * ``flight.span`` — the ONE span primitive on top of the flight
+    recorder: ring begin/end events, a per-cycle µs accumulator the
+    batcher folds into ``ipt_stage_us{stage=<sub-stage>}``, and a
+    ``jax.profiler.TraceAnnotation("ipt:<name>")`` so the same span
+    lies in a profiler trace beside the device operations.
+  * ``ProfilerSwitch`` — the program's own profiler switch
+    (``POST /debug/profile?seconds=``, traces into ``--trace-dir``):
+    one bounded session at a time, Python tracer off.
+  * ``GcWatch`` — ``gc.callbacks`` hook: interpreter collection pauses
+    as ring spans and ``ipt_gc_pause_us_total{generation}``.
 """
 
 from __future__ import annotations
 
+import gc
+import glob
 import heapq
 import os
 import re
@@ -39,7 +48,6 @@ import time
 import traceback
 from bisect import bisect_left
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -439,20 +447,30 @@ class BatchTrace:
     confirm_us: int           # CPU confirm portion (cumulative delta)
     request_ids: List[str] = field(default_factory=list)
     prep_us: int = 0          # host prep (normalize/unpack/row build)
+    #: the cycle's sub-spans, {sub-stage: µs} (``ACCUMULATED``; empty
+    #: with the flight recorder off)
+    sub_us: Dict[str, int] = field(default_factory=dict)
+    gc_us: int = 0            # interpreter collection pauses in the cycle
 
     def stages(self) -> Dict[str, int]:
         """Per-stage µs breakdown; ``other_us`` is the unattributed
-        remainder of the dispatch cycle (stream scan work, queue ops)."""
+        remainder of the dispatch cycle (stream scan work, queue ops).
+        The sub-spans and the cycle's GC pauses ride along (``<sub-
+        stage>_us``, ``gc_us``), so a slow exemplar names its part."""
         other = self.batch_us - self.prep_us - self.engine_us \
             - self.confirm_us
-        return {
+        out = {
             "queue_us": self.queue_delay_us,
             "prep_us": self.prep_us,
             "scan_us": self.engine_us,
             "confirm_us": self.confirm_us,
             "batch_us": self.batch_us,
             "other_us": max(other, 0),
+            "gc_us": self.gc_us,
         }
+        for name, us in self.sub_us.items():
+            out[name + "_us"] = us
+        return out
 
 
 class TraceRing:
@@ -699,13 +717,13 @@ def stage_breakdown_from_metrics(text: str,
 
 #: event codes (ints on the hot path; EVENT_NAMES only at export)
 EV_CYCLE = 1       # one dispatch cycle, launch → resolve (dispatch)
-EV_DRAIN = 2       # admission-queue drain wait (dispatch)
+EV_DRAIN = 2       # dispatch thread waiting for work (drain_idle)
 EV_QUEUE = 3       # instant: a tenant sub-queue's max wait this cycle
 EV_PREP = 4        # host prep: normalize/unpack/row build+merge
 EV_LAUNCH = 5      # one lane share's prep+launch (dispatch), tag=lane
-EV_DEVICE = 6      # device dispatch busy (lane worker), tag=lane
+EV_DEVICE = 6      # scan dispatch: launch + wait on the HOST's clock, tag=lane
 EV_COLLECT = 7     # one lane share's scan collection (dispatch), tag=lane
-EV_CONFIRM = 8     # one confirm share's walk, tag=worker, arg=n_requests
+EV_CONFIRM = 8     # one confirm share's candidate walk, tag=worker, arg=n_requests
 EV_FINALIZE = 9    # finalize join + single-threaded fold (dispatch)
 EV_MIRROR = 10     # rollout shadow mirroring of resolved verdicts
 EV_STREAM = 11     # stream-step scan work (pinned lane worker)
@@ -715,16 +733,61 @@ EV_VERDICT = 14    # instant: verdict resolved, tag=req-id hash, arg=lane
 EV_SHADOW = 15     # shadow-lane candidate scan (shadow thread)
 EV_EXPORT = 16     # postanalytics export flush attempt
 EV_WATCHDOG = 17   # instant: watchdog released futures, arg=count
+EV_SCAN_PACK = 18  # pad/pack of rows into tier buckets (host), arg=rows
+EV_SCAN_LAUNCH = 19  # host→device transfers + enqueue of every program
+EV_SCAN_WAIT = 20  # blocked on the device result + device→host copy
+EV_CONFIRM_FOLD = 21  # telemetry fold, scoring, ACL, verdict assembly
+EV_HANDOFF = 22    # a lane call's thread hand-off, tag 0=to worker 1=back
+EV_REPLY = 23      # verdict resolved → reply frame written, tag=req-id hash
+EV_GC = 24         # one interpreter collection pause, arg=generation
+EV_LANE_CALL = 25  # dispatch thread blocked in a lane call, tag=lane
 
 EVENT_NAMES: Dict[int, str] = {
-    EV_CYCLE: "cycle", EV_DRAIN: "drain", EV_QUEUE: "queue_wait",
+    EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
     EV_PREP: "host_prep", EV_LAUNCH: "lane_launch", EV_DEVICE:
-    "device_busy", EV_COLLECT: "lane_collect", EV_CONFIRM:
-    "confirm_share", EV_FINALIZE: "finalize_join", EV_MIRROR: "mirror",
+    "scan_dispatch", EV_COLLECT: "lane_collect", EV_CONFIRM:
+    "confirm_walk", EV_FINALIZE: "finalize_join", EV_MIRROR: "mirror",
     EV_STREAM: "stream_step", EV_OVERSIZED: "oversized",
     EV_SUBMIT: "submit", EV_VERDICT: "verdict", EV_SHADOW: "shadow_scan",
     EV_EXPORT: "export", EV_WATCHDOG: "watchdog_release",
+    EV_SCAN_PACK: "scan_pack", EV_SCAN_LAUNCH: "scan_launch",
+    EV_SCAN_WAIT: "scan_wait", EV_CONFIRM_FOLD: "confirm_fold",
+    EV_HANDOFF: "lane_handoff", EV_REPLY: "reply", EV_GC: "gc",
+    EV_LANE_CALL: "lane_call",
 }
+
+#: span codes whose elapsed µs accumulate per cycle id for the batcher's
+#: once-per-dispatch fold into ``ipt_stage_us{stage=<sub-stage>}``
+#: (drain_idle is summed by the batcher itself, over the drains that
+#: led to a dispatch; reply is observed per request where it ends)
+ACCUMULATED: Dict[int, str] = {
+    c: EVENT_NAMES[c] for c in (
+        EV_SCAN_PACK, EV_SCAN_LAUNCH, EV_SCAN_WAIT, EV_CONFIRM,
+        EV_CONFIRM_FOLD, EV_HANDOFF)}
+
+#: the sub-stages observed once per dispatch
+PER_DISPATCH: Tuple[str, ...] = tuple(ACCUMULATED.values()) + ("drain_idle",)
+
+#: sub-stages — spans INSIDE a stage (or beside the cycle), rendered
+#: under the same ``ipt_stage_us{stage=}`` family but kept in their own
+#: tuple: a sum over STAGES decomposes a request's latency, and adding
+#: these in would count the scan and confirm stages twice.
+#: scan = scan_pack + scan_launch + scan_wait (+ a residue of Python
+#: between them); confirm = confirm_walk + confirm_fold (+ the join);
+#: lane_handoff = the two thread hand-offs around a lane call;
+#: drain_idle = the dispatch thread waiting for a dispatch's work since
+#: the cycle before it ended (observed per dispatch like the rest, so
+#: its _sum over a window is the loop's idle time inside it); reply =
+#: verdict resolved → reply frame written, per request (event loop).
+SUBSTAGES: Tuple[str, ...] = PER_DISPATCH + ("reply",)
+
+#: the profiler-trace names, built once (``ipt:<name>``)
+_ANNOTATION_NAMES: Dict[int, str] = {
+    c: "ipt:" + n for c, n in EVENT_NAMES.items()}
+
+#: cycles whose accumulators may wait for their fold at once (the mesh
+#: loop holds two in flight); past it the oldest is dropped
+_MAX_OPEN_CYCLES = 16
 
 #: phases — begin / end / instant (flow endpoints are instants on the
 #: submit/verdict codes; the exporter synthesizes Chrome s/f pairs)
@@ -789,6 +852,90 @@ class _ThreadRing:
         return out
 
 
+_annotation_cls = None
+
+
+def _annotation(code: int, cycle: int, arg: int):
+    """``jax.profiler.TraceAnnotation("ipt:<name>", cycle=, n=)``: the
+    span on the profiler's clock.  Always built while the recorder is
+    on — whoever starts a profiler session (``ProfilerSwitch``, or a
+    harness from outside the program) finds the spans in its trace;
+    with no session active it costs about a microsecond.  The import
+    is deferred so this module loads without JAX."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(_ANNOTATION_NAMES[code], cycle=cycle, n=arg)
+
+
+class _NoSpan:
+    """``flight.span`` with the recorder off: nothing, ``us`` 0."""
+
+    __slots__ = ()
+    us = 0
+
+    def begin(self):
+        return self
+
+    def end(self) -> None:
+        return None
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One open span (see :meth:`FlightRecorder.span`)."""
+
+    __slots__ = ("_rec", "_code", "_tag", "_arg", "_cycle", "_ring",
+                 "_t0", "_ann", "us")
+
+    def __init__(self, rec: "FlightRecorder", code: int,
+                 cycle: Optional[int], tag: int, arg: int):
+        self._rec, self._code, self._tag, self._arg = rec, code, tag, arg
+        self._cycle = cycle
+        self.us = 0
+
+    def begin(self):
+        """Open the span (``with`` does; a span that opens in one
+        function and closes in another calls ``begin``/``end`` itself,
+        on one thread)."""
+        # concheck: ok a span object is opened and closed by one thread
+        ring = self._ring = self._rec._ring()
+        if self._cycle is None:
+            # concheck: ok a span object is opened and closed by one thread
+            self._cycle = ring.cycle
+        # concheck: ok a span object is opened and closed by one thread
+        ann = self._ann = _annotation(self._code, self._cycle, self._arg)
+        ann.__enter__()
+        # concheck: ok a span object is opened and closed by one thread
+        t0 = self._t0 = time.monotonic_ns()
+        ring.record(t0, self._code, PH_B, self._cycle, self._tag,
+                    self._arg)
+        return self
+
+    def end(self) -> None:
+        t1 = time.monotonic_ns()
+        self._ring.record(t1, self._code, PH_E, self._cycle, self._tag, 0)
+        self._ann.__exit__(None, None, None)
+        # concheck: ok a span object is opened and closed by one thread
+        self.us = us = (t1 - self._t0) // 1000
+        if self._code in ACCUMULATED:
+            self._rec._accumulate(self._code, self._cycle, us)
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
 class FlightRecorder:
     """Process-wide cycle flight recorder.  Threads register (or are
     lazily auto-registered under their normalized thread name) and get a
@@ -809,6 +956,10 @@ class FlightRecorder:
         self._lock = named_lock("FlightRecorder._lock")
         self._rings: List[_ThreadRing] = []
         self._tls = threading.local()
+        #: cycle id → {sub-stage: µs}, filled by spans on any thread,
+        #: taken by the batcher when the cycle's verdicts resolve
+        self._acc: Dict[int, Dict[str, int]] = {}
+        self._acc_lock = named_lock("FlightRecorder._acc_lock")
 
     # ------------------------------------------------------- lifecycle
 
@@ -824,6 +975,8 @@ class FlightRecorder:
                 self.enabled = bool(enabled)
             self._rings = []
             self._gen += 1
+        with self._acc_lock:
+            self._acc = {}
 
     def reset(self) -> None:
         self.configure()
@@ -860,6 +1013,18 @@ class FlightRecorder:
         self._tls.gen = gen
         return ring
 
+    def _ring_if_registered(self) -> Optional[_ThreadRing]:
+        """The calling thread's ring if it has a current one — never
+        registers, never locks (the GC hook's entry: a collection can
+        start inside ``_register`` itself)."""
+        if not self.enabled:
+            return None
+        tls = self._tls
+        ring = getattr(tls, "ring", None)
+        if ring is None or getattr(tls, "gen", -1) != self._gen:
+            return None
+        return ring
+
     def _ring(self) -> _ThreadRing:
         tls = self._tls
         ring = getattr(tls, "ring", None)
@@ -893,6 +1058,57 @@ class FlightRecorder:
     def instant(self, code: int, cycle: Optional[int] = None,
                 tag: int = 0, arg: int = 0) -> None:
         self.record(code, PH_I, cycle, tag, arg)
+
+    def span(self, code: int, cycle: Optional[int] = None,
+             tag: int = 0, arg: int = 0):
+        """The span primitive — ``with flight.span(EV_X, arg=n) as sp:``.
+        From this one call site the span (a) writes the ring's
+        begin/end events under the thread's ambient cycle id, (b) adds
+        its elapsed µs to that cycle's accumulator when its code is in
+        ``ACCUMULATED`` (``take`` hands them to the batcher), and (c)
+        lies in a profiler trace as ``ipt:<name>`` with ``cycle`` and
+        ``n`` (TraceAnnotation).  ``sp.us`` holds the elapsed µs after
+        the block.  For per-dispatch spans: a dozen or so a cycle.
+        Recorder off: one attribute read, ``sp.us`` stays 0."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, code, cycle, tag, arg)
+
+    def span_at(self, code: int, t0_ns: int, t1_ns: int,
+                cycle: Optional[int] = None, tag: int = 0,
+                arg: int = 0) -> None:
+        """A span whose ends were stamped elsewhere
+        (``time.monotonic_ns``): a hand-off between two threads, a
+        per-request reply.  Ring events and accumulator as ``span``; no
+        profiler annotation (it cannot be back-dated, and per-request
+        spans would grow the trace with the request count)."""
+        if not self.enabled:
+            return
+        ring = self._ring()
+        if cycle is None:
+            cycle = ring.cycle
+        ring.record(t0_ns, code, PH_B, cycle, tag, arg)
+        ring.record(t1_ns, code, PH_E, cycle, tag, 0)
+        if code in ACCUMULATED:
+            self._accumulate(code, cycle, max(t1_ns - t0_ns, 0) // 1000)
+
+    def _accumulate(self, code: int, cycle: int, us: int) -> None:
+        name = ACCUMULATED[code]
+        with self._acc_lock:
+            acc = self._acc.get(cycle)
+            if acc is None:
+                if len(self._acc) >= _MAX_OPEN_CYCLES:
+                    # a cycle nobody took (a library caller's, a side
+                    # lane's): the oldest goes, the dict stays small
+                    del self._acc[next(iter(self._acc))]
+                acc = self._acc[cycle] = {}
+            acc[name] = acc.get(name, 0) + us
+
+    def take(self, cycle: int) -> Dict[str, int]:
+        """Pop what the spans of ``cycle`` accumulated, ``{sub-stage:
+        µs}`` (empty with the recorder off or nothing recorded)."""
+        with self._acc_lock:
+            return self._acc.pop(cycle, None) or {}
 
     def set_cycle(self, cycle: int) -> None:
         """Ambient cycle id for subsequent events on THIS thread (the
@@ -1044,27 +1260,134 @@ def match_spans(events: Sequence[tuple]) -> List[tuple]:
 flight = FlightRecorder()
 
 
-@contextmanager
-def profiled(trace_dir: Optional[str]):
-    """JAX profiler region (no-op when trace_dir is falsy).
+class GcWatch:
+    """Interpreter collection pauses, from ``gc.callbacks``: each pause
+    is a span on whichever thread ran it (``EV_GC``, arg=generation:
+    ring and profiler trace) and counts into per-generation totals
+    (``ipt_gc_pause_us_total`` / ``ipt_gc_collections_total``).  A
+    collection holds the interpreter lock for its whole length, so a
+    pause on ANY thread stalls the cycle in flight: the batcher reads
+    ``pause_us()`` around each cycle (``BatchTrace.gc_us``).
 
-    Traces land as XProf protobufs under trace_dir; view with
-    TensorBoard's profile plugin.  Kept coarse (whole-region) because the
-    serve loop's dispatch is one jit call per batch — per-op detail comes
-    from the trace itself, not from host-side span nesting.
-    """
-    if not trace_dir:
-        yield
-        return
-    import jax
+    A collection starts wherever its thread happens to allocate — also
+    inside a locked section of this module — so the hook takes NO lock
+    and registers nothing: it writes the span by hand, into a ring the
+    thread already has (a thread with none only counts)."""
 
-    jax.profiler.start_trace(trace_dir)
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        import sys
+    def __init__(self, recorder: "FlightRecorder"):
+        self._rec = recorder
+        self._t0 = 0
+        self._ann = None
+        self.pause_us_by_gen = [0, 0, 0]
+        self.collections_by_gen = [0, 0, 0]
+        self._installed = False
 
-        print("profiler trace (%.1fs) written to %s"
-              % (time.time() - t0, trace_dir), file=sys.stderr)
+    def install(self) -> None:
+        """Idempotent (serve start-up; tests that build many loops)."""
+        if not self._installed:
+            if self._rec.enabled:
+                _annotation(EV_GC, 0, 0)   # the import, outside the hook
+            gc.callbacks.append(self._on_gc)
+            # concheck: ok installed once, by the thread that starts the server
+            self._installed = True
+
+    def uninstall(self) -> None:
+        if self._installed:
+            gc.callbacks.remove(self._on_gc)
+            # concheck: ok as install
+            self._installed = False
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never nest and run under the interpreter lock, so
+        # one start stamp and plain adds are enough
+        gen = min(int(info.get("generation", 2)), 2)
+        rec = self._rec
+        if phase == "start":
+            if rec.enabled and _annotation_cls is not None:
+                self._ann = _annotation(EV_GC, 0, gen)
+                self._ann.__enter__()
+            self._t0 = time.monotonic_ns()
+        elif self._t0:
+            t1 = time.monotonic_ns()
+            t0, self._t0 = self._t0, 0
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            ring = rec._ring_if_registered()
+            if ring is not None:
+                ring.record(t0, EV_GC, PH_B, 0, 0, gen)
+                ring.record(t1, EV_GC, PH_E, 0, 0, 0)
+            self.pause_us_by_gen[gen] += (t1 - t0) // 1000
+            self.collections_by_gen[gen] += 1
+
+    def pause_us(self) -> int:
+        return sum(self.pause_us_by_gen)
+
+
+#: the process-wide GC watch (installed by the serve entry point)
+gc_watch = GcWatch(flight)
+
+
+class ProfilerBusy(Exception):
+    """A profiler session is already running."""
+
+
+#: what ``ProfilerSwitch`` hands ``jax.profiler.ProfileOptions``: the
+#: Python tracer OFF (with it on the host slows until the server sheds:
+#: PERF.md, PR 26) and the host tracer at 1 — TraceMe events of level 1,
+#: which is where ``TraceAnnotation`` (the ``ipt:`` spans) lies, without
+#: the runtime's own level-2 chatter.  On the chip that wrote 0.9 s of
+#: the body-post cell in 28.5 s against 127.7 s at the default level 2;
+#: the file stays ~40 MB either way (its ~0.8 M device events are every
+#: step of the scan's loop, and no TPU trace mode that keeps ``XLA Ops``
+#: drops them): PERF.md §6 (PR 27) has every setting tried.
+PROFILE_OPTIONS = {"python_tracer_level": 0, "host_tracer_level": 1}
+
+
+class ProfilerSwitch:
+    """The program's own profiler switch: one bounded ``jax.profiler``
+    session at a time into ``trace_dir`` (``serve --trace-dir``),
+    started by ``POST /debug/profile?seconds=<s>``."""
+
+    MAX_SECONDS = 60.0
+
+    def __init__(self, trace_dir: Optional[str]):
+        self.trace_dir = trace_dir
+        self._busy = threading.Lock()
+
+    def run(self, seconds: float) -> dict:
+        """Start now, stop after ``seconds``, answer when the file is
+        written: its path, size and write time.  Blocks the calling
+        thread (never the event loop's).  Raises :class:`ProfilerBusy`
+        while a session runs."""
+        import jax
+
+        if not self._busy.acquire(blocking=False):
+            raise ProfilerBusy()
+        try:
+            options = jax.profiler.ProfileOptions()
+            for key, value in PROFILE_OPTIONS.items():
+                setattr(options, key, value)
+            before = set(self._traces())
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=options)
+            t0 = time.monotonic()
+            try:
+                time.sleep(seconds)
+            finally:
+                t1 = time.monotonic()
+                jax.profiler.stop_trace()
+            write_s = time.monotonic() - t1
+            new = sorted(set(self._traces()) - before)
+            path = new[-1] if new else None
+            return {"path": path,
+                    "bytes": os.path.getsize(path) if path else 0,
+                    "seconds": round(t1 - t0, 3),
+                    "write_s": round(write_s, 3),
+                    "options": dict(PROFILE_OPTIONS)}
+        finally:
+            self._busy.release()
+
+    def _traces(self) -> List[str]:
+        return glob.glob(os.path.join(
+            self.trace_dir, "plugins", "profile", "*", "*.xplane.pb"))

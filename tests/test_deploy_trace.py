@@ -3,6 +3,7 @@ postanalytics consolidator CLI — golden-file style like the reference's
 template_test.go† (SURVEY.md §4)."""
 
 import json
+import time
 from pathlib import Path
 
 from ingress_plus_tpu.control.deploy import (
@@ -142,7 +143,12 @@ SecRule ARGS "@rx (?i)union\\s+select" "id:942100,phase:2,block,severity:CRITICA
         fut = b.submit(Request(uri="/?q=1%20union%20select%20x",
                                request_id="t-1"))
         assert fut.result(timeout=60).attack
-        traces = b.traces.snapshot()
+        # the verdict resolves first and the cycle's trace is cut after
+        # it, on the dispatch thread: give that thread its turn
+        deadline = time.monotonic() + 10
+        while not (traces := b.traces.snapshot()) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert traces and traces[-1]["n_requests"] == 1
         assert traces[-1]["request_ids"] == ["t-1"]
         assert traces[-1]["batch_us"] > 0

@@ -246,7 +246,7 @@ def test_overlap_report_known_fraction():
     assert rep["lane_idle_share"]["0"] == pytest.approx(0.5, abs=1e-4)
     assert rep["drain_occupancy"] == pytest.approx(0.1, abs=1e-4)
     # confirm (60ms) out-lasts device (50ms): the cycle's critical path
-    assert next(iter(rep["critical_path"])) == "confirm_share"
+    assert next(iter(rep["critical_path"])) == "confirm_walk"
     # serialized residue: confirm worker holds the largest exclusive
     # share (40ms of the 90ms any-busy union)
     top = rep["serialized_residue"][0]
@@ -340,23 +340,23 @@ def test_clean_path_ab_overhead(cr):
     (CHANGES.md carries the measured number); this in-suite assertion
     uses a noise-tolerant bound so a loaded CI host cannot flake it,
     while still catching an accidentally-hot record path (a 2x
-    regression fails loudly)."""
+    regression fails loudly).  The two sides are timed call by call,
+    in turn, and compared at their lower quartile: a block of calls
+    per side read 0.67-1.36 on an unchanged tree under the suite's
+    own load (xdist), the quartile of interleaved calls 1.02-1.08."""
     pipe = DetectionPipeline(cr, mode="block")
     reqs = _reqs(16)
     pipe.detect(reqs)                      # compile outside the clock
 
-    def measure(enabled, iters=60):
-        flight.configure(enabled=enabled)
-        best = float("inf")
-        for _ in range(3):
+    times = {False: [], True: []}
+    for _ in range(150):
+        for enabled in (False, True):
+            flight.configure(enabled=enabled)
             t0 = time.perf_counter()
-            for _ in range(iters):
-                pipe.detect(reqs)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t_off = measure(False)
-    t_on = measure(True)
+            pipe.detect(reqs)
+            times[enabled].append(time.perf_counter() - t0)
+    t_off, t_on = (sorted(times[e])[len(times[e]) // 4]
+                   for e in (False, True))
     ratio = t_on / t_off
     assert ratio < 1.30, (
         "flight recorder clean-path overhead ratio %.3f (on=%.4fs "
@@ -410,7 +410,7 @@ def test_dbg_timeline_render(cr):
     from ingress_plus_tpu.control.dbg import render_timeline
     out = render_timeline(ct)
     assert "cycle " in out
-    assert "device_busy" in out or "host_prep" in out
+    assert "scan_dispatch" in out and "host_prep" in out
     assert "|" in out and "#" in out
     # disabled surface renders the explanation, not a stack trace
     assert "disabled" in render_timeline(
@@ -590,3 +590,279 @@ def test_bench_trend_refuses_cross_backend(tmp_path):
     assert not any("r02" in w for w in rep.get("warnings", []))
     art("r05", 100.0, "cpu")       # same-backend regression still gates
     assert trend(load_artifacts(str(tmp_path)))["status"] == "FAIL"
+
+
+# ------------------------------------- the span primitive (ISSUE 27)
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what the
+    primitive hands the profiler and when it enters and leaves."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.kwargs))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name, self.kwargs))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(trace_mod, "_annotation_cls", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_span_writes_ring_accumulator_and_annotation_from_one_call(
+        fake_annotation):
+    rec = FlightRecorder()
+    rec.set_cycle(7)
+    with rec.span(trace_mod.EV_SCAN_PACK, tag=2, arg=3) as sp:
+        time.sleep(0.002)
+    # (a) the ring's begin/end pair, under the thread's ambient cycle id
+    events = rec.snapshot()["events"]
+    assert [(e[2], e[3], e[4], e[5]) for e in events] == [
+        (trace_mod.EV_SCAN_PACK, PH_B, 7, 2),
+        (trace_mod.EV_SCAN_PACK, PH_E, 7, 2)]
+    assert events[0][6] == 3                      # arg rides the begin
+    ring_us = (events[1][1] - events[0][1]) // 1000
+    # (b) the cycle's accumulator holds the same elapsed time, once
+    assert sp.us == ring_us >= 2000
+    assert rec.take(7) == {"scan_pack": sp.us}
+    assert rec.take(7) == {}
+    # (c) one profiler annotation, entered and left, named and tagged
+    assert fake_annotation == [
+        ("enter", "ipt:scan_pack", {"cycle": 7, "n": 3}),
+        ("exit", "ipt:scan_pack", {"cycle": 7, "n": 3})]
+
+
+def test_spans_nest_under_the_cycle_id_across_threads(fake_annotation):
+    """A span opened on a worker that the cycle id travelled to
+    (flight.scoped) accumulates under that cycle, beside the spans of
+    the thread that owns the cycle; begin/end is the same primitive."""
+    import threading
+
+    rec = FlightRecorder()
+    rec.set_cycle(11)
+    env = rec.span(trace_mod.EV_CYCLE, arg=4).begin()
+
+    def work():
+        with rec.span(trace_mod.EV_SCAN_LAUNCH, arg=2):
+            with rec.span(trace_mod.EV_SCAN_WAIT):
+                pass
+
+    t = threading.Thread(target=lambda: rec.scoped(11, work))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    with rec.span(trace_mod.EV_CONFIRM_FOLD):
+        pass
+    rec.span_at(trace_mod.EV_HANDOFF, 1_000, 6_000, tag=1)
+    env.end()
+    acc = rec.take(11)
+    assert set(acc) == {"scan_launch", "scan_wait", "confirm_fold",
+                        "lane_handoff"}
+    assert acc["lane_handoff"] == 5               # (6000 - 1000) ns
+    assert acc["scan_launch"] >= acc["scan_wait"]   # nested inside it
+    # the envelope itself is a span (ring + annotation), not a sub-stage
+    spans = trace_mod.match_spans(rec.snapshot()["events"])
+    assert {s[1] for s in spans} == {
+        trace_mod.EV_CYCLE, trace_mod.EV_SCAN_LAUNCH,
+        trace_mod.EV_SCAN_WAIT, trace_mod.EV_CONFIRM_FOLD,
+        trace_mod.EV_HANDOFF}
+    assert {s[2] for s in spans} == {11}
+    # a back-dated span is ring-only: nothing for it on the profiler
+    names = [n for kind, n, _kw in fake_annotation if kind == "enter"]
+    assert sorted(names) == ["ipt:confirm_fold", "ipt:cycle",
+                             "ipt:scan_launch", "ipt:scan_wait"]
+
+
+def test_span_is_a_noop_without_the_flight_recorder(fake_annotation):
+    rec = FlightRecorder(enabled=False)
+    sp = rec.span(trace_mod.EV_SCAN_PACK, arg=3)
+    assert sp is trace_mod._NO_SPAN       # no object built per call
+    with sp as inner:
+        pass
+    assert inner.us == 0 and sp.begin() is sp and sp.end() is None
+    rec.span_at(trace_mod.EV_HANDOFF, 0, 5_000)
+    assert rec.take(0) == {} and rec.snapshot()["events"] == []
+    assert fake_annotation == []
+
+
+def test_accumulators_nobody_takes_stay_bounded(fake_annotation):
+    rec = FlightRecorder()
+    for cid in range(1, 200):
+        rec.span_at(trace_mod.EV_SCAN_PACK, 0, 1_000, cycle=cid)
+    assert len(rec._acc) <= trace_mod._MAX_OPEN_CYCLES
+    assert rec.take(199) == {"scan_pack": 1} and rec.take(1) == {}
+    rec.reset()
+    assert rec._acc == {}
+
+
+def test_batch_trace_carries_sub_spans_inside_their_stages(cr):
+    """Every dispatch's sub-spans lie inside the stage they open, and
+    the sub-stage histograms count dispatches (drain_idle too: the
+    loop's wait for each dispatch's work, empty drains included)."""
+    pipe = DetectionPipeline(cr, mode="block")
+    b = Batcher(pipe, max_batch=8)
+    try:
+        _serve(b, _reqs(24))
+        traces = b.traces.snapshot()
+        n_batches = b.hist["batch"].total
+    finally:
+        b.close()
+    assert traces and n_batches == len(traces)
+    for t in traces:
+        sub = t["sub_us"]
+        assert set(sub) == set(trace_mod.PER_DISPATCH), sub
+        assert (sub["scan_pack"] + sub["scan_launch"] + sub["scan_wait"]
+                <= t["engine_us"])
+        assert sub["confirm_walk"] + sub["confirm_fold"] <= t["confirm_us"]
+        assert sub["lane_handoff"] <= t["batch_us"]
+    for name in trace_mod.PER_DISPATCH:
+        assert b.subhist[name].total == n_batches, name
+    assert set(b.subhist) == set(trace_mod.SUBSTAGES)
+    assert set(trace_mod.SUBSTAGES) == set(trace_mod.PER_DISPATCH) | {
+        "reply"}
+    # the exemplars' stage dict names the parts too
+    stages = b.slow.snapshot()[0]["batch"]
+    assert {"scan_launch_us", "confirm_fold_us", "gc_us"} <= set(stages)
+
+
+def test_sub_stage_series_are_absent_without_the_recorder(cr):
+    flight.configure(enabled=False)
+    pipe = DetectionPipeline(cr, mode="block")
+    b = Batcher(pipe, max_batch=8)
+    try:
+        vs = _serve(b, _reqs(8))
+        assert all(v.resolved_ns == 0 for v in vs)
+        assert all(h.total == 0 for h in b.subhist.values())
+        assert b.traces.snapshot()[0]["sub_us"] == {}
+        from ingress_plus_tpu.serve.server import ServeLoop
+        text = ServeLoop(b, socket_path="/tmp/ipt-flight-test3.sock"
+                         )._metrics_text()
+        assert 'stage="scan"' in text and 'stage="scan_pack"' not in text
+        assert "ipt_device_launches_total" in text
+    finally:
+        b.close()
+
+
+def test_lane_handoff_is_the_callers_wait_less_the_closures_run():
+    from ingress_plus_tpu.serve.lanes import Lane
+
+    lane = Lane(0)
+    try:
+        flight.set_cycle(5)
+        t0 = time.monotonic_ns()
+        assert lane.call(lambda: time.sleep(0.02) or 41, 10.0) == 41
+        wall_us = (time.monotonic_ns() - t0) // 1000
+    finally:
+        lane.close()
+    handoff = flight.take(5)["lane_handoff"]
+    assert 0 <= handoff <= wall_us - 20_000
+    snap = flight.snapshot()
+    root = {t["tid"]: t["root"] for t in snap["threads"]}
+    spans = [s for s in trace_mod.match_spans(snap["events"])
+             if s[1] == trace_mod.EV_HANDOFF]
+    # to the worker (tag 0, on its ring), and back (tag 1, the caller's)
+    assert [(s[3], root[s[0]] == "lane_worker") for s in spans] == [
+        (0, True), (1, False)]
+    assert sum((s[6] - s[5]) // 1000 for s in spans) == pytest.approx(
+        handoff, abs=2)
+    # lane_call covers the whole wait on the caller's thread
+    (call,) = [s for s in trace_mod.match_spans(snap["events"])
+               if s[1] == trace_mod.EV_LANE_CALL]
+    assert (call[6] - call[5]) // 1000 >= 20_000 + handoff - 2
+
+
+def test_confirm_pool_workers_stamp_no_lane_handoff(cr):
+    pipe = DetectionPipeline(cr, mode="block", confirm_workers=2)
+    try:
+        flight.set_cycle(9)
+        pipe.detect(_reqs(8))
+    finally:
+        pipe.confirm_pool.close()
+    assert "lane_handoff" not in flight.take(9)
+
+
+def test_gc_watch_counts_pauses_and_records_spans():
+    import gc
+
+    rec = FlightRecorder()
+    rec.register_thread("test")            # the hook never registers one
+    watch = trace_mod.GcWatch(rec)
+    watch.install()
+    watch.install()                        # idempotent
+    try:
+        before = list(watch.collections_by_gen)
+        gc.collect()
+        gc.collect(0)
+    finally:
+        watch.uninstall()
+    assert gc.callbacks.count(watch._on_gc) == 0
+    assert watch.collections_by_gen[2] == before[2] + 1
+    assert watch.collections_by_gen[0] >= before[0] + 1
+    assert watch.pause_us() == sum(watch.pause_us_by_gen) > 0
+    spans = [s for s in trace_mod.match_spans(rec.snapshot()["events"])
+             if s[1] == trace_mod.EV_GC]
+    assert {s[4] for s in spans} >= {0, 2}         # arg = generation
+    gc.collect()                           # uninstalled: counts stay
+    assert watch.collections_by_gen[2] == before[2] + 1
+
+
+def test_empty_drains_count_for_the_dispatch_that_ends_them(cr):
+    """An idle server's 50 ms drain time-outs are nobody's until a
+    dispatch follows: a scrape after the traffic has stopped reads no
+    more idle time than one before it stopped."""
+    pipe = DetectionPipeline(cr, mode="block")
+    b = Batcher(pipe, max_batch=8)
+    try:
+        _serve(b, _reqs(4))
+        time.sleep(0.3)                    # ~6 empty drains
+        assert b.subhist["drain_idle"].total == b.hist["batch"].total
+        idle_before = b.subhist["drain_idle"].sum_us
+        _serve(b, _reqs(4))
+        waited = b.subhist["drain_idle"].sum_us - idle_before
+        last = b.traces.snapshot()[-1]["sub_us"]["drain_idle"]
+    finally:
+        b.close()
+    assert waited >= 250_000               # the idle stretch, counted once
+    assert last <= waited
+
+
+def test_gc_hook_takes_no_lock_and_registers_nothing():
+    """A collection starts wherever its thread allocates — also inside
+    the recorder's own locked sections (thread registration allocates
+    a ring under the lock).  The hook must not need that lock: a
+    thread with no ring yet only counts; it is not registered from
+    inside a collection."""
+    import gc
+    import threading
+
+    rec = FlightRecorder()
+    watch = trace_mod.GcWatch(rec)
+    watch.install()
+    done = []
+
+    def worker():
+        # no ring yet on this thread, and the registration lock is
+        # held, as it is while _register builds the ring
+        with rec._lock:
+            gc.collect()
+        done.append(len(rec.snapshot()["threads"]))
+
+    t = threading.Thread(target=worker)
+    try:
+        before = watch.collections_by_gen[2]
+        t.start()
+        t.join(timeout=20)
+        assert not t.is_alive(), "the GC hook waited for the lock"
+    finally:
+        watch.uninstall()
+    assert watch.collections_by_gen[2] == before + 1
+    assert done == [0]                     # counted, nothing registered

@@ -10,6 +10,7 @@ import socket
 import subprocess
 import sys
 import time
+import urllib.error
 import urllib.request
 from pathlib import Path
 
@@ -37,7 +38,8 @@ def server(tmp_path_factory):
         [sys.executable, "-m", "ingress_plus_tpu.serve",
          "--socket", sock, "--http-port", str(PORT),
          "--rules-dir", str(rules_dir), "--platform", "cpu",
-         "--max-delay-us", "1000", "--no-warmup"],
+         "--max-delay-us", "1000", "--no-warmup",
+         "--trace-dir", str(tmp / "trace")],
         cwd=str(REPO), env=env, stderr=subprocess.PIPE, text=True)
     for _ in range(600):
         if Path(sock).exists():
@@ -62,6 +64,19 @@ def server(tmp_path_factory):
 def _get(path):
     return urllib.request.urlopen(
         "http://127.0.0.1:%d%s" % (PORT, path), timeout=10).read()
+
+
+def _post(path):
+    """(status, decoded JSON body) of an empty POST."""
+    import urllib.error
+
+    req = urllib.request.Request(
+        "http://127.0.0.1:%d%s" % (PORT, path), data=b"", method="POST")
+    try:
+        with urllib.request.urlopen(req, timeout=120) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, json.loads(e.read())
 
 
 def _drive(sock_path, reqs):
@@ -245,3 +260,161 @@ def test_dbg_latency_parses_live_endpoints(server, capsys):
         assert stage in rows, out
         assert rows[stage][0] > 0          # count
     assert "slowest requests" in out
+
+
+# ------------------------------- sub-spans, GC, profiler (ISSUE 27)
+
+def _wave(base, n=12):
+    from ingress_plus_tpu.serve.normalize import Request
+
+    return [(Request(uri="/q?a=%d+union+select+2" % i if i % 4 == 0
+                     else "/item/%d?q=benign" % i,
+                     request_id=str(base + i)), base + i)
+            for i in range(n)]
+
+
+def test_sub_stage_series_and_counters_after_traffic(server):
+    """Every series this PR adds is on /metrics after traffic, and per
+    dispatch the sub-spans lie inside the stage they open."""
+    from ingress_plus_tpu.utils.trace import (
+        PER_DISPATCH, SUBSTAGES, stage_breakdown_from_metrics)
+
+    got = _drive(server, _wave(5000))
+    assert got[5000]["attack"] and not got[5001]["attack"]
+    metrics = _get("/metrics").decode()
+    sb = stage_breakdown_from_metrics(metrics)
+    for stage in SUBSTAGES:
+        assert sb[stage]["count"] > 0, stage
+    # per-dispatch sub-stages count dispatches; reply counts requests
+    for stage in PER_DISPATCH:
+        assert sb[stage]["count"] == sb["batch"]["count"], stage
+    assert sb["reply"]["count"] >= len(got)
+    values = {ln.split()[0]: float(ln.split()[1])
+              for ln in metrics.splitlines()
+              if ln and not ln.startswith("#")}
+    assert values["ipt_device_launches_total"] >= 7 * sb["batch"]["count"]
+    for g in "012":
+        assert 'ipt_gc_pause_us_total{generation="%s"}' % g in values
+        assert 'ipt_gc_collections_total{generation="%s"}' % g in values
+    # traffic allocates: the youngest generation has collected by now
+    assert values['ipt_gc_collections_total{generation="0"}'] > 0
+    assert values['ipt_gc_pause_us_total{generation="0"}'] > 0
+    # CPU reports no device memory: the gauge is left out, not zeroed
+    assert "ipt_device_memory_peak_bytes" not in metrics
+    assert ("# HELP ipt_lane_busy_us_sum host clock around launch and "
+            "wait per lane (us)") in metrics
+
+    traces = json.loads(_get("/traces"))["traces"]
+    assert traces
+    full = 0
+    for t in traces:
+        # a cycle that scanned nothing (its one request went to the
+        # oversized side lane) has no scan spans: absent reads 0
+        sub = {name: t["sub_us"].get(name, 0)
+               for name in PER_DISPATCH}
+        full += set(t["sub_us"]) == set(PER_DISPATCH)
+        assert (sub["scan_pack"] + sub["scan_launch"] + sub["scan_wait"]
+                <= t["engine_us"]), t
+        assert (sub["confirm_walk"] + sub["confirm_fold"]
+                <= t["confirm_us"]), t
+        assert t["gc_us"] >= 0
+    assert full >= 1
+
+    # a slow exemplar names its part: sub-spans and the cycle's GC time
+    slow = json.loads(_get("/debug/slow"))["slowest"]
+    ex = {e["request_id"]: e for e in slow}["5000"]
+    for name in PER_DISPATCH:
+        assert name + "_us" in ex["batch"], name
+    assert "gc_us" in ex["batch"]
+
+
+def test_forced_collection_moves_the_gc_counters():
+    """In-process (a collection cannot be forced in another process):
+    the hook the serve entry point installs counts a forced
+    gc.collect() on /metrics, by generation."""
+    import gc
+
+    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
+    from ingress_plus_tpu.compiler.seclang import parse_seclang
+    from ingress_plus_tpu.models.pipeline import DetectionPipeline
+    from ingress_plus_tpu.serve.batcher import Batcher
+    from ingress_plus_tpu.serve.server import ServeLoop
+    from ingress_plus_tpu.utils.trace import gc_watch
+
+    def counters(serve):
+        return {ln.split()[0]: float(ln.split()[1])
+                for ln in serve._metrics_text().splitlines()
+                if ln.startswith("ipt_gc_")}
+
+    b = Batcher(DetectionPipeline(
+        compile_ruleset(parse_seclang(TINY_RULES)), mode="block"),
+        max_batch=8)
+    gc_watch.install()
+    try:
+        serve = ServeLoop(b, socket_path="/tmp/ipt-obs-gc.sock")
+        before = counters(serve)
+        gc.collect()
+        after = counters(serve)
+    finally:
+        gc_watch.uninstall()
+        b.close()
+    key = 'ipt_gc_collections_total{generation="2"}'
+    assert after[key] == before[key] + 1
+    assert (after['ipt_gc_pause_us_total{generation="2"}']
+            > before['ipt_gc_pause_us_total{generation="2"}'])
+
+
+def test_debug_profile_writes_a_trace_holding_the_spans(server):
+    """POST /debug/profile?seconds= traces the next stretch with the
+    Python tracer off, refuses a second session meanwhile, and the
+    .xplane.pb holds the program's spans on the profiler's clock."""
+    import threading
+
+    first = {}
+
+    def profile():
+        first["answer"] = _post("/debug/profile?seconds=1.0")
+
+    t = threading.Thread(target=profile)
+    t.start()
+    time.sleep(0.3)
+    busy_status, busy = _post("/debug/profile?seconds=0.2")
+    for k in range(3):
+        _drive(server, _wave(6000 + 100 * k))
+    t.join(timeout=120)
+    assert not t.is_alive()
+    assert busy_status == 409 and "running" in busy["error"]
+    status, written = first["answer"]
+    assert status == 200
+    assert written["path"].endswith(".xplane.pb")
+    assert written["bytes"] == os.path.getsize(written["path"]) > 0
+    assert written["seconds"] >= 1.0 and written["write_s"] >= 0
+    assert written["options"]["python_tracer_level"] == 0
+
+    from jax.profiler import ProfileData
+
+    spans = {}
+    for plane in ProfileData.from_file(written["path"]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ipt:"):
+                    spans.setdefault(e.name, []).append(dict(e.stats))
+    assert spans["ipt:scan_launch"], sorted(spans)
+    for name in ("ipt:cycle", "ipt:host_prep", "ipt:scan_pack",
+                 "ipt:scan_dispatch", "ipt:scan_wait", "ipt:confirm_walk",
+                 "ipt:finalize_join", "ipt:confirm_fold", "ipt:lane_call",
+                 "ipt:drain_idle"):
+        assert name in spans, (name, sorted(spans))
+    # per-request instants stay out of the profiler's trace
+    assert not {"ipt:submit", "ipt:verdict", "ipt:reply"} & set(spans)
+    # the annotation carries the cycle id the ring stitches by
+    assert all(s["cycle"] > 0 for s in spans["ipt:scan_launch"])
+    # once it has ended the switch is free again; bad requests say so
+    assert _post("/debug/profile?seconds=0.2")[0] == 200
+    assert _post("/debug/profile?seconds=0")[0] == 400
+    assert _post("/debug/profile?seconds=abc")[0] == 400
+    try:
+        _get("/debug/profile?seconds=0.2")
+        raise AssertionError("GET /debug/profile must be refused")
+    except urllib.error.HTTPError as e:
+        assert e.code == 405

@@ -73,8 +73,10 @@ def server(tmp_path_factory, loadgen_bin):
          "--spool-dir", str(spool), "--export-interval-s", "0.5"],
         cwd=str(REPO), env=env,
         stderr=subprocess.PIPE, text=True)
-    # wait for the socket
-    for _ in range(600):
+    # wait for the socket: the start is ~100 CPU-seconds of XLA compiles
+    # (the warm-up's 42 shapes), 20 s of wall on an idle 8-core host and
+    # past 60 s beside the suite's other xdist workers
+    for _ in range(1800):
         if Path(sock).exists():
             try:
                 s = socket.socket(socket.AF_UNIX)

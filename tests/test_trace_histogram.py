@@ -207,3 +207,78 @@ def test_dbg_render_latency_on_real_shapes():
     # missing histograms: explicit, not a crash
     out2 = render_latency("", {"slowest": []})
     assert "MISSING" in out2
+
+
+# ------------------------------------------------- sub-stages (ISSUE 27)
+
+def _exposition(stage_us: dict, n: int = 50) -> str:
+    lines = ["# TYPE ipt_stage_us histogram"]
+    for stage, us in stage_us.items():
+        h = Histogram()
+        for _ in range(n):
+            h.observe(us)
+        lines += h.prometheus("ipt_stage_us", {"stage": stage})
+    return "\n".join(lines)
+
+
+STAGE_US = {"queue": 10, "prep": 20, "scan": 100, "confirm": 30,
+            "batch": 160, "e2e": 170}
+SUBSTAGE_US = {"scan_pack": 5, "scan_launch": 60, "scan_wait": 30,
+               "confirm_walk": 20, "confirm_fold": 8, "lane_handoff": 4,
+               "drain_idle": 400, "reply": 90}
+
+
+def test_substages_render_and_parse_beside_the_stages():
+    from ingress_plus_tpu.utils.trace import SUBSTAGES
+
+    assert set(SUBSTAGE_US) == set(SUBSTAGES)
+    assert not set(SUBSTAGES) & set(STAGES)
+    sb = stage_breakdown_from_metrics(
+        _exposition({**STAGE_US, **SUBSTAGE_US}))
+    assert set(sb) == set(STAGES) | set(SUBSTAGES)
+    for name, us in SUBSTAGE_US.items():
+        assert sb[name]["count"] == 50
+        assert us / 2 <= sb[name]["p50_us"] <= us * 2, name
+
+
+def test_substages_stay_out_of_the_stage_sum():
+    """The bench's decomposition check adds queue + prep + scan +
+    confirm; sub-stages lie inside scan and confirm and must not be
+    added a second time."""
+    import bench
+
+    class StubServe:
+        def __init__(self, text):
+            self._text = text
+
+        def _metrics_text(self):
+            return self._text
+
+    plain = bench.scrape_stage_breakdown(StubServe(_exposition(STAGE_US)))
+    both = bench.scrape_stage_breakdown(
+        StubServe(_exposition({**STAGE_US, **SUBSTAGE_US})))
+    assert both["sum_check"] == plain["sum_check"]
+    assert set(both) == set(plain)
+
+
+def test_dbg_latency_lists_substages_after_the_stages():
+    from ingress_plus_tpu.control.dbg import render_latency
+    from ingress_plus_tpu.utils.trace import SUBSTAGES
+
+    out = render_latency(_exposition({**SUBSTAGE_US, **STAGE_US}),
+                         {"slowest": []})
+    rows = [ln.split()[0] for ln in out.splitlines()[1:]
+            if ln.strip() and ln.split()[0] in STAGES + SUBSTAGES]
+    assert rows == list(STAGES) + list(SUBSTAGES)
+
+
+def test_batch_trace_stages_carry_sub_spans_and_gc():
+    t = BatchTrace(ts=1.0, n_requests=2, n_stream_items=0,
+                   queue_delay_us=100, batch_us=1000, engine_us=600,
+                   confirm_us=100, prep_us=200,
+                   sub_us={"scan_launch": 400, "scan_wait": 150},
+                   gc_us=7)
+    st = t.stages()
+    assert st["scan_launch_us"] == 400 and st["scan_wait_us"] == 150
+    assert st["gc_us"] == 7
+    assert st["other_us"] == 100       # sub-spans are not subtracted
