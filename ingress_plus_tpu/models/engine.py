@@ -166,10 +166,10 @@ def fold_rows(
     """Fold one bucket's rows into the per-REQUEST accumulators: factor
     hits (Q, F) and stream-variant coverage (Q, N_SV), each a running
     max.  The first half of :func:`map_match_words`, split out so a
-    multi-bucket dispatch folds bucket by bucket — its executables key
-    on one bucket's (B, Q), never on the combination of buckets a cycle
-    happens to contain (an eager concatenate of the buckets' match
-    words compiled a fresh program per combination, in front of
+    multi-bucket dispatch folds bucket by bucket
+    (:func:`scan_fold_bucket`), never over the combination of buckets a
+    cycle happens to contain (an eager concatenate of the buckets'
+    match words compiled a fresh program per combination, in front of
     traffic)."""
     Q = req_fh.shape[0]
     # factor hits: gather each factor's word, test its bit     (B, F)
@@ -186,10 +186,17 @@ def expand_requests(
     tables: EngineTables,
     req_fh: jax.Array,        # (Q, F) float32 factor hits per request
     req_sv: jax.Array,        # (Q, N_SV) float32 stream-variant coverage
+    num_requests: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Per-request factor hits → (rule_hits, class_hits, scores): the
     second half of :func:`map_match_words`, and the one stage whose
-    cost scales with rule count — it runs once per dispatch."""
+    cost scales with rule count — it runs once per dispatch.
+    ``num_requests`` (static) takes the accumulators' first rows before
+    anything else: a dispatch folds into accumulators of the engine's
+    fixed request capacity and expands its own Q tier of them, so this
+    program — and the (Q, R) result the host fetches — keys on Q."""
+    if num_requests is not None:
+        req_fh, req_sv = req_fh[:num_requests], req_sv[:num_requests]
     # factor → prefilter-GROUP hits (G ≤ R equivalence classes of rules
     # with identical candidate behavior — clone rules cost nothing here)
     req_group = jnp.dot(req_fh, tables.factor_rule,
@@ -250,8 +257,71 @@ def map_match_words(
 
 map_match_words_jit = jax.jit(
     map_match_words, static_argnames=("num_requests",))
-fold_rows_jit = jax.jit(fold_rows)
-expand_requests_jit = jax.jit(expand_requests)
+expand_requests_jit = jax.jit(
+    expand_requests, static_argnames=("num_requests",))
+
+
+#: bytes after the L token bytes of a packed bucket row: the row's
+#: length and its owning request (little-endian int32 each), then its
+#: N_SV stream-variant flags, padded to whole words
+ROW_TAIL = -(-(8 + N_SV) // 4) * 4
+
+
+def empty_bucket(B: int, L: int) -> np.ndarray:
+    """One bucket as the host ships it: ``(B, L + ROW_TAIL)`` uint8, a
+    shape that depends on (B, L) alone, so a bucket is ONE
+    host-to-device transfer.  Fill it through :func:`bucket_views`."""
+    return np.zeros((B, L + ROW_TAIL), np.uint8)
+
+
+def bucket_views(packed: np.ndarray):
+    """``(tokens (B, L) uint8, lengths (B,) int32, row_req (B,) int32,
+    row_sv (B, N_SV) uint8)`` as writable views of a packed bucket."""
+    L = packed.shape[1] - ROW_TAIL
+    ints = packed[:, L:L + 8].view("<i4")
+    return (packed[:, :L], ints[:, 0], ints[:, 1],
+            packed[:, L + 8:L + 8 + N_SV])
+
+
+def _split_bucket(packed: jax.Array):
+    """:func:`bucket_views` on the device, inside the bucket's program."""
+    L = packed.shape[1] - ROW_TAIL
+
+    def int32_at(col):
+        b = packed[:, col:col + 4].astype(jnp.int32)
+        return b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24)
+
+    return (packed[:, :L], int32_at(L), int32_at(L + 4),
+            packed[:, L + 8:L + 8 + N_SV])
+
+
+def _match_words(impl: str, tables: EngineTables, scanner, tokens,
+                 lengths, interpret: bool) -> jax.Array:
+    """One bucket's sticky match words (B, W) uint32 under ``impl``;
+    ``scanner`` is the Pallas scanner of the Pallas implementations
+    (it carries its own packed full-width tables), None otherwise."""
+    if scanner is not None:
+        return scanner(tokens, lengths, interpret=interpret)[0]
+    if impl == "take":
+        return scan_bytes_jit(tables.scan, tokens, lengths)[0]
+    return scan_pairs_jit(tables.scan, tokens, lengths)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
+def scan_fold_bucket(tables: EngineTables, scanner, packed: jax.Array,
+                     req_fh: jax.Array, req_sv: jax.Array,
+                     impl: str, interpret: bool = False):
+    """One bucket's whole device work as ONE program: unpack the
+    shipped buffer, scan its rows, fold them into the per-request
+    accumulators (:func:`fold_rows`).  The accumulators have the
+    engine's fixed request capacity, so the executable keys on the
+    bucket's (B, L) alone — not on the dispatch's Q tier, and never on
+    the set of buckets a cycle contains.  (``scan`` stays in the name:
+    the profiler prints ``jit_<name>`` and the benchmark's roofline
+    finds the scan programs by that word.)"""
+    tokens, lengths, row_req, row_sv = _split_bucket(packed)
+    m = _match_words(impl, tables, scanner, tokens, lengths, interpret)
+    return fold_rows(tables, m, row_req, row_sv, req_fh, req_sv)
 
 
 def detect_rows(
@@ -341,6 +411,14 @@ class DetectionEngine:
         #: (ipt_device_launches_total); carried across hot swaps
         self.device_launches = 0
         self._launch_lock = named_lock("DetectionEngine._launch_lock")
+        #: rows of the per-request accumulators every bucket program
+        #: folds into.  The pipeline's warm-up sets it to its largest Q
+        #: tier; a larger dispatch grows it, and its buckets then
+        #: compile anew.  Carried across hot swaps.
+        self.request_capacity = 0
+        # the zero accumulators a dispatch starts from, made once:
+        # {(capacity, F, device): (req_fh, req_sv)}; never donated
+        self._zero_acc: dict = {}
 
     def rebuilt(self, cr: CompiledRuleset) -> "DetectionEngine":
         """Fresh engine of the SAME kind on a new ruleset — the batcher
@@ -351,6 +429,7 @@ class DetectionEngine:
         eng.pallas_interpret = self.pallas_interpret
         eng.bakeoff = self.bakeoff
         eng.device_launches = self.device_launches
+        eng.request_capacity = self.request_capacity
         return eng
 
     def device_info(self) -> dict:
@@ -398,6 +477,7 @@ class DetectionEngine:
         self._pallas3 = None
         self._pallas3_dev = {}
         self._device_tables = {}
+        self._zero_acc = {}
 
     def tables_for(self, device):
         """The (tables, head_tables) pair replicated to ``device`` —
@@ -446,15 +526,16 @@ class DetectionEngine:
             self._pallas3_dev[device] = sc
         return sc
 
-    def scan_exec_shape(self, B: int, L: int):
-        """Executable-keying shape of one (B, L) scan dispatch — the
-        pallas3 Mosaic kernel keys on tile-padded rectangles (several
-        bucket shapes share one executable), everything else on the
-        exact bucket shape.  The pipeline recompile gauge reads this
-        so the zero-serve-time-recompile pin counts REAL compiles."""
+    def _scanner(self, device=None):
+        """The Pallas scanner behind ``scan_impl`` (only pallas3 can be
+        placed on ``device``); None for the XLA lowerings."""
+        if self.scan_impl == "pallas":
+            return self._pallas_scanner()
+        if self.scan_impl == "pallas2":
+            return self._pallas_pair_scanner()
         if self.scan_impl == "pallas3":
-            return self._pallas_byte_scanner().exec_shape(B, L)
-        return (B, L)
+            return self._pallas_byte_scanner(device)
+        return None
 
     def drop_compiled(self) -> None:
         """Forget every compiled executable (the recompile_storm fault
@@ -466,26 +547,16 @@ class DetectionEngine:
         self._pallas3 = None
         self._pallas3_dev = {}
         self._device_tables = {}
+        self._zero_acc = {}
 
     def scan_words(self, tabs: EngineTables, tokens, lengths, device=None):
         """One bucket's sticky match words (B, W) uint32 under
-        ``scan_impl`` — the per-bucket scan every dispatch path and the
-        parity checks (ops/parity.py) share.  ``tabs`` is what the XLA
-        lowerings scan (full or head-sliced, default or per-device);
-        the Pallas scanners carry their own packed full-width tables,
-        and only pallas3 can be placed on ``device``."""
-        impl = self.scan_impl
-        if impl == "take":
-            return scan_bytes_jit(tabs.scan, tokens, lengths)[0]
-        if impl == "pallas":
-            scanner = self._pallas_scanner()
-        elif impl == "pallas2":
-            scanner = self._pallas_pair_scanner()
-        elif impl == "pallas3":
-            scanner = self._pallas_byte_scanner(device)
-        else:
-            return scan_pairs_jit(tabs.scan, tokens, lengths)[0]
-        return scanner(tokens, lengths, interpret=self.pallas_interpret)[0]
+        ``scan_impl``, as a program of its own — the legacy per-bucket
+        path and the parity checks (ops/parity.py) use it.  ``tabs`` is
+        what the XLA lowerings scan (full or head-sliced, default or
+        per-device)."""
+        return _match_words(self.scan_impl, tabs, self._scanner(device),
+                            tokens, lengths, self.pallas_interpret)
 
     def _rule_hits_device(self, tokens, lengths, row_req, row_sv,
                           num_requests: int):
@@ -525,18 +596,21 @@ class DetectionEngine:
     def detect_device_multi(self, buckets, num_requests: int,
                             head_only: bool = False, device=None):
         """Multi-bucket dispatch with ONE rule expansion (docs/
-        SCAN_KERNEL.md): each length bucket scans in its own jit
-        program and folds its rows into per-request factor hits
-        (``fold_rows``) — executable space stays ADDITIVE per bucket
-        shape, (B, L) for the scan and (B, Q) for the fold, the
-        serving-stability property the per-bucket path always had — and
-        the rule-count-scaling factor→rule expansion
-        (``expand_requests``) runs once per dispatch.  (Any program
-        keyed on the bucket SET would multiply the executable space by
-        every combination of tier sizes a traffic mix produces; the
-        serve plane recompiled its way into brownout under exactly that
-        — the bench's detect_k, one static batch shape repeated, is
-        where full fusion pays.)
+        SCAN_KERNEL.md): ``buckets`` are packed buffers
+        (:func:`empty_bucket`), and each is one call into JAX — one
+        host-to-device transfer and one program
+        (:func:`scan_fold_bucket`) that scans the bucket's rows and
+        folds them into per-request factor hits.  The rule-count-scaling
+        factor→rule expansion (``expand_requests``) then runs once, on
+        the dispatch's own Q tier of the accumulators: ``buckets + 1``
+        launches.  Executable space stays ADDITIVE — (B, L) for a
+        bucket, Q for the expansion — the serving-stability property
+        the per-bucket path always had.  (Any program keyed on the
+        bucket SET would multiply the executable space by every
+        combination of tier sizes a traffic mix produces; the serve
+        plane recompiled its way into brownout under exactly that — the
+        bench's detect_k, one static batch shape repeated, is where
+        full fusion pays.)
 
         ``head_only=True`` (caller asserts no row carries a
         body/response stream-variant) scans the sliced head tables —
@@ -544,13 +618,13 @@ class DetectionEngine:
         (Q, R) rule-hit device array without blocking.
 
         ``device`` pins the dispatch to one chip of the serve mesh
-        (docs/MESH_SERVING.md): inputs are device_put there and the
-        scan runs against that device's replicated tables
-        (``tables_for``), so N lanes' dispatches execute concurrently
-        on N chips.  The legacy pallas/pallas2 kernels are built on
-        the default device's tables — for them ``device`` is ignored
-        (documented limitation); pallas3 honors it via per-device
-        scanner replicas (NamedSharding placement)."""
+        (docs/MESH_SERVING.md): the programs run against that device's
+        replicated tables (``tables_for``) and accumulators, and the
+        buffers follow them there, so N lanes' dispatches execute
+        concurrently on N chips.  The legacy pallas/pallas2 kernels are
+        built on the default device's tables — for them ``device`` is
+        ignored (documented limitation); pallas3 honors it via
+        per-device scanner replicas (NamedSharding placement)."""
         faults.sleep_if("dispatch_hang")
         faults.raise_if("dispatch_raise")
         # scan_launch: the host→device transfers and the enqueue of
@@ -562,45 +636,51 @@ class DetectionEngine:
     def _enqueue_multi(self, buckets, num_requests: int, head_only: bool,
                        device):
         """The body of :meth:`detect_device_multi` (its ``scan_launch``
-        span): transfers and program launches, counted as they go."""
+        span): one call per bucket and the expansion, counted as they
+        are made."""
+        if not buckets:
+            return np.zeros((num_requests, max(self.ruleset.n_rules, 1)),
+                            bool)
         pallas = self.scan_impl in ("pallas", "pallas2", "pallas3")
         # pallas3 is device-aware: its packed tables replicate per chip
         # like the sigpack, so mesh lanes keep the raw-byte path
-        use_device = device is not None and (
-            not pallas or self.scan_impl == "pallas3")
-        full_tabs, head_tabs = (self.tables, self.head_tables)
-        if use_device:
-            full_tabs, head_tabs = self.tables_for(device)
+        if device is not None and pallas and self.scan_impl != "pallas3":
+            device = None
+        full_tabs, head_tabs = self.tables_for(device)
         tabs = (head_tabs
                 if head_only and head_tabs is not None
                 and not pallas else full_tabs)
-        if not buckets:
-            R = self.ruleset.n_rules
-            self._count_launches(2)
-            return jnp.zeros((num_requests, max(R, 1)), bool)
+        scanner = self._scanner(device)
+        # committed arguments (a lane's tables and accumulators) place
+        # the program, and the host buffer follows them: the numpy
+        # array goes to the device inside the bucket's own call
+        req_fh, req_sv = self._accumulators(tabs, num_requests, device)
+        launched = 0
+        for packed in buckets:
+            req_fh, req_sv = scan_fold_bucket(
+                tabs, scanner, packed, req_fh, req_sv,
+                impl=self.scan_impl, interpret=self.pallas_interpret)
+            launched += 1
+        rule_hits = expand_requests_jit(tabs, req_fh, req_sv,
+                                        num_requests=num_requests)[0]
+        self._count_launches(launched + 1)
+        return rule_hits
 
-        def _dev(x):
-            return (jax.device_put(x, device) if use_device
-                    else jnp.asarray(x))
-
-        # fold bucket by bucket into per-request accumulators, expand
-        # to rules once: every executable keys on ONE bucket's shape
-        at = device if use_device else None
-        req_fh = jnp.zeros((num_requests, tabs.factor_word.shape[0]),
-                           jnp.float32, device=at)
-        req_sv = jnp.zeros((num_requests, np.shape(buckets[0][3])[1]),
-                           jnp.float32, device=at)
-        for tok, ln, rr, rs in buckets:
-            m = self.scan_words(tabs, _dev(tok), _dev(ln), device=at)
-            req_fh, req_sv = fold_rows_jit(
-                tabs, m, _dev(np.asarray(rr)), _dev(np.asarray(rs)),
-                req_fh, req_sv)
-        # programs enqueued: each jnp.zeros is two (the scalar's dtype
-        # conversion and its broadcast: jit_convert_element_type and
-        # jit_broadcast_in_dim in a device trace), each bucket a scan
-        # and a fold, and the one expansion below
-        self._count_launches(2 * 2 + 2 * len(buckets) + 1)
-        return expand_requests_jit(tabs, req_fh, req_sv)[0]
+    def _accumulators(self, tabs: EngineTables, num_requests: int,
+                      device):
+        """The zero (capacity, F) / (capacity, N_SV) accumulators a
+        dispatch of ``tabs`` starts from, on ``device``: made once per
+        shape and passed in, never donated, so no dispatch launches a
+        program to zero them."""
+        cap = self.request_capacity = max(self.request_capacity,
+                                          num_requests)
+        key = (cap, tabs.factor_word.shape[0], device)
+        acc = self._zero_acc.get(key)
+        if acc is None:
+            acc = self._zero_acc[key] = tuple(
+                jnp.zeros((cap, n), jnp.float32, device=device)
+                for n in (key[1], N_SV))
+        return acc
 
     def _count_launches(self, n: int) -> None:
         """``ipt_device_launches_total``: device programs enqueued by

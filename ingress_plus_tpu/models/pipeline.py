@@ -23,12 +23,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ingress_plus_tpu.compiler.ruleset import (
-    CompiledRuleset,
-    N_HEAD_SV,
-    VARIANTS,
-)
-from ingress_plus_tpu.compiler.seclang import CLASSES, STREAMS
+from ingress_plus_tpu.compiler.ruleset import CompiledRuleset, N_HEAD_SV
+from ingress_plus_tpu.compiler.seclang import CLASSES
 from ingress_plus_tpu.models.acl import AclStore
 from ingress_plus_tpu.models.confirm import ConfirmRule, parse_exclusion_token
 from ingress_plus_tpu.models.confirm_plane import (
@@ -37,7 +33,12 @@ from ingress_plus_tpu.models.confirm_plane import (
     launch_confirm,
     join_confirm,
 )
-from ingress_plus_tpu.models.engine import DetectionEngine
+from ingress_plus_tpu.models.engine import (
+    ROW_TAIL,
+    DetectionEngine,
+    bucket_views,
+    empty_bucket,
+)
 from ingress_plus_tpu.models.rule_stats import RuleStats
 from ingress_plus_tpu.utils import faults
 from ingress_plus_tpu.utils.trace import (
@@ -59,7 +60,6 @@ from ingress_plus_tpu.utils.trace import (
 MODE_STRENGTH = {0: 0, 1: 1, 3: 2, 2: 3}   # off, monitoring, safe_blocking, block
 MODE_NAME_STRENGTH = {"off": 0, "monitoring": 1, "safe_blocking": 2,
                       "block": 3}
-from ingress_plus_tpu.ops.scan import pad_rows
 from ingress_plus_tpu.serve.normalize import (
     Request,
     merged_rows_for_requests,
@@ -432,10 +432,9 @@ class DetectionPipeline:
         # entries — the batcher's hot-swap replay warms each lane's
         # device-bound executables too
         self.seen_lane_shapes: set = set()
-        # underlying executable shapes (per-(B, L) scan, per-(B, Q)
-        # fold and per-Q expansion jits, keyed per lane device — XLA
-        # executables are device-bound) — the recompile gauge's ground
-        # truth
+        # underlying executable shapes (per-(B, L) bucket programs
+        # and per-Q expansions, keyed per lane device — XLA executables
+        # are device-bound) — the recompile gauge's ground truth
         self._seen_exec: set = set()
         #: the outgoing generation's counters, frozen at the last
         #: hot-swap (drift's "before"; None until a swap happens)
@@ -564,28 +563,24 @@ class DetectionPipeline:
         """``(buckets, Q_pad)`` dispatch signatures whose executables
         cover every shape a cycle of up to ``max_batch`` requests can
         dispatch — derived from shapes alone, never from what a sample
-        corpus happens to contain: one scan executable per (pow2 row
-        tier B x L tier), one fold executable per (B x Q-pad tier), one
-        expansion per Q-pad tier (DetectionEngine.detect_device_multi).
-        A request yields at most one scan row per needed (stream,
-        variant), which bounds the row tier a Q-pad tier can fill.
-        Feed each to ``warm_shape`` / ``warm_lane_shape`` (they add the
-        head-sliced twin)."""
+        corpus happens to contain: one bucket executable (scan + fold)
+        per (pow2 row tier B x L tier) and one expansion per Q-pad tier
+        (DetectionEngine.detect_device_multi); a bucket's program does
+        not key on Q, so any Q tier may ride with any bucket.  A
+        request yields at most one scan row per needed (stream,
+        variant), which bounds the row tier.  Feed each to
+        ``warm_shape`` / ``warm_lane_shape`` (they add the head-sliced
+        twin)."""
         rpr = max(1, sum(len(v) for v in self._variants_for.values()))
         q_tiers = sorted({self._pad_q(n) for n in warm_sizes(max_batch)})
-        sigs: List[tuple] = []
+        shapes = []
         B = 8
         while B <= self._pad_q(max_batch * rpr, floor=8):
-            # the request tiers whose cycles can fill this row tier
-            qs = [q for q in q_tiers
-                  if self._pad_q(min(q, max_batch) * rpr, floor=8) >= B]
-            # every L tier once and every such Q tier once, in step
-            for i in range(max(len(self.L_BUCKETS), len(qs))):
-                sigs.append((
-                    ((B, self.L_BUCKETS[i % len(self.L_BUCKETS)]),),
-                    qs[i % len(qs)]))
+            shapes += [(B, L) for L in self.L_BUCKETS]
             B *= 2
-        return sigs
+        # every bucket shape once and every Q tier once, in step
+        return [((shapes[i % len(shapes)],), q_tiers[i % len(q_tiers)])
+                for i in range(max(len(shapes), len(q_tiers)))]
 
     def warm_grid(self, max_batch: int, lanes: Sequence = ()) -> int:
         """Compile and run once every executable of
@@ -599,6 +594,12 @@ class DetectionPipeline:
         from concurrent.futures import ThreadPoolExecutor
 
         sigs = self.warm_signatures(max_batch)
+        # the accumulators every bucket program folds into take their
+        # full size before the first compile, not when a large cycle
+        # first arrives (legacy engines have no such capacity)
+        if hasattr(self.engine, "request_capacity"):
+            self.engine.request_capacity = max(
+                self.engine.request_capacity, self._pad_q(max_batch))
         if lanes:
             slicing = getattr(self.engine, "head_slicing_active", None)
             heads = ((False, True) if slicing is not None and slicing()
@@ -629,39 +630,25 @@ class DetectionPipeline:
                                lane_key=None) -> int:
         """How many REAL jit executables a dispatch of this bucket set
         will compile fresh.  Fused engines (detect_device_multi): one
-        per unseen (B, L) scan shape, one per unseen (B, Q) fold shape,
-        one for an unseen Q expansion.  Legacy per-bucket engines
-        (MeshEngine): one per unseen (B, L, Q) fused executable — their
-        programs key on the request pad too and have no separate
-        mapping pass.  ``lane_key`` scopes the keys to one serve lane's
-        device (XLA executables are device-bound, so the same shape on
-        another chip IS a fresh compile — the gauge must not hide it).
-        Also records the shapes as seen."""
-        new = 0
-        if not fused:
-            for B, L in bucket_shapes:
-                key = ("legacy", B, L, Q_pad, lane_key)
-                if key not in self._seen_exec:
-                    new += 1
-                    self._seen_exec.add(key)
-            return new
-        # engines whose scan executables key on coarser-than-bucket
-        # shapes (the pallas3 Mosaic kernel keys on tile-padded
-        # rectangles) expose scan_exec_shape — without it the gauge
-        # would count phantom compiles for bucket shapes that share an
-        # already-warm executable (ISSUE 13)
-        shape_fn = getattr(self.engine, "scan_exec_shape", None)
-        for B, L in bucket_shapes:
-            kb, kl = shape_fn(B, L) if shape_fn is not None else (B, L)
-            for key in (("scan", kb, kl, head_ok, lane_key),
-                        ("fold", B, Q_pad, head_ok, lane_key)):
-                if key not in self._seen_exec:
-                    new += 1
-                    self._seen_exec.add(key)
-        ekey = ("expand", Q_pad, head_ok, lane_key)
-        if ekey not in self._seen_exec:
-            new += 1
-            self._seen_exec.add(ekey)
+        per unseen (B, L) bucket program — scan and fold together, at
+        the engine's request capacity — and one for an unseen Q
+        expansion.  Legacy per-bucket engines (MeshEngine): one per
+        unseen (B, L, Q) executable — their programs key on the request
+        pad too and have no separate mapping pass.  ``lane_key`` scopes
+        the keys to one serve lane's device (XLA executables are
+        device-bound, so the same shape on another chip IS a fresh
+        compile — the gauge must not hide it).  Also records the shapes
+        as seen."""
+        if fused:
+            cap = max(self.engine.request_capacity, Q_pad)
+            keys = [("bucket", B, L, cap, head_ok, lane_key)
+                    for B, L in bucket_shapes]
+            keys.append(("expand", Q_pad, head_ok, lane_key))
+        else:
+            keys = [("legacy", B, L, Q_pad, lane_key)
+                    for B, L in bucket_shapes]
+        new = len(set(keys) - self._seen_exec)
+        self._seen_exec.update(keys)
         return new
 
     def warm_lane_shape(self, buckets, Q_pad: int, head_ok: bool,
@@ -674,12 +661,8 @@ class DetectionPipeline:
         queue; callers fan shapes across ephemeral threads to overlap
         the per-lane compiles (one overlapped compile pass for an
         8-lane start, not 8 serial ones)."""
-        n_sv = len(STREAMS) * len(VARIANTS)
         multi = getattr(self.engine, "detect_device_multi", None)
-        bks = tuple(
-            (np.zeros((B, L), np.uint8), np.zeros((B,), np.int32),
-             np.zeros((B,), np.int32), np.zeros((B, n_sv), np.int8))
-            for B, L in buckets)
+        bks = tuple(empty_bucket(B, L) for B, L in buckets)
         self._count_new_executables(tuple(buckets), Q_pad, head_ok,
                                     fused=multi is not None,
                                     lane_key=lane.index)
@@ -689,8 +672,8 @@ class DetectionPipeline:
             np.asarray(multi(bks, Q_pad, head_only=head_ok,
                              device=lane.device))
         else:
-            for tok, lens, rreq, rsv in bks:
-                self.engine.detect(tok, lens, rreq, rsv, Q_pad)
+            for packed in bks:
+                self.engine.detect(*bucket_views(packed), Q_pad)
 
     def warm_shape(self, buckets, Q_pad: int,
                    head_ok: bool = False) -> None:
@@ -699,9 +682,9 @@ class DetectionPipeline:
         ``buckets`` is a bucket-set signature — a tuple of (B, L) row
         shapes, exactly a ``seen_shapes`` entry's first element (a
         legacy (B, L, Q) int triple is accepted for older callers).
-        dtypes must match the live path exactly (uint8 tokens from
-        pad_rows) — jit keys executables on dtype, so an int32 warm
-        compiles a cache entry real traffic never hits.
+        The zero buffers are the live path's own
+        (``empty_bucket``) — jit keys executables on dtype, so any
+        other warm compiles a cache entry real traffic never hits.
 
         When THIS pipeline's pack is word-tiered but the replayed entry
         came from an untiered incumbent (head_ok=False), the head-sliced
@@ -711,7 +694,6 @@ class DetectionPipeline:
         candidate dispatch hang and roll back a good rollout)."""
         if isinstance(buckets, int):     # legacy (B, L, Q) positional form
             buckets, Q_pad, head_ok = ((buckets, Q_pad),), head_ok, False
-        n_sv = len(STREAMS) * len(VARIANTS)
         multi = getattr(self.engine, "detect_device_multi", None)
         slicing = getattr(self.engine, "head_slicing_active", None)
         variants = [head_ok]
@@ -719,15 +701,12 @@ class DetectionPipeline:
                 and slicing is not None and slicing()):
             variants.append(True)
         for head in variants:
-            bks = tuple(
-                (np.zeros((B, L), np.uint8), np.zeros((B,), np.int32),
-                 np.zeros((B,), np.int32), np.zeros((B, n_sv), np.int8))
-                for B, L in buckets)
+            bks = tuple(empty_bucket(B, L) for B, L in buckets)
             if multi is not None:
                 np.asarray(multi(bks, Q_pad, head_only=head))
             else:
-                for tok, lens, rreq, rsv in bks:
-                    self.engine.detect(tok, lens, rreq, rsv, Q_pad)
+                for packed in bks:
+                    self.engine.detect(*bucket_views(packed), Q_pad)
             self._count_new_executables(tuple(buckets), Q_pad, head,
                                         fused=multi is not None)
             self.seen_shapes.add((tuple(buckets), Q_pad, head))
@@ -892,9 +871,9 @@ class DetectionPipeline:
                         with flight.span(EV_SCAN_WAIT, tag=trace_lane):
                             return np.asarray(rh_dev)
                     acc = None
-                    for tok, lens, rreq, rsv in buckets:
+                    for packed in buckets:
                         rh = np.asarray(engine.detect_device(
-                            tok, lens, rreq, rsv, Q_pad))
+                            *bucket_views(packed), Q_pad))
                         acc = rh if acc is None else np.logical_or(acc, rh)
                     return acc
             finally:
@@ -1076,7 +1055,7 @@ class DetectionPipeline:
         with flight.span(EV_SCAN_PACK, arg=len(data_list)):
             buckets, head_ok, live_rows, padded_rows = self._pack_buckets(
                 data_list, req_list, sv_list, Q)
-        bucket_shapes = tuple((b[0].shape[0], b[0].shape[1])
+        bucket_shapes = tuple((b.shape[0], b.shape[1] - ROW_TAIL)
                               for b in buckets)
         bucket_us = int((time.perf_counter() - te0) * 1e6)
         return (buckets, bucket_shapes, head_ok, bucket_us,
@@ -1085,10 +1064,12 @@ class DetectionPipeline:
     def _pack_buckets(self, data_list, req_list, sv_list, Q: int):
         """Pad/pack the merged rows into L-tier buckets (the
         ``scan_pack`` span of :meth:`_build_scan_buckets`) and book the
-        device-efficiency accounting.  Returns ``(buckets, head_ok,
-        live_rows, padded_rows)``."""
+        device-efficiency accounting.  A bucket is ONE buffer
+        (``engine.empty_bucket``: tokens, lengths, owning requests and
+        stream-variant flags of its rows), so it reaches the device in
+        one transfer.  Returns ``(buckets, head_ok, live_rows,
+        padded_rows)``."""
         stats = self.stats
-        n_sv = len(STREAMS) * len(VARIANTS)
         # Shape stability: jit caches one executable per bucket-set
         # signature, so rows bucket into fixed L tiers, row counts
         # pad to powers of two, and Q pads likewise.  Without this
@@ -1113,23 +1094,24 @@ class DetectionPipeline:
             B_pad = self._pad_q(len(idxs), floor=8)
             stats.truncated_rows += sum(
                 1 for i in idxs if len(data_list[i]) > L)
-            rows_b = [data_list[i][:L] for i in idxs]
-            rows_b += [b""] * (B_pad - len(idxs))
-            tokens, lengths = pad_rows(rows_b, max_len=L, round_to=L)
-            row_req = np.zeros((B_pad,), np.int32)
+            packed = empty_bucket(B_pad, L)
+            tokens, lengths, row_req, row_sv = bucket_views(packed)
+            nbytes = 0
+            for j, i in enumerate(idxs):
+                row = data_list[i][:L]
+                tokens[j, :len(row)] = np.frombuffer(row, np.uint8)
+                lengths[j] = len(row)
+                row_sv[j, sv_list[i]] = 1
+                nbytes += len(row)
             row_req[: len(idxs)] = [req_list[i] for i in idxs]
             row_req[len(idxs):] = self._pad_q(Q) - 1
-            row_sv = np.zeros((B_pad, n_sv), dtype=np.int8)
-            for j, i in enumerate(idxs):
-                row_sv[j, sv_list[i]] = 1
-            buckets.append((tokens, lengths, row_req, row_sv))
-            nbytes = sum(len(r) for r in rows_b)
+            buckets.append(packed)
             stats.rows += len(idxs)
             stats.row_bytes += nbytes
             stats.live_rows += len(idxs)
             stats.live_row_bytes += nbytes
             stats.padded_rows += B_pad
-            stats.padded_bytes += B_pad * tokens.shape[1]
+            stats.padded_bytes += B_pad * L
             stats.bucket_rows[L] = \
                 stats.bucket_rows.get(L, 0) + len(idxs)
             stats.bucket_padded_rows[L] = \
@@ -1159,17 +1141,17 @@ class DetectionPipeline:
             _lane = faults.current_lane()
             _ltag = _lane if _lane is not None else -1
             # Single-mapping dispatch (docs/SCAN_KERNEL.md): each bucket
-            # scans in its own jit program, the rule-count-scaling
-            # factor→rule mapping runs once per batch.  Engines that
-            # predate the fused API (parallel/serve_mesh MeshEngine)
-            # keep the per-bucket detect_device path — feature-detected,
-            # never assumed.
+            # is one transfer and one program (scan + fold), the
+            # rule-count-scaling factor→rule mapping runs once per
+            # batch.  Engines that predate the fused API
+            # (parallel/serve_mesh MeshEngine) keep the per-bucket
+            # detect_device path — feature-detected, never assumed.
             multi = getattr(self.engine, "detect_device_multi", None)
             shape = (bucket_shapes, self._pad_q(Q), head_ok)
             # recompile gauge counts REAL executables, not bucket-set
-            # signatures: per unseen (B, L) scan / (B, Q) fold shape
-            # plus an unseen Q expansion — a novel combination of
-            # already-warm executables is free
+            # signatures: per unseen (B, L) bucket program plus an
+            # unseen Q expansion — a novel combination of already-warm
+            # executables is free
             stats.engine_compiles += self._count_new_executables(
                 bucket_shapes, self._pad_q(Q), head_ok,
                 fused=multi is not None)
@@ -1185,9 +1167,9 @@ class DetectionPipeline:
                 else:
                     # legacy engine: per-bucket dispatch, async then OR
                     dispatched = [
-                        self.engine.detect_device(tok, lens, rreq, rsv,
+                        self.engine.detect_device(*bucket_views(packed),
                                                   self._pad_q(Q))
-                        for tok, lens, rreq, rsv in buckets]
+                        for packed in buckets]
                     for rh_dev in dispatched:
                         rule_hits |= np.asarray(rh_dev)
             stats.engine_us += bucket_us + int(

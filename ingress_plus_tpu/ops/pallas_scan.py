@@ -48,6 +48,33 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+class _JitArgument:
+    """A scanner goes into a jit as an ARGUMENT (the engine's per-bucket
+    program, models/engine.py ``scan_fold_bucket``): the device arrays
+    named in ``ARRAYS`` are its pytree leaves and everything else — the
+    tiling ints, the device a replica is placed on — is static
+    structure.  A hot swap that keeps the geometry then keeps the
+    executables, and no table becomes a constant of the program."""
+
+    ARRAYS: Tuple[str, ...] = ()
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        jax.tree_util.register_pytree_node_class(cls)
+
+    def tree_flatten(self):
+        static = tuple(sorted(
+            kv for kv in vars(self).items() if kv[0] not in self.ARRAYS))
+        return tuple(getattr(self, k) for k in self.ARRAYS), static
+
+    @classmethod
+    def tree_unflatten(cls, static, arrays):
+        new = object.__new__(cls)
+        vars(new).update(static)
+        vars(new).update(zip(cls.ARRAYS, arrays))
+        return new
+
+
 #: scoped-VMEM ceiling a kernel may ask for — half of a v5e core's
 #: 128 MiB, so a pack that outgrows it fails in the constructor with its
 #: numbers instead of inside Mosaic
@@ -218,9 +245,11 @@ def _pallas_scan(tokens, lengths, planes, init, final, state, match,
     return out_m, out_s
 
 
-class PallasScanner:
+class PallasScanner(_JitArgument):
     """Caches the padded/packed device tables for repeated kernel calls
     (serving + bench reuse one instance; hot-swap = build a new one)."""
+
+    ARRAYS = ("planes", "init", "final")
 
     def __init__(self, tables: ScanTables, TB: int = 64, CL: int = 32,
                  MR: int = 128):
@@ -517,11 +546,13 @@ def pack_pair_tables(class_table: np.ndarray, init_mask: np.ndarray,
     return planes, init, final, K1p, Wp
 
 
-class PallasPairScanner:
+class PallasPairScanner(_JitArgument):
     """Class-pair Pallas kernel with cached packed tables.
 
     Same call contract as PallasScanner, with scan_pairs' state caveat
     (dead-class padding: short rows return state 0)."""
+
+    ARRAYS = ("planes", "init", "final", "byte_class")
 
     def __init__(self, tables: ScanTables, TB: int = 64, CL: int = 16,
                  MR: int = 128):
@@ -647,7 +678,7 @@ def _fused_byte_scan(tokens, lengths, planes, init, final, state, match,
     return to_u32(out_m[:B, :W]), to_u32(out_s[:B, :W])
 
 
-class PallasByteScanner:
+class PallasByteScanner(_JitArgument):
     """Raw-byte fused scanner — serving name ``pallas3`` (ISSUE 13,
     docs/SCAN_KERNEL.md "Device path").
 
@@ -668,6 +699,8 @@ class PallasByteScanner:
     State contract = scan_pairs (dead padding): rows shorter than L
     return state 0 — request scans and equal-length chunk waves, NOT
     ragged streaming carries (streams keep the byte path)."""
+
+    ARRAYS = ("planes", "init", "final", "tables")
 
     def __init__(self, tables: ScanTables, TB: int = 64, CL: int = 16,
                  MR: int = 128):
@@ -711,24 +744,11 @@ class PallasByteScanner:
         new.device = device
         return new
 
-    # ------------------------------------------------------- exec keys
-
     def _use_kernel(self) -> bool:
         """On a TPU the Mosaic kernel serves, always; the reference
         lowering exists for CPU tests (pallas_call without interpret
         raises there)."""
         return on_tpu()
-
-    def exec_shape(self, B: int, L: int) -> Tuple[int, int]:
-        """The executable-keying shape of one (B, L) dispatch: the
-        Mosaic kernel keys on the TILE-padded rectangle (several host
-        bucket shapes share one executable), the reference lowering on
-        the exact shape.  The pipeline recompile gauge reads this so
-        pallas3 serving counts real compiles, not phantom ones."""
-        if self._use_kernel():
-            return (_round_up(max(B, self.TB), self.TB),
-                    _round_up(max(L, self.CL), self.CL))
-        return (B, L)
 
     # --------------------------------------------------------- dispatch
 

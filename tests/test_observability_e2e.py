@@ -292,7 +292,14 @@ def test_sub_stage_series_and_counters_after_traffic(server):
     values = {ln.split()[0]: float(ln.split()[1])
               for ln in metrics.splitlines()
               if ln and not ln.startswith("#")}
-    assert values["ipt_device_launches_total"] >= 7 * sb["batch"]["count"]
+    # a dispatch launches one program per occupied L tier and the one
+    # expansion: at least 2, at most (tiers the traffic filled) + 1
+    tiers = sum(1 for k, v in values.items()
+                if k.startswith("ipt_bucket_rows_total{") and v > 0)
+    batches = sb["batch"]["count"]
+    assert tiers >= 1
+    assert (2 * batches <= values["ipt_device_launches_total"]
+            <= (tiers + 1) * batches)
     for g in "012":
         assert 'ipt_gc_pause_us_total{generation="%s"}' % g in values
         assert 'ipt_gc_collections_total{generation="%s"}' % g in values

@@ -250,18 +250,14 @@ def test_byte_scanner_full_pack_geometry():
 
 
 def test_byte_scanner_exec_shape_and_tiling(tables):
-    """exec_shape keys the recompile gauge: exact shapes on the CPU
-    reference lowering (each (B, L) is its own XLA executable),
-    tile-padded rectangles only when the Mosaic kernel compiles.  Bad
-    tilings are rejected loudly, and classless tables are refused
-    (the reference lowering needs the pair tables)."""
+    """Bad tilings are rejected loudly, and classless tables are
+    refused (the reference lowering needs the pair tables)."""
     import pytest as _pytest
 
     from ingress_plus_tpu.ops.pallas_scan import PallasByteScanner
     from ingress_plus_tpu.ops.scan import ScanTables as _ST
 
-    sc = PallasByteScanner(tables, TB=8, CL=16, MR=8)
-    assert sc.exec_shape(13, 300) == (13, 300)   # cpu backend: exact
+    PallasByteScanner(tables, TB=8, CL=16, MR=8)   # a tiling that holds
     with _pytest.raises(ValueError):
         PallasByteScanner(tables, TB=7, CL=16)   # TB % 8
     with _pytest.raises(ValueError):
