@@ -211,6 +211,13 @@ class _ScanJob:
     live_rows: int = 0
     padded_rows: int = 0
     busy_us: int = 0
+    #: a lane share's scan interval on the host's clock
+    #: (time.monotonic_ns): handed to the lane's worker → result on the
+    #: host; 0 until each has happened
+    t_submit_ns: int = 0
+    t_done_ns: int = 0
+    #: µs the collector was blocked waiting for this share's result
+    wait_us: int = 0
     pending: object = None
     result: Optional[np.ndarray] = None
 
@@ -882,8 +889,10 @@ class DetectionPipeline:
                 # wall includes a whole drain window — that must not
                 # book as scan time
                 job.busy_us = int((time.perf_counter() - tb0) * 1e6)
+                job.t_done_ns = time.monotonic_ns()
 
         if lane is not None:
+            job.t_submit_ns = time.monotonic_ns()
             job.pending = lane.submit(_dispatch)
         else:
             job.result = _dispatch()
@@ -919,7 +928,12 @@ class DetectionPipeline:
         rule_hits = np.zeros((self._pad_q(Q), self.ruleset.n_rules),
                              dtype=bool)
         if job.pending is not None:
-            rule_hits |= job.pending.wait(timeout)
+            tw0 = time.perf_counter()
+            try:
+                hits = job.pending.wait(timeout)
+            finally:
+                job.wait_us = int((time.perf_counter() - tw0) * 1e6)
+            rule_hits |= hits
             st.engine_us += job.busy_us
         elif job.result is not None:
             rule_hits |= job.result

@@ -77,10 +77,12 @@ from ingress_plus_tpu.utils.trace import (
     EV_COLLECT,
     EV_CYCLE,
     EV_DRAIN,
+    EV_LANE_SCAN,
     EV_LAUNCH,
     EV_MIRROR,
     EV_OVERSIZED,
     EV_QUEUE,
+    EV_SCAN_WALL,
     EV_STREAM,
     EV_SUBMIT,
     EV_VERDICT,
@@ -324,6 +326,10 @@ class _MeshCycle:
         # the cycle's flight-recorder envelope span and the process's
         # GC pause total when it began
         "span", "gc_us0",
+        # µs of the dispatch thread's own work for this cycle so far
+        # (launch + collect + resolve, without the waits for the lanes'
+        # results and without the drains between the phases)
+        "own_us",
     )
 
     def __init__(self):
@@ -448,6 +454,12 @@ class Batcher:
         # drain_idle per drain, reply per request): the same metric
         # family, their own dict — a sum over STAGES must not meet them
         self.subhist: dict = {s: Histogram() for s in SUBSTAGES}
+        # what lanes add (mesh loop only; ipt_lane_stage_us,
+        # ipt_lane_cycle_us): {(lane, sub-stage): [µs, cycles]} and
+        # {"scan_wall" | "dispatch_own": [µs, cycles]}.  The dispatch
+        # thread is the one writer; a scrape reads a copy
+        self.lane_stage_us: Dict[tuple, List[int]] = {}
+        self.lane_cycle_us: Dict[str, List[int]] = {}
         # µs the dispatch thread has waited for work since the last
         # cycle's trace was cut (dispatch thread only)
         self._drain_idle_us = 0
@@ -478,6 +490,9 @@ class Batcher:
             self.tenant_guard.configure_depth(self._q.tenant_cap)
         self._batch_ewma = Ewma(alpha=0.2)
         self._batch_ewma_n = 0   # samples seen; shedding needs a floor
+        # when the mesh loop last resolved a cycle (perf_counter): its
+        # service-time samples run from there (dispatch thread only)
+        self._last_resolve = 0.0
         self.pipeline.load_controller.configure_deadline(hard_deadline_s)
         # per-device lane plane (serve/lanes.py, docs/MESH_SERVING.md):
         # n_lanes == 1 is the classic single-lane fail-safe plane of
@@ -567,6 +582,8 @@ class Batcher:
         conc.unguarded-mutation, ISSUE 11)."""
         for h in (*self.hist.values(), *self.subhist.values()):
             h.reset()
+        self.lane_stage_us = {}
+        self.lane_cycle_us = {}
         self.batch_size_hist.reset()
         self.slow.reset()
         # the flight recorder rides the same post-warmup reset: the
@@ -1123,11 +1140,29 @@ class Batcher:
         """Cycle ``cid``'s sub-spans for its :class:`BatchTrace`: what
         the flight recorder's spans accumulated under the cycle id, and
         the loop's idle time before it.  Empty with the recorder off."""
-        sub = flight.take(cid)
+        sub, lanes = flight.take_with_lanes(cid)
         if flight.enabled:
             sub["drain_idle"] = self._drain_idle_us
         self._drain_idle_us = 0
+        if self.lanes.n > 1:
+            # the same spans by the lane they ran for, one observation
+            # a cycle each (ipt_lane_stage_us{device=,stage=})
+            for key, us in lanes.items():
+                self._count_us(self.lane_stage_us, key, us)
         return sub
+
+    @staticmethod
+    def _count_us(table: dict, key, us: int) -> None:
+        """One more observation of ``us`` under ``key`` in a ``{key:
+        [µs sum, count]}`` table (dispatch thread only)."""
+        cell = table.get(key)
+        if cell is None:
+            # a scrape copies the table while this thread fills it: the
+            # new cell goes in whole
+            table[key] = [us, 1]
+        else:
+            cell[0] += us
+            cell[1] += 1
 
     def _stream_step_guarded(self, begins, chunks, finishes,
                              route: str, lane: Optional[Lane] = None) -> List:
@@ -1734,6 +1769,10 @@ class Batcher:
                 for (lane, lroute), part in zip(targets, shares):
                     if not part:
                         continue
+                    # what this thread does for the share books to its
+                    # lane too (scan_pack here; walk, fold and the
+                    # hand-off back at collect and resolve)
+                    flight.set_lane(lane.index)
                     try:
                         flight.begin(EV_LAUNCH, cycle=c.cid,
                                      tag=lane.index, arg=len(part))
@@ -1759,9 +1798,11 @@ class Batcher:
                     lane.stats.rows += job.live_rows
                     lane.stats.padded_rows += job.padded_rows
                     c.lane_parts.append((lane, lroute, part, job))
+                flight.set_lane(-1)
             c.launch_d_engine = ps.engine_us - c.engine_us0
             c.launch_d_prep = ps.prep_us - c.prep_us0
             c.launch_d_compiles = ps.engine_compiles - c.compiles0
+        c.own_us = int((time.perf_counter() - t0) * 1e6)
         return c
 
     def _collect_cycle(self, c: "_MeshCycle") -> None:
@@ -1781,14 +1822,18 @@ class Batcher:
         # for one budget, not k stacked budgets (reviewer catch); a
         # healthy lane that finished long ago returns instantly
         # regardless of what its siblings burned
-        collect_deadline = time.perf_counter() + self.hang_budget_s
+        t_collect = time.perf_counter()
+        collect_deadline = t_collect + self.hang_budget_s
         fins: List = []   # (lane, part, _FinishJob)
+        scans: List = []  # (lane index, submit ns, result ns) collected
+        waited_us = 0     # blocked on the lanes' results
         flight.set_cycle(c.cid)
         with self._swap_lock:
             ps = p.stats
             e0, cf0 = ps.engine_us, ps.confirm_us
             pp0, cp0 = ps.prep_us, ps.engine_compiles
             for lane, lroute, part, job in c.lane_parts:
+                flight.set_lane(lane.index)
                 try:
                     flight.begin(EV_COLLECT, cycle=c.cid,
                                  tag=lane.index)
@@ -1800,6 +1845,10 @@ class Batcher:
                     finally:
                         flight.end(EV_COLLECT, cycle=c.cid,
                                    tag=lane.index)
+                        waited_us += job.wait_us
+                    if job.t_done_ns:
+                        scans.append((lane.index, job.t_submit_ns,
+                                      job.t_done_ns))
                     # success is recorded in _resolve_cycle AFTER the
                     # confirm join: recording here would reset the
                     # breaker's consecutive-failure count every cycle
@@ -1820,6 +1869,8 @@ class Batcher:
                     lane.stats.errors += 1
                     lane.breaker.record_failure()
                     done += self._fail_open_part(p, part, lane.index)
+            flight.set_lane(-1)
+            self._scan_spans(c.cid, scans)
             if c.fallback_items:
                 # every lane down: exact CPU confirm-only verdicts, the
                 # PR 4 fallback as the mesh's last resort
@@ -1854,6 +1905,28 @@ class Batcher:
         c.pending_fins = fins
         c.done = done
         c.cand_verdicts = cand_verdicts
+        c.own_us += max(
+            int((time.perf_counter() - t_collect) * 1e6) - waited_us, 0)
+
+    def _scan_spans(self, cid: int, scans: List) -> None:
+        """What only a cycle over several lanes has: each collected
+        share's scan interval (``lane_scan``: handed to the lane's
+        worker → result on the host, summed per lane) and the cycle's
+        wall span of the same (``scan_wall``: first share handed over →
+        last result).  Their ratio says how far the lanes' scans
+        overlapped: N when all were in flight together, 1 when they ran
+        one after another."""
+        if not scans or not flight.enabled:
+            return
+        for lane_idx, t_submit, t_done in scans:
+            flight.span_at(EV_LANE_SCAN, t_submit, t_done, cycle=cid,
+                           tag=lane_idx)
+            self._count_us(self.lane_stage_us, (lane_idx, "lane_scan"),
+                           (t_done - t_submit) // 1000)
+        t0 = min(s[1] for s in scans)
+        t1 = max(s[2] for s in scans)
+        flight.span_at(EV_SCAN_WALL, t0, t1, cycle=cid, arg=len(scans))
+        self._count_us(self.lane_cycle_us, "scan_wall", (t1 - t0) // 1000)
 
     def _resolve_cycle(self, c: "_MeshCycle") -> None:
         """Phase B2 of a mesh cycle: bounded-join the confirm shares,
@@ -1865,12 +1938,14 @@ class Batcher:
         scan dispatch."""
         done = c.done
         p = c.pipeline
+        t_resolve = time.perf_counter()
         flight.set_cycle(c.cid)
         with self._swap_lock:
             ps = p.stats
             e0, cf0 = ps.engine_us, ps.confirm_us
             pp0, cp0 = ps.prep_us, ps.engine_compiles
             for lane, part, fin in c.pending_fins:
+                flight.set_lane(lane.index)
                 try:
                     verdicts = p.detect_collect_join(fin)
                     lane.breaker.record_success()
@@ -1885,6 +1960,7 @@ class Batcher:
                     lane.stats.errors += 1
                     lane.breaker.record_failure()
                     done += self._fail_open_part(p, part, lane.index)
+            flight.set_lane(-1)
             d_engine = (c.launch_d_engine + c.collect_d_engine
                         + ps.engine_us - e0)
             d_confirm = c.collect_d_confirm + ps.confirm_us - cf0
@@ -1904,9 +1980,29 @@ class Batcher:
         self._clear_guard(c.guard)
         c.span.end()
         t_end = time.perf_counter()
+        if flight.enabled:
+            # the serial host work of the cycle: this thread's launch,
+            # collect and resolve phases for all lanes, without its
+            # waits for their results
+            self._count_us(self.lane_cycle_us, "dispatch_own",
+                           c.own_us + int((t_end - t_resolve) * 1e6))
         took = max(t_end - c.t0 - c.overlap_drain_s, 0.0)
+        # the queue math's service time is what the loop takes PER
+        # CYCLE.  ``took`` is this cycle's launch → resolve, and under
+        # the double buffer that stretch also holds the collect and
+        # resolve of the cycle before and the launch of the one after:
+        # about two cycles' work.  Fed to the estimator it doubled the
+        # estimated wait, and admission shed at half the deadline (on
+        # the four-chip host 1.7-2.3% of a window's requests, after
+        # each long collection pause).  So: since the last resolve —
+        # or since this cycle's launch, where the loop stood idle
+        # before it — less the drains in between
+        service = max(t_end - max(c.t0, self._last_resolve)
+                      - c.overlap_drain_s, 0.0)
+        self._last_resolve = t_end
         if d_compiles == 0:
-            self._batch_ewma.update(min(took, 2.0 * self.hard_deadline_s))
+            self._batch_ewma.update(
+                min(service, 2.0 * self.hard_deadline_s))
             self._batch_ewma_n += 1
             self.pipeline.load_controller.observe(c.max_queue_delay_us)
         self.stats.batch_us_sum += int(took * 1e6)

@@ -119,9 +119,12 @@ class LaneWorker:
         reuse the bounded-call machinery for non-device work (the
         confirm plane's workers, models/confirm_plane.py) override this
         with their own attribution."""
+        flight.register_thread("lane_worker")
         if self.lane_index is not None:
             faults.set_current_lane(self.lane_index)
-        flight.register_thread("lane_worker")
+            # spans this thread closes book to its lane too
+            # (ipt_lane_stage_us, the annotations' lane=)
+            flight.set_lane(self.lane_index)
 
     def _run(self) -> None:
         self._setup()

@@ -132,6 +132,14 @@ METRIC_HELP = {
     "ipt_lane_errors_total": "dispatch errors per lane",
     "ipt_lane_busy_us_sum":
         "host clock around launch and wait per lane (us)",
+    "ipt_lane_stage_us":
+        "a cycle's sub-stage spans by the lane they ran for, and "
+        "lane_scan: a share handed to its lane -> result on the host "
+        "(us; more than one lane only)",
+    "ipt_lane_cycle_us":
+        "per mesh cycle: scan_wall = first share handed over -> last "
+        "result; dispatch_own = the dispatch thread's own work for all "
+        "lanes, its waits for them excluded (us)",
     "ipt_ruleset_info": "live ruleset version/size (info joint)",
     "ipt_scorer_active": "1 while a learned scoring head is installed",
     "ipt_scorer_diff_total":
@@ -642,6 +650,23 @@ class ServeLoop:
             for ln in lane_snaps:
                 lines.append('%s{device="%s"} %s'
                              % (metric, ln["lane"], ln[key]))
+        if len(lane_snaps) > 1 and flight.enabled:
+            # what only several lanes have (docs/OBSERVABILITY.md): the
+            # sub-stages by the lane they ran for — over the lanes they
+            # add up to ipt_stage_us{stage=}, which under N lanes is
+            # lane-time, not wall time — and the cycle's own spans
+            lines.append("# TYPE ipt_lane_stage_us summary")
+            for (lane, stage), (us, n) in sorted(
+                    self.batcher.lane_stage_us.copy().items()):
+                labels = 'device="%d",stage="%s"' % (lane, stage)
+                lines += ["ipt_lane_stage_us_sum{%s} %d" % (labels, us),
+                          "ipt_lane_stage_us_count{%s} %d" % (labels, n)]
+            lines.append("# TYPE ipt_lane_cycle_us summary")
+            for span, (us, n) in sorted(
+                    self.batcher.lane_cycle_us.copy().items()):
+                lines += ['ipt_lane_cycle_us_sum{span="%s"} %d' % (span, us),
+                          'ipt_lane_cycle_us_count{span="%s"} %d'
+                          % (span, n)]
         lines.append("# TYPE ipt_shed_total counter")
         lines += bounded_counter_series(
             "ipt_shed_total", "reason", dict(p.shed))

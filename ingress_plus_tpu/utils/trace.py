@@ -741,6 +741,8 @@ EV_HANDOFF = 22    # a lane call's thread hand-off, tag 0=to worker 1=back
 EV_REPLY = 23      # verdict resolved → reply frame written, tag=req-id hash
 EV_GC = 24         # one interpreter collection pause, arg=generation
 EV_LANE_CALL = 25  # dispatch thread blocked in a lane call, tag=lane
+EV_LANE_SCAN = 26  # one lane share's scan, submit → result on the host, tag=lane
+EV_SCAN_WALL = 27  # a mesh cycle's scan: first share's submit → last result
 
 EVENT_NAMES: Dict[int, str] = {
     EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
@@ -753,7 +755,8 @@ EVENT_NAMES: Dict[int, str] = {
     EV_SCAN_PACK: "scan_pack", EV_SCAN_LAUNCH: "scan_launch",
     EV_SCAN_WAIT: "scan_wait", EV_CONFIRM_FOLD: "confirm_fold",
     EV_HANDOFF: "lane_handoff", EV_REPLY: "reply", EV_GC: "gc",
-    EV_LANE_CALL: "lane_call",
+    EV_LANE_CALL: "lane_call", EV_LANE_SCAN: "lane_scan",
+    EV_SCAN_WALL: "scan_wall",
 }
 
 #: span codes whose elapsed µs accumulate per cycle id for the batcher's
@@ -764,6 +767,11 @@ ACCUMULATED: Dict[int, str] = {
     c: EVENT_NAMES[c] for c in (
         EV_SCAN_PACK, EV_SCAN_LAUNCH, EV_SCAN_WAIT, EV_CONFIRM,
         EV_CONFIRM_FOLD, EV_HANDOFF)}
+#: a span that closes on a thread with an ambient lane
+#: (``FlightRecorder.set_lane``) accumulates per (cycle, lane) too: under
+#: N lanes the sub-stages add up over the lanes' threads, and the mesh
+#: batcher folds the per-lane sums into ``ipt_lane_stage_us{device=,
+#: stage=}``; over the lanes each equals its ``ipt_stage_us{stage=}`` twin
 
 #: the sub-stages observed once per dispatch
 PER_DISPATCH: Tuple[str, ...] = tuple(ACCUMULATED.values()) + ("drain_idle",)
@@ -816,7 +824,7 @@ class _ThreadRing:
     torn read of at most the newest slot — telemetry, not verdicts)."""
 
     __slots__ = ("root", "thread_name", "index", "cap", "buf", "head",
-                 "dropped", "cycle", "thread")
+                 "dropped", "cycle", "lane", "thread")
 
     def __init__(self, root: str, thread_name: str, index: int, cap: int):
         self.root = root
@@ -827,6 +835,7 @@ class _ThreadRing:
         self.head = 0
         self.dropped = 0            # events evicted by the byte cap
         self.cycle = 0              # ambient cycle id for this thread
+        self.lane = -1              # ambient serve lane (-1: none)
         #: owner thread — registration prunes DEAD threads' rings past
         #: a soft cap, so short-lived workers (abandoned lanes, test
         #: batchers, swap warmers) cannot grow the registry unbounded
@@ -855,9 +864,11 @@ class _ThreadRing:
 _annotation_cls = None
 
 
-def _annotation(code: int, cycle: int, arg: int):
+def _annotation(code: int, cycle: int, arg: int, lane: int = -1):
     """``jax.profiler.TraceAnnotation("ipt:<name>", cycle=, n=)``: the
-    span on the profiler's clock.  Always built while the recorder is
+    span on the profiler's clock; a span opened under an ambient lane
+    (a lane worker's closure, a share's work on the dispatch thread)
+    carries ``lane=`` too.  Always built while the recorder is
     on — whoever starts a profiler session (``ProfilerSwitch``, or a
     harness from outside the program) finds the spans in its trace;
     with no session active it costs about a microsecond.  The import
@@ -867,6 +878,9 @@ def _annotation(code: int, cycle: int, arg: int):
         from jax.profiler import TraceAnnotation
 
         _annotation_cls = TraceAnnotation
+    if lane >= 0:
+        return _annotation_cls(_ANNOTATION_NAMES[code], cycle=cycle, n=arg,
+                               lane=lane)
     return _annotation_cls(_ANNOTATION_NAMES[code], cycle=cycle, n=arg)
 
 
@@ -913,7 +927,8 @@ class _Span:
             # concheck: ok a span object is opened and closed by one thread
             self._cycle = ring.cycle
         # concheck: ok a span object is opened and closed by one thread
-        ann = self._ann = _annotation(self._code, self._cycle, self._arg)
+        ann = self._ann = _annotation(self._code, self._cycle, self._arg,
+                                      ring.lane)
         ann.__enter__()
         # concheck: ok a span object is opened and closed by one thread
         t0 = self._t0 = time.monotonic_ns()
@@ -928,7 +943,8 @@ class _Span:
         # concheck: ok a span object is opened and closed by one thread
         self.us = us = (t1 - self._t0) // 1000
         if self._code in ACCUMULATED:
-            self._rec._accumulate(self._code, self._cycle, us)
+            self._rec._accumulate(self._code, self._cycle, us,
+                                  self._ring.lane)
 
     __enter__ = begin
 
@@ -956,9 +972,11 @@ class FlightRecorder:
         self._lock = named_lock("FlightRecorder._lock")
         self._rings: List[_ThreadRing] = []
         self._tls = threading.local()
-        #: cycle id → {sub-stage: µs}, filled by spans on any thread,
-        #: taken by the batcher when the cycle's verdicts resolve
-        self._acc: Dict[int, Dict[str, int]] = {}
+        #: cycle id → ({sub-stage: µs}, {(lane, sub-stage): µs}), filled
+        #: by spans on any thread, taken by the batcher when the cycle's
+        #: verdicts resolve
+        self._acc: Dict[int, Tuple[Dict[str, int],
+                                   Dict[Tuple[int, str], int]]] = {}
         self._acc_lock = named_lock("FlightRecorder._acc_lock")
 
     # ------------------------------------------------------- lifecycle
@@ -1033,8 +1051,10 @@ class FlightRecorder:
         if getattr(tls, "gen", -1) != self._gen:
             # re-arm after a configure()/reset(): keep the declared
             # root name — a post-warmup reset must not demote
-            # "dispatch" to its raw thread name
-            return self._register(ring.root)
+            # "dispatch" to its raw thread name — and the ambient lane
+            lane = ring.lane
+            ring = self._register(ring.root)
+            ring.lane = lane
         return ring
 
     # --------------------------------------------------------- hot path
@@ -1090,10 +1110,11 @@ class FlightRecorder:
         ring.record(t0_ns, code, PH_B, cycle, tag, arg)
         ring.record(t1_ns, code, PH_E, cycle, tag, 0)
         if code in ACCUMULATED:
-            self._accumulate(code, cycle, max(t1_ns - t0_ns, 0) // 1000)
+            self._accumulate(code, cycle, max(t1_ns - t0_ns, 0) // 1000,
+                             ring.lane)
 
-    def _accumulate(self, code: int, cycle: int, us: int) -> None:
-        name = ACCUMULATED[code]
+    def _accumulate(self, code: int, cycle: int, us: int,
+                    lane: int = -1) -> None:
         with self._acc_lock:
             acc = self._acc.get(cycle)
             if acc is None:
@@ -1101,14 +1122,25 @@ class FlightRecorder:
                     # a cycle nobody took (a library caller's, a side
                     # lane's): the oldest goes, the dict stays small
                     del self._acc[next(iter(self._acc))]
-                acc = self._acc[cycle] = {}
-            acc[name] = acc.get(name, 0) + us
+                acc = self._acc[cycle] = ({}, {})
+            sub, lanes = acc
+            name = ACCUMULATED[code]
+            sub[name] = sub.get(name, 0) + us
+            if lane >= 0:
+                lanes[lane, name] = lanes.get((lane, name), 0) + us
 
     def take(self, cycle: int) -> Dict[str, int]:
         """Pop what the spans of ``cycle`` accumulated, ``{sub-stage:
         µs}`` (empty with the recorder off or nothing recorded)."""
+        return self.take_with_lanes(cycle)[0]
+
+    def take_with_lanes(self, cycle: int) -> Tuple[
+            Dict[str, int], Dict[Tuple[int, str], int]]:
+        """:meth:`take`, and beside it what the same spans accumulated
+        per lane, ``{(lane, sub-stage): µs}``: the spans that closed on
+        a thread with an ambient lane (:meth:`set_lane`)."""
         with self._acc_lock:
-            return self._acc.pop(cycle, None) or {}
+            return self._acc.pop(cycle, None) or ({}, {})
 
     def set_cycle(self, cycle: int) -> None:
         """Ambient cycle id for subsequent events on THIS thread (the
@@ -1122,6 +1154,14 @@ class FlightRecorder:
         if not self.enabled:
             return 0
         return self._ring().cycle
+
+    def set_lane(self, lane: int) -> None:
+        """Ambient serve lane for subsequent spans on THIS thread (-1:
+        none).  A lane worker stamps its own once; the mesh dispatch
+        loop stamps a share's lane around the work it does for it."""
+        if not self.enabled:
+            return
+        self._ring().lane = lane
 
     def scoped(self, cycle: int, fn, *args):
         """Run ``fn`` with the calling thread's ambient cycle set —
