@@ -114,11 +114,14 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
                     "lane; zombies may linger after an abandon)"),
     ThreadRoot(
         name="confirm_worker",
-        entries=("models/confirm_plane.py::confirm_one",),
+        entries=("models/confirm_plane.py::_ConfirmWorker.recv_reply",),
         concurrent=True,
-        description="ipt-confirm-N sharded confirm workers "
-                    "(--confirm-workers > 1); shares arrive as closures "
-                    "through ConfirmPool.submit"),
+        description="ipt-confirm-N confirm workers (--confirm-workers "
+                    "> 1): waiter threads that block on the answer of "
+                    "their walker process (models/confirm_walker.py, "
+                    "which runs confirm_one) to a share the poster put "
+                    "on its pipe; the closures arrive through "
+                    "LaneWorker.submit"),
     ThreadRoot(
         name="watchdog",
         entries=("serve/batcher.py::Batcher._watch",),

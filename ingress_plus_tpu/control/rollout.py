@@ -545,6 +545,10 @@ class RolloutController:
         if len(verdicts) != len(smoke):
             raise RuntimeError("smoke detect returned %d verdicts for %d "
                                "requests" % (len(verdicts), len(smoke)))
+        # the pool it will inherit at promote learns its rules now, on
+        # the admission thread (docs/CONFIRM_PLANE.md)
+        live.confirm_pool.install(
+            candidate, wait_s=candidate.WALKER_INSTALL_WAIT_S)
         return candidate
 
     def _replay_diff(self, live, candidate) -> dict:
@@ -738,6 +742,10 @@ class RolloutController:
         if len(verdicts) != len(smoke):
             raise RuntimeError("smoke detect returned %d verdicts for %d "
                                "requests" % (len(verdicts), len(smoke)))
+        # the pool it will inherit at promote learns its rules now, on
+        # the admission thread (docs/CONFIRM_PLANE.md)
+        live.confirm_pool.install(
+            candidate, wait_s=candidate.WALKER_INSTALL_WAIT_S)
         return candidate
 
     # ----------------------------------------------------- shadow phase
@@ -958,11 +966,19 @@ class RolloutController:
         b = self.batcher
         try:
             faults.raise_if("swap_fail")
+            # the live confirm pool's walker processes hold the
+            # candidate's generation since its admission; queued again
+            # here (not waited for: this is the dispatch thread) in
+            # case later installs pushed it out
+            b.pipeline.confirm_pool.install(cand)
             with b._swap_lock:
                 prev = b.pipeline
                 prev_stream = b.stream_engine.pipeline
                 try:
                     cand.frozen_rule_stats = prev.rule_stats.freeze()
+                    # the confirm pool spans generations, as at a hot
+                    # swap (Batcher.swap_ruleset)
+                    cand.confirm_pool = prev.confirm_pool
                     # cross-cycle verdict cache: carried like the pool
                     # (generation-keyed — old entries are unreachable
                     # by construction; the drop is hygiene)

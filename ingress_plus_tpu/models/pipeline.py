@@ -32,6 +32,7 @@ from ingress_plus_tpu.models.confirm_plane import (
     VerdictCache,
     launch_confirm,
     join_confirm,
+    next_confirm_generation,
 )
 from ingress_plus_tpu.models.engine import (
     ROW_TAIL,
@@ -358,6 +359,11 @@ class DetectionPipeline:
             p *= 2
         return p
 
+    #: how long an install stays for the confirm pool's walker processes
+    #: to hold a new generation (they start and rebuild the rules in
+    #: about a second); past it the walk is inline until they do
+    WALKER_INSTALL_WAIT_S = 30.0
+
     def __init__(
         self,
         ruleset: CompiledRuleset,
@@ -377,11 +383,25 @@ class DetectionPipeline:
         confirm_memo_entries: int = 4096,
         confirm_cache_entries: int = 0,
     ):
+        # parallel confirm plane (docs/CONFIRM_PLANE.md): workers == 1
+        # (the default here) runs the classic serial walk inline — no
+        # threads, no processes, no handoff; the serve plane sizes the
+        # pool via --confirm-workers (derived from the host's cores by
+        # default).  The batcher carries ONE pool across hot swaps like
+        # the stats object, so a replacement pipeline's own (inline)
+        # pool is simply dropped.  First of all, so that the walker
+        # processes start beside the engine's build, not after it.
+        self.confirm_pool = ConfirmPool(n_workers=confirm_workers,
+                                        hang_budget_s=confirm_hang_budget_s)
         # ``engine``: pre-built engine to serve with (e.g. the batcher
         # hot-swap passing a mesh-backed MeshEngine.rebuilt) — skips
         # building the single-chip engine just to discard it
-        self.engine = (engine if engine is not None
-                       else DetectionEngine(ruleset, scan_impl=scan_impl))
+        try:
+            self.engine = (engine if engine is not None
+                           else DetectionEngine(ruleset, scan_impl=scan_impl))
+        except BaseException:
+            self.confirm_pool.close()   # no walker outlives a failed build
+            raise
         self.mode = mode
         # learned scoring lane (ISSUE 8, docs/LEARNED_SCORING.md):
         # ``scoring_head`` is the portable rule-id-keyed artifact;
@@ -407,14 +427,6 @@ class DetectionPipeline:
             paranoia_level = getattr(ruleset, "paranoia_hint", None) or 2
         self.fail_open = fail_open
         self.stats = PipelineStats()
-        # parallel confirm plane (docs/CONFIRM_PLANE.md): workers == 1
-        # (the default) runs the classic serial walk inline — no
-        # threads, no handoff; the serve plane sizes the pool via
-        # --confirm-workers.  The batcher carries ONE pool across hot
-        # swaps like the stats object, so a replacement pipeline's own
-        # (inline, thread-free) pool is simply dropped.
-        self.confirm_pool = ConfirmPool(n_workers=confirm_workers,
-                                        hang_budget_s=confirm_hang_budget_s)
         #: per-cycle flood-memo capacity; 0 disables memoization
         self.confirm_memo_entries = int(confirm_memo_entries)
         # cross-cycle verdict cache (ISSUE 15, docs/RETUNE.md): opt-in
@@ -469,6 +481,9 @@ class DetectionPipeline:
             ruleset.version if self.scorer is None
             else "%s+%s" % (ruleset.version, self.scorer.version))
         self.confirms = [ConfirmRule(m.confirm) for m in ruleset.rules]
+        # what the confirm pool's walker processes key this installed
+        # state by: a new id per install, whatever the version says
+        self.confirm_gen = next_confirm_generation()
         # detection-plane telemetry keyed by THIS generation's rule axis
         # (a swap starts fresh counters; the old ones freeze for drift)
         self.rule_stats = RuleStats(ruleset, self.confirms)
@@ -519,6 +534,9 @@ class DetectionPipeline:
             # config machinery out of the health views (never-hit /
             # never-candidate) — it can't confirm by design
             self.rule_stats.ignored[sorted(self._ctl_pass_idx)] = True
+        # this pipeline's own walker processes (confirm_workers > 1)
+        # hold the generation before it serves; an inline pool: no-op
+        self.confirm_pool.install(self, wait_s=self.WALKER_INSTALL_WAIT_S)
 
     def swap_ruleset(self, ruleset: CompiledRuleset,
                      paranoia_level: Optional[int] = None) -> None:
@@ -1211,9 +1229,9 @@ class DetectionPipeline:
         """Start the confirm phase for one batch of already-masked
         prefilter hits (docs/CONFIRM_PLANE.md): the per-request
         candidate walks run on the confirm pool — inline (the classic
-        serial path) at ``--confirm-workers 1``, as round-robin request
-        shares on the worker threads otherwise.  Returns the job for
-        :meth:`finalize_join`."""
+        serial path) at ``--confirm-workers 1`` and for a batch of one,
+        as request shares in the workers' walker processes otherwise.
+        Returns the job for :meth:`finalize_join`."""
         return launch_confirm(self, requests, rule_hits)
 
     def finalize(self, requests: List[Request], rule_hits: np.ndarray,
@@ -1249,16 +1267,18 @@ class DetectionPipeline:
             (time.perf_counter() - tc0) * 1e6)
 
         elapsed = int((time.perf_counter() - t0) * 1e6)
-        # worker attribution (ISSUE 12 satellite): the pool round-robins
-        # request qi onto worker qi % N (confirm_plane.launch_confirm),
-        # so the stamp is derivable without threading state through the
-        # walk; 0 = the inline serial walk, wedged shares keep -1
-        nw = self.confirm_pool.n_workers
+        # worker attribution (ISSUE 12 satellite): the pool deals
+        # request qi to share qi % (shares of this batch)
+        # (confirm_plane.launch_confirm), so the stamp is derivable
+        # without threading state through the walk; 0 = the inline
+        # serial walk, wedged shares keep -1
+        shares = cjob.share_workers
         for qi, v in enumerate(verdicts):
             v.elapsed_us = elapsed
             v.generation = self.generation_tag
             if not v.fail_open:
-                v.confirm_worker = (qi % nw) if nw > 1 else 0
+                v.confirm_worker = (shares[qi % len(shares)]
+                                    if shares else 0)
         return verdicts
 
     def _fold_verdicts(self, cjob, results,
@@ -1385,5 +1405,7 @@ class DetectionPipeline:
         if cjob.memo is not None:
             stats.confirm_memo_hits += cjob.memo.hits
             stats.confirm_memo_misses += cjob.memo.misses
+        stats.confirm_memo_hits += cjob.memo_hits
+        stats.confirm_memo_misses += cjob.memo_misses
         stats.confirmed_rule_hits += sum(len(v.rule_ids) for v in verdicts)
         return verdicts

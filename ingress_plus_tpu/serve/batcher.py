@@ -493,6 +493,7 @@ class Batcher:
         # when the mesh loop last resolved a cycle (perf_counter): its
         # service-time samples run from there (dispatch thread only)
         self._last_resolve = 0.0
+        self._drain_since_resolve = 0.0   # interleaved drains since then
         self.pipeline.load_controller.configure_deadline(hard_deadline_s)
         # per-device lane plane (serve/lanes.py, docs/MESH_SERVING.md):
         # n_lanes == 1 is the classic single-lane fail-safe plane of
@@ -610,7 +611,12 @@ class Batcher:
         if per_batch <= 0.0:
             return 0.0
         batches_ahead = (depth + self.max_batch - 1) // self.max_batch
-        return (batches_ahead + 1) * per_batch
+        # the mesh loop with a pooled confirm holds a cycle's confirm
+        # open across the next cycle's launch: one more cycle between
+        # an arrival and its verdict
+        held = (1 if self.lanes.n > 1
+                and self.pipeline.confirm_pool.n_workers > 1 else 0)
+        return (batches_ahead + 1 + held) * per_batch
 
     def _shed(self, request: Request, fut: "Future[Verdict]",
               reason: str, tenant: Optional[int] = None) -> "Future[Verdict]":
@@ -981,9 +987,13 @@ class Batcher:
         new.load_controller = old.load_controller
         # the confirm pool spans swaps too (docs/CONFIRM_PLANE.md): it
         # is ruleset-free, and the replacement pipeline's own default
-        # (inline, thread-free) pool is simply dropped — a hot swap
-        # must not orphan N worker threads per reload
+        # (inline) pool is simply dropped — a hot swap must not orphan
+        # N workers and their walker processes per reload.  The new
+        # generation is installed in the walkers HERE, before it serves
+        # a request; cycles in flight stay pinned to the old one, which
+        # the walkers keep
         new.confirm_pool = old.confirm_pool
+        new.confirm_pool.install(new, wait_s=new.WALKER_INSTALL_WAIT_S)
         new.confirm_memo_entries = old.confirm_memo_entries
         # the cross-cycle verdict cache spans swaps like the pool (its
         # keys carry the generation, so old entries can never serve the
@@ -1636,6 +1646,7 @@ class Batcher:
                 # the deadline-overrun accounting describe real work
                 # (reviewer catch)
                 dt = time.perf_counter() - td0
+                self._drain_since_resolve += dt
                 if pending is not None:
                     pending.overlap_drain_s += dt
                 if confirming is not None:
@@ -1996,10 +2007,17 @@ class Batcher:
         # the four-chip host 1.7-2.3% of a window's requests, after
         # each long collection pause).  So: since the last resolve —
         # or since this cycle's launch, where the loop stood idle
-        # before it — less the drains in between
-        service = max(t_end - max(c.t0, self._last_resolve)
-                      - c.overlap_drain_s, 0.0)
+        # before it — less the drains in between.  Those are the
+        # loop's since that resolve, not the cycle's own: with the
+        # confirm held open a cycle lives through two drains, and only
+        # the later one lies in the stretch
+        if self._last_resolve > c.t0:
+            service = max(t_end - self._last_resolve
+                          - self._drain_since_resolve, 0.0)
+        else:
+            service = max(t_end - c.t0 - c.overlap_drain_s, 0.0)
         self._last_resolve = t_end
+        self._drain_since_resolve = 0.0
         if d_compiles == 0:
             self._batch_ewma.update(
                 min(service, 2.0 * self.hard_deadline_s))

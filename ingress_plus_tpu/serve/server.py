@@ -115,6 +115,9 @@ METRIC_HELP = {
         "every XLA backend compile in the process, eager per-shape "
         "programs included",
     "ipt_confirm_workers": "confirm pool size (1 = inline serial walk)",
+    "ipt_confirm_requests_total":
+        "requests confirm-walked, by where: a walker process or inline "
+        "on the caller",
     "ipt_confirm_quick_reject_total":
         "confirm evaluations resolved by the literal quick-reject",
     "ipt_confirm_regex_evals_total": "confirm re.search evaluations",
@@ -791,6 +794,11 @@ class ServeLoop:
             "ipt_confirm_workers %d" % pool.n_workers,
             "# TYPE ipt_confirm_workers_replaced_total counter",
             "ipt_confirm_workers_replaced_total %d" % pool.workers_replaced,
+            "# TYPE ipt_confirm_requests_total counter",
+            'ipt_confirm_requests_total{where="process"} %d'
+            % pool.requests_process,
+            'ipt_confirm_requests_total{where="inline"} %d'
+            % pool.requests_inline,
             "# TYPE ipt_confirm_hangs_total counter",
             "ipt_confirm_hangs_total %d" % p.confirm_hangs,
             "# TYPE ipt_confirm_memo_hits_total counter",
@@ -1606,12 +1614,17 @@ def build_default_batcher(mode: str = "block", rules_dir: Optional[str] = None,
         n_lanes = max(1, len(jax.devices()))
         print("lane serving: auto -> %d per-device lanes" % n_lanes,
               file=sys.stderr)
-    if confirm_workers == 0:   # --confirm-workers auto: one per host core
+    if confirm_workers == 0:   # --confirm-workers auto, the CLI's default
         import os as _os
 
-        confirm_workers = max(1, min(8, _os.cpu_count() or 1))
-        print("confirm plane: auto -> %d confirm workers"
-              % confirm_workers, file=sys.stderr)
+        from ingress_plus_tpu.models.confirm_plane import auto_workers
+
+        cores = len(_os.sched_getaffinity(0))
+        confirm_workers = auto_workers(n_lanes, cores)
+        print("confirm plane: auto -> %d confirm workers (%d of %d cores "
+              "in the affinity, %d lanes)"
+              % (confirm_workers, cores, _os.cpu_count() or 0, n_lanes),
+              file=sys.stderr)
     pipeline = DetectionPipeline(
         cr, mode=mode, engine=engine, confirm_workers=confirm_workers,
         confirm_cache_entries=confirm_cache_entries)
@@ -1726,7 +1739,8 @@ def warmup_pipeline(pipeline, max_batch: int) -> None:
 def _parse_auto_count(value: str, flag: str) -> int:
     """Shared N|'auto' flag parser (--lanes, --confirm-workers):
     'auto' → the internal 0 sentinel (resolved per flag: one lane per
-    local device / one confirm worker per host core); integers must be
+    local device / confirm workers from the cores the process may run
+    on, confirm_plane.auto_workers); integers must be
     >= 1 — an explicit 0 must not silently collide with the sentinel
     and fan out."""
     if value == "auto":
@@ -1773,15 +1787,19 @@ def main(argv=None) -> None:
                          "watchdog + circuit breaker; a sick chip "
                          "degrades capacity, not the service.  "
                          "Mutually exclusive with --mesh")
-    ap.add_argument("--confirm-workers", default="1",
+    ap.add_argument("--confirm-workers", default="auto",
                     help="parallel confirm plane (docs/CONFIRM_PLANE.md)"
-                         ": worker threads the CPU confirm stage shards "
-                         "each cycle's requests across — an integer, or "
-                         "'auto' = one per host core (capped at 8).  1 "
-                         "(default) runs the classic serial confirm "
-                         "inline.  A wedged worker fails only its "
-                         "request share open; with the mesh loop, "
-                         "confirm overlaps the next cycle's scan")
+                         ": workers the CPU confirm stage deals each "
+                         "cycle's requests to, each a waiter thread in "
+                         "front of a walker process — an integer, or "
+                         "'auto' (default) = the cores this process may "
+                         "run on less the dispatch thread, the event "
+                         "loop and the lane workers, capped at 8, and 1 "
+                         "where that leaves fewer than two.  1 runs the "
+                         "classic serial confirm inline.  A wedged "
+                         "worker fails only its request share open; "
+                         "with the mesh loop, confirm overlaps the next "
+                         "cycle's scan")
     ap.add_argument("--confirm-cache", type=int, default=0,
                     help="cross-cycle verdict cache entries "
                          "(docs/RETUNE.md): bounded confirm-outcome "

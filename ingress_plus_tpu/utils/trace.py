@@ -743,6 +743,7 @@ EV_GC = 24         # one interpreter collection pause, arg=generation
 EV_LANE_CALL = 25  # dispatch thread blocked in a lane call, tag=lane
 EV_LANE_SCAN = 26  # one lane share's scan, submit → result on the host, tag=lane
 EV_SCAN_WALL = 27  # a mesh cycle's scan: first share's submit → last result
+EV_CONFIRM_IPC = 28  # a confirm share's hop: send → answer less the walker's walk, tag=worker
 
 EVENT_NAMES: Dict[int, str] = {
     EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
@@ -756,7 +757,7 @@ EVENT_NAMES: Dict[int, str] = {
     EV_SCAN_WAIT: "scan_wait", EV_CONFIRM_FOLD: "confirm_fold",
     EV_HANDOFF: "lane_handoff", EV_REPLY: "reply", EV_GC: "gc",
     EV_LANE_CALL: "lane_call", EV_LANE_SCAN: "lane_scan",
-    EV_SCAN_WALL: "scan_wall",
+    EV_SCAN_WALL: "scan_wall", EV_CONFIRM_IPC: "confirm_ipc",
 }
 
 #: span codes whose elapsed µs accumulate per cycle id for the batcher's
@@ -766,7 +767,7 @@ EVENT_NAMES: Dict[int, str] = {
 ACCUMULATED: Dict[int, str] = {
     c: EVENT_NAMES[c] for c in (
         EV_SCAN_PACK, EV_SCAN_LAUNCH, EV_SCAN_WAIT, EV_CONFIRM,
-        EV_CONFIRM_FOLD, EV_HANDOFF)}
+        EV_CONFIRM_FOLD, EV_HANDOFF, EV_CONFIRM_IPC)}
 #: a span that closes on a thread with an ambient lane
 #: (``FlightRecorder.set_lane``) accumulates per (cycle, lane) too: under
 #: N lanes the sub-stages add up over the lanes' threads, and the mesh
@@ -781,7 +782,10 @@ PER_DISPATCH: Tuple[str, ...] = tuple(ACCUMULATED.values()) + ("drain_idle",)
 #: tuple: a sum over STAGES decomposes a request's latency, and adding
 #: these in would count the scan and confirm stages twice.
 #: scan = scan_pack + scan_launch + scan_wait (+ a residue of Python
-#: between them); confirm = confirm_walk + confirm_fold (+ the join);
+#: between them); confirm = confirm_walk + confirm_fold (+ the join),
+#: where confirm_walk adds up the shares' time over the confirm workers;
+#: confirm_ipc = what the hop to the walker processes costs the shares:
+#: send → answer less the walker's own walk time (0 for an inline walk);
 #: lane_handoff = the two thread hand-offs around a lane call;
 #: drain_idle = the dispatch thread waiting for a dispatch's work since
 #: the cycle before it ended (observed per dispatch like the rest, so
@@ -1162,6 +1166,11 @@ class FlightRecorder:
         if not self.enabled:
             return
         self._ring().lane = lane
+
+    def lane(self) -> int:
+        if not self.enabled:
+            return -1
+        return self._ring().lane
 
     def scoped(self, cycle: int, fn, *args):
         """Run ``fn`` with the calling thread's ambient cycle set —

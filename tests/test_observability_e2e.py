@@ -322,8 +322,15 @@ def test_sub_stage_series_and_counters_after_traffic(server):
         full += set(t["sub_us"]) == set(PER_DISPATCH)
         assert (sub["scan_pack"] + sub["scan_launch"] + sub["scan_wait"]
                 <= t["engine_us"]), t
-        assert (sub["confirm_walk"] + sub["confirm_fold"]
-                <= t["confirm_us"]), t
+        if sub["confirm_ipc"] == 0:
+            # an inline walk lies inside the stage
+            assert (sub["confirm_walk"] + sub["confirm_fold"]
+                    <= t["confirm_us"]), t
+        else:
+            # shares walked in walker processes: confirm_walk adds up
+            # the shares' time over the workers and may pass the wall
+            # the dispatch thread paid
+            assert sub["confirm_fold"] <= t["confirm_us"], t
         assert t["gc_us"] >= 0
     assert full >= 1
 

@@ -225,6 +225,7 @@ STAGE_US = {"queue": 10, "prep": 20, "scan": 100, "confirm": 30,
             "batch": 160, "e2e": 170}
 SUBSTAGE_US = {"scan_pack": 5, "scan_launch": 60, "scan_wait": 30,
                "confirm_walk": 20, "confirm_fold": 8, "lane_handoff": 4,
+               "confirm_ipc": 3,
                "drain_idle": 400, "reply": 90}
 
 

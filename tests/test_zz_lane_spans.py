@@ -53,10 +53,11 @@ def bundled():
     return compile_ruleset(load_bundled_rules(), base_path=RULES_DIR / "crs")
 
 
-def _batcher(cr, n_lanes):
+def _batcher(cr, n_lanes, confirm_workers=1):
     from ingress_plus_tpu.models.pipeline import DetectionPipeline
 
-    return Batcher(DetectionPipeline(cr, mode="block", fail_open=False),
+    return Batcher(DetectionPipeline(cr, mode="block", fail_open=False,
+                                     confirm_workers=confirm_workers),
                    n_lanes=n_lanes, max_batch=16, max_delay_s=0.001)
 
 
@@ -220,16 +221,20 @@ def test_the_wall_span_is_at_most_the_longest_share_plus_the_launches(small):
     assert walls >= 4
 
 
-def test_mesh_service_time_samples_do_not_count_a_stretch_twice(small):
+@pytest.mark.parametrize("confirm_workers", [1, 2])
+def test_mesh_service_time_samples_do_not_count_a_stretch_twice(
+        small, confirm_workers):
     """The admission queue math divides by the loop's time per cycle.
     Under the double buffer a cycle's launch → resolve also holds its
     neighbours' work, so those spans overlap (their sum passes the wall
-    time); the samples fed to the estimator must not."""
+    time); the samples fed to the estimator must not.  With confirm
+    workers the loop holds a cycle's confirm open across the next
+    launch too (three cycles alive at once): the same must hold."""
     import threading
     import time
 
     flight.configure(enabled=True)
-    b = _batcher(small, 2)
+    b = _batcher(small, 2, confirm_workers)
     samples = []
 
     class Recording(type(b._batch_ewma)):
