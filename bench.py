@@ -1420,26 +1420,9 @@ def run_bench() -> dict:
 
     from ingress_plus_tpu.models.engine import detect_rows
 
-    scanner = scanner2 = None
-    if platform == "tpu":
-        from ingress_plus_tpu.ops.pallas_scan import (
-            PallasPairScanner,
-            PallasScanner,
-        )
-
-        scanner = PallasScanner(tables.scan)
-        scanner2 = PallasPairScanner(tables.scan)
-    # built on EVERY platform: the raw-byte scanner runs its XLA
-    # reference lowering on CPU (an explicit --impl=pallas3 CPU run
-    # measures the fused raw-byte program, docs/SCAN_KERNEL.md)
-    from ingress_plus_tpu.ops.pallas_scan import PallasByteScanner
-
-    scanner3 = PallasByteScanner(tables.scan)
-
     def make_detect_k(impl: str):
         """K state-chained repetitions of the full multi-bucket batch for
-        one scan implementation (VERDICT round-1: the serving/bench path
-        must measure pair vs take vs pallas, not assume).
+        one scan lowering.
 
         Fused mapping (docs/SCAN_KERNEL.md, the serving path's
         detect_device_multi shape): every bucket scans at its own
@@ -1470,18 +1453,7 @@ def run_bench() -> dict:
                 matches = []
                 for (tok, lens, rreq, rsv), (state, match) in zip(
                         bufs, states):
-                    if impl == "pallas":
-                        match, state = scanner(tok, lens, state=state,
-                                               match=match)
-                    elif impl == "pallas2":
-                        # pair-kernel contract: sticky match chains; the
-                        # dead-class-padded state is not a byte carry
-                        match, state = scanner2(tok, lens, match=match)
-                    elif impl == "pallas3":
-                        # raw-byte fused kernel (ISSUE 13): uint8 in,
-                        # byte→reach mapping + padding on-device
-                        match, state = scanner3(tok, lens, match=match)
-                    elif impl == "pair":
+                    if impl == "pair":
                         # pair path contract: state=None (request scans
                         # consume only the sticky match, which we chain)
                         match, state = scan_pairs(
@@ -1511,23 +1483,13 @@ def run_bench() -> dict:
     # measured-winner-first ordering (pair won r01-r03 on BOTH platforms):
     # if the watchdog fires mid-loop the stashed best-so-far is already
     # the likely champion, not the warm-up act
-    # the Pallas kernels join the default bake-off on a TPU (compiled);
-    # on CPU pallas3's lowering is the pair program, so the default CPU
-    # loop skips the duplicate measurement — the `kernel` block
-    # (microbench --scan) carries the CPU A/B, and an explicit
-    # --impl=pallas3 still measures it here
-    impls = (["pair"]
-             + (["pallas3", "pallas2", "pallas"] if platform == "tpu"
-                else [])
-             + ["take"])
+    impls = ["pair", "take"]
     only = [a.split("=", 1)[1] for a in sys.argv if a.startswith("--impl=")]
     if only:
-        bad = [i for i in only
-               if i not in ("take", "pair", "pallas", "pallas2",
-                            "pallas3")]
+        bad = [i for i in only if i not in impls]
         if bad:
             raise SystemExit("unknown --impl value(s) %s (choose from "
-                             "take/pair/pallas/pallas2/pallas3)" % bad)
+                             "take/pair)" % bad)
         impls = only
     impl_stats: dict = {}
     best_impl, best_rps = None, -1.0
@@ -1699,64 +1661,9 @@ def run_bench() -> dict:
     except Exception as e:
         log("pack-scale leg failed (non-fatal): %r" % (e,))
 
-    # kernel microbench leg (ISSUE 13): the raw-byte fused device path
-    # vs the XLA lax.scan lowering at the dominant bucket tiers, plus
-    # the kernel parity verdict — recorded as the `kernel` block.  A fused path LOSING to the baseline lowering is a
-    # regression in the hand-scheduled kernel and is warned about
-    # LOUDLY, never silently recorded.
-    try:
-        if _budget_left() > 150:
-            from ingress_plus_tpu.utils.microbench import bench_scan_modes
-
-            kb = bench_scan_modes(tables=tables.scan, iters=9)
-            result["kernel"] = kb
-            shapes = kb.get("shapes", [])
-            losing = [s for s in shapes
-                      if s.get("fused_vs_xla_scan") is not None
-                      and s["fused_vs_xla_scan"] < 1.0]
-            unmeasured = [s for s in shapes
-                          if s.get("fused_vs_xla_scan") is None]
-            if losing:
-                log("=" * 64)
-                log("KERNEL WARNING: the Pallas fused path LOSES to "
-                    "the XLA lax.scan lowering at %s — a regression "
-                    "in the hand-scheduled kernel (lowering: %s); "
-                    "pick the scan impl by measurement, not by hope."
-                    % ([(s["B"], s["L"]) for s in losing],
-                       kb.get("fused_lowering")))
-                log("=" * 64)
-            elif unmeasured:
-                # a timing failure is a broken MEASUREMENT, not a
-                # kernel regression — do not send the triage hunting
-                # a nonexistent kernel bug (review catch)
-                log("KERNEL WARNING: no timing signal at %s (K-diff "
-                    "<= 0, jitter > compute) — the fused-vs-lax.scan "
-                    "comparison is UNMEASURED at those shapes this "
-                    "round" % [(s["B"], s["L"]) for s in unmeasured])
-            else:
-                log("kernel: fused raw-byte path beats the lax.scan "
-                    "lowering at every dominant shape (%s)"
-                    % ", ".join("%.2fx" % s["fused_vs_xla_scan"]
-                                for s in kb.get("shapes", [])))
-            par = kb.get("kernel_parity") or {}
-            if not par.get("ok", True):
-                log("=" * 64)
-                log("KERNEL WARNING: %s kernel parity DIVERGED from "
-                    "the XLA reference — the Mosaic kernel does not "
-                    "match the serving math (devicegate should have "
-                    "caught this)" % par.get("lowering"))
-                log("=" * 64)
-            _HEADLINE = dict(result)
-        else:
-            log("kernel microbench skipped inline (%.0fs budget "
-                "left); run `python -m ingress_plus_tpu.utils."
-                "microbench --scan` for the A/B" % _budget_left())
-    except Exception as e:
-        log("kernel microbench failed (non-fatal): %r" % (e,))
-
     # retune leg (ISSUE 15): profile-guided pack retuning A/B — static
     # vs profile-priced pack crossed with the cross-cycle verdict cache,
-    # recorded as the `retune` block (same shape as the kernel block).
+    # recorded as the `retune` block.
     # The profile-priced pack LOSING to the static pricing on the mixed
     # corpus means the telemetry→compiler loop is feeding the pricer
     # garbage — warned about LOUDLY, never silently recorded.

@@ -27,12 +27,12 @@ Run from the root of a checkout, on a machine with one accelerator (or
    (``DetectionPipeline.detect_cpu_only``) of the same frames, computed
    by a child pinned to CPU: same attack flag, same blocked flag, same
    rule ids.
-5. **kernels** — after the server has exited and released the chip, a
-   second chip-holding child compiles every member of ``SCAN_IMPLS`` at
-   the bundled pack's geometry, at every (rows, L) tier the serve path
-   can dispatch, and checks match words bit for bit against
-   ``ops/scan.py`` on the device (ops/parity.py — devicegate's
-   comparison, compiled).
+5. **parity** — after the server has exited and released the chip, a
+   second chip-holding child compiles every member of ``SCAN_IMPLS``
+   (the lowerings ``pair`` and ``take``, the unserved byte kernel
+   ``pallas``) at the bundled pack's geometry, at every (rows, L) tier
+   the serve path can dispatch, and checks match words bit for bit
+   against ``ops/scan.py scan_bytes`` on the device (ops/parity.py).
 
 This parent never imports JAX: a parent that touches it holds the chip
 its children need.  Timings printed here are set-up facts, not records.
@@ -40,8 +40,8 @@ The last stdout line on success is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
 ``CHIP_SMOKE_REHEARSAL=1`` rehearses the same phases on CPU at a tiny
-size (interpreted kernels) to debug the script without a chip; it never
-prints the ``ok`` line.
+size to debug the script without a chip; it never prints the ``ok``
+line.
 """
 
 from __future__ import annotations
@@ -165,11 +165,11 @@ def child_oracle(seed: int, n_corpus: int, out: str) -> None:
           flush=True)
 
 
-# -------------------------------------------------------- child: kernels
+# --------------------------------------------------------- child: parity
 
-def child_kernels(max_batch: int) -> None:
-    """Chip-holding: every scan implementation, compiled, against
-    ops/scan.py at every tier the serve path can dispatch."""
+def child_parity(max_batch: int) -> None:
+    """Chip-holding: every member of SCAN_IMPLS, compiled, against
+    ops/scan.py scan_bytes at every tier the serve path can dispatch."""
     from ingress_plus_tpu.utils.platform import (
         device_block,
         enable_compile_cache,
@@ -177,9 +177,9 @@ def child_kernels(max_batch: int) -> None:
 
     enable_compile_cache()
     dev = device_block()
-    print("kernels: device %s" % json.dumps(dev), flush=True)
+    print("parity: device %s" % json.dumps(dev), flush=True)
     check(REHEARSAL or dev["platform"] == "tpu",
-          "kernel phase needs a TPU, JAX reports %s" % dev)
+          "parity phase needs a TPU, JAX reports %s" % dev)
     from ingress_plus_tpu.compiler.ruleset import compile_ruleset
     from ingress_plus_tpu.compiler.sigpack import load_bundled_rules
     from ingress_plus_tpu.models.pipeline import DetectionPipeline
@@ -188,28 +188,27 @@ def child_kernels(max_batch: int) -> None:
     pipe = DetectionPipeline(compile_ruleset(load_bundled_rules()))
     shapes = sorted({b for buckets, _q in pipe.warm_signatures(max_batch)
                      for b in buckets})
-    if REHEARSAL:     # the interpreter is ~1000x slower than the chip
+    if REHEARSAL:     # the interpreted kernel takes minutes at long tiers
         shapes = [s for s in shapes if s[0] <= 16 and s[1] <= 128]
     t0 = time.monotonic()
-    results = engine_parity(pipe.engine, shapes, interpret=REHEARSAL,
+    results = engine_parity(pipe.engine, shapes,
                             workers=min(8, os.cpu_count() or 1))
     for impl, cases in results.items():
         bad = failed(cases) + [c for c in cases if not c["non_vacuous"]]
-        print("kernels: %-8s %3d (rows, L) tiers %s  [platform=%s "
+        print("parity: %-8s %3d (rows, L) tiers %s  [platform=%s "
               "device_kind=%s device_count=%d]"
               % (impl, len(cases),
                  "bit-identical to ops/scan.py" if not bad
                  else "DIVERGED at %s" % [(c["B"], c["L"]) for c in bad],
                  dev["platform"], dev["device_kind"],
                  dev["device_count"]), flush=True)
-    print("kernels: %d implementations x %d tiers, rows %d..%d, "
+    print("parity: %d implementations x %d tiers, rows %d..%d, compiled for "
           "%s, %.1fs (set-up time, not a record)"
           % (len(results), len(shapes), shapes[0][0], shapes[-1][0],
-             "interpreted" if REHEARSAL else "compiled",
-             time.monotonic() - t0), flush=True)
+             dev["platform"], time.monotonic() - t0), flush=True)
     check(all(not failed(c) and all(x["non_vacuous"] for x in c)
               for c in results.values()),
-          "a scan implementation diverged from ops/scan.py")
+          "a scan implementation diverged from ops/scan.py scan_bytes")
 
 
 # ------------------------------------------------------------ the parent
@@ -405,9 +404,7 @@ def check_served_clean(port: int, before: dict, lanes: str) -> dict:
     for L, n in rows.items():
         check(n > 0, "no scan row reached the L=%d tier" % L)
     path = rob["device_path"]
-    say("served scan_impl=%s (%s); bake-off seconds/batch: %s"
-        % (path["scan_impl"], path["scan_contract"], path["bakeoff"]))
-    check(path["bakeoff"] is not None, "--scan-impl auto ran no bake-off")
+    say("served scan_impl=%s" % path["scan_impl"])
     if lanes != "1":
         devs = path["lane_devices"]
         say("lane devices: %s" % devs)
@@ -565,24 +562,24 @@ def run(args) -> dict:
                   "canonical payload %r was not blocked"
                   % CANONICAL[i - n_corpus])
 
-        # ---- kernels: the second, and only other, chip-holding child
-        kern_log = tmp / "kernels.log"
-        kernels = subprocess.Popen(
+        # ---- parity: the second, and only other, chip-holding child
+        parity_log = tmp / "parity.log"
+        parity = subprocess.Popen(
             [sys.executable, str(REPO / "chip_smoke.py"), "--child",
-             "kernels", "--max-batch", "8" if REHEARSAL else "256"],
+             "parity", "--max-batch", "8" if REHEARSAL else "256"],
             env=child_env(), cwd=str(REPO),
-            stdout=kern_log.open("w"), stderr=subprocess.STDOUT)
-        procs.append((kernels, "kernel child", kern_log))
+            stdout=parity_log.open("w"), stderr=subprocess.STDOUT)
+        procs.append((parity, "parity child", parity_log))
         try:
-            kernels.wait(timeout=max(left(), 1.0))
+            parity.wait(timeout=max(left(), 1.0))
         except subprocess.TimeoutExpired:
-            raise SmokeFailure("kernel phase ran out of time\n%s"
-                               % tail(kern_log))
-        for line in kern_log.read_text(errors="replace").splitlines():
-            if line.startswith("kernels:"):
+            raise SmokeFailure("parity phase ran out of time\n%s"
+                               % tail(parity_log))
+        for line in parity_log.read_text(errors="replace").splitlines():
+            if line.startswith("parity:"):
                 say(line)
-        check(kernels.returncode == 0,
-              "kernel child failed\n%s" % tail(kern_log))
+        check(parity.returncode == 0,
+              "parity child failed\n%s" % tail(parity_log))
         check("jax" not in sys.modules, "the parent imported JAX")
         return {"platform": device["platform"],
                 "kind": device["device_kind"],
@@ -610,7 +607,7 @@ def main() -> int:
     ap.add_argument("--lanes", default="1",
                     help="passed to the server when not 1 (e.g. auto)")
     ap.add_argument("--seed", type=int, default=20260926)
-    ap.add_argument("--child", choices=["oracle", "kernels"])
+    ap.add_argument("--child", choices=["oracle", "parity"])
     ap.add_argument("--n-corpus", type=int, default=256)
     ap.add_argument("--max-batch", type=int, default=256)
     ap.add_argument("--out")
@@ -620,7 +617,7 @@ def main() -> int:
         if args.child == "oracle":
             child_oracle(args.seed, args.n_corpus, args.out)
         else:
-            child_kernels(args.max_batch)
+            child_parity(args.max_batch)
         return 0
     signal.signal(signal.SIGALRM, out_of_time)
     signal.alarm(int(BUDGET_S))

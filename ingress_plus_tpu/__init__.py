@@ -8,8 +8,8 @@ A brand-new framework with the capabilities of wallarm/ingress-plus
   tables.  The analog of the reference's closed-source libproton compiled
   ruleset (proton.db) and of libmodsecurity's SecLang engine
   (reference: internal C engines, see SURVEY.md §2.2).
-- ``ops/``       — JAX/XLA + Pallas TPU kernels for the batched byte-stream
-  scan (the reference's per-byte automaton hot loop, SURVEY.md §3.3).
+- ``ops/``       — the batched byte-stream scan as XLA programs (the
+  reference's per-byte automaton hot loop, SURVEY.md §3.3).
 - ``models/``    — detection models: prefilter NFA + per-class verdict heads,
   strict-grammar SQLi/XSS confirm (libdetection analog), ML scorer.
 - ``parallel/``  — device-mesh sharding: DP (batch), TP (ruleset shards),

@@ -230,9 +230,9 @@ def map_match_words(
     row_sv: jax.Array,
     num_requests: int,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Match words → (rule_hits, class_hits, scores).  Factored out of
-    detect_rows so scan implementations living outside the jit (the
-    Pallas kernel path) reuse the identical rule-mapping math.
+    """Match words → (rule_hits, class_hits, scores): the rule-mapping
+    math of detect_rows on its own, for match words scanned elsewhere
+    (the plain reference the tests compare against).
 
     Rows fold to REQUESTS before the factor→rule expansion
     (:func:`fold_rows` then :func:`expand_requests`): the (·, F) ×
@@ -295,22 +295,53 @@ def _split_bucket(packed: jax.Array):
             packed[:, L + 8:L + 8 + N_SV])
 
 
-def _match_words(impl: str, tables: EngineTables, scanner, tokens,
-                 lengths, interpret: bool) -> jax.Array:
-    """One bucket's sticky match words (B, W) uint32 under ``impl``;
-    ``scanner`` is the Pallas scanner of the Pallas implementations
-    (it carries its own packed full-width tables), None otherwise."""
-    if scanner is not None:
-        return scanner(tokens, lengths, interpret=interpret)[0]
-    if impl == "take":
-        return scan_bytes_jit(tables.scan, tokens, lengths)[0]
-    return scan_pairs_jit(tables.scan, tokens, lengths)[0]
+#: the two XLA lowerings of ops/scan.py: "pair" = class-pair stride
+#: (one reach gather per two bytes), "take" = one gather per byte — the
+#: plain reference, what carries state across streamed chunks, and what
+#: serves a pack whose tables hold no pair tables.  "pallas" = the byte
+#: kernel of ops/pallas_scan.py, by name only: nothing selects it
+#: (PERF.md §6 PR 31: fastest at the 2,048 tier, behind "pair" at the
+#: others)
+SCAN_IMPLS = ("pair", "take", "pallas")
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
-def scan_fold_bucket(tables: EngineTables, scanner, packed: jax.Array,
-                     req_fh: jax.Array, req_sv: jax.Array,
-                     impl: str, interpret: bool = False):
+def resolve_scan_impl(scan_impl: str, scan: ScanTables) -> str:
+    """``"auto"`` → ``"pair"`` where the pack's tables carry
+    ``pair_reach``, else ``"take"``: the one place that states the
+    rule.  A named lowering is returned as it is."""
+    if scan_impl == "auto":
+        return "pair" if scan.pair_reach is not None else "take"
+    if scan_impl not in SCAN_IMPLS:
+        raise ValueError("scan_impl must be \"auto\" or one of %s, not %r"
+                         % (SCAN_IMPLS, scan_impl))
+    return scan_impl
+
+
+def _byte_scan(impl: str):
+    """The scan with ``scan_bytes``' whole contract (state carried)
+    behind ``impl``: the kernel for "pallas", else ``scan_bytes``."""
+    if impl == "pallas":
+        from ingress_plus_tpu.ops.pallas_scan import pallas_scan_bytes
+
+        return pallas_scan_bytes
+    return scan_bytes
+
+
+def _match_words(impl: str, tables: EngineTables, tokens,
+                 lengths) -> jax.Array:
+    """One bucket's sticky match words (B, W) uint32 under ``impl``."""
+    if impl == "pair":
+        scan = scan_pairs_jit
+    elif impl == "take":
+        scan = scan_bytes_jit
+    else:
+        scan = _byte_scan(impl)
+    return scan(tables.scan, tokens, lengths)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def scan_fold_bucket(tables: EngineTables, packed: jax.Array,
+                     req_fh: jax.Array, req_sv: jax.Array, impl: str):
     """One bucket's whole device work as ONE program: unpack the
     shipped buffer, scan its rows, fold them into the per-request
     accumulators (:func:`fold_rows`).  The accumulators have the
@@ -320,7 +351,7 @@ def scan_fold_bucket(tables: EngineTables, scanner, packed: jax.Array,
     the profiler prints ``jit_<name>`` and the benchmark's roofline
     finds the scan programs by that word.)"""
     tokens, lengths, row_req, row_sv = _split_bucket(packed)
-    m = _match_words(impl, tables, scanner, tokens, lengths, interpret)
+    m = _match_words(impl, tables, tokens, lengths)
     return fold_rows(tables, m, row_req, row_sv, req_fh, req_sv)
 
 
@@ -336,12 +367,9 @@ def detect_rows(
     scan_impl: str = "auto",
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """The full detection step (jit this with static num_requests and
-    scan_impl).  ``scan_impl``: "auto"/"pair" = class-pair stride (when
-    available), "take" = per-byte scan with dynamic-gather reach.  The
-    "pallas" implementation lives outside this jit (DetectionEngine
-    dispatches the kernel, then map_match_words_jit)."""
-    if (scan_impl in ("auto", "pair")
-            and tables.scan.pair_reach is not None and state is None):
+    scan_impl; ``scan_impl`` as :func:`resolve_scan_impl` takes it)."""
+    impl = resolve_scan_impl(scan_impl, tables.scan)
+    if impl == "pair" and state is None:
         # class-pair stride: half the steps, one reach gather per two
         # bytes (ops/scan.py scan_pairs) — the request path only consumes
         # the match mask, so the pair path's zero-state-after-padding
@@ -349,7 +377,7 @@ def detect_rows(
         match_words, state = scan_pairs(
             tables.scan, tokens, lengths, None, match)
     else:
-        match_words, state = scan_bytes(
+        match_words, state = _byte_scan(impl)(
             tables.scan, tokens, lengths, state, match)
     rule_hits, class_hits, scores = map_match_words(
         tables, match_words, row_req, row_sv, num_requests)
@@ -360,8 +388,6 @@ detect_rows_jit = jax.jit(
     detect_rows, static_argnames=("num_requests", "scan_impl"))
 
 
-
-
 class DetectionEngine:
     """Host-facing wrapper: upload tables once, detect per batch.
 
@@ -370,17 +396,9 @@ class DetectionEngine:
     the jit cache is reused; the old tables are dropped after the next
     dispatch completes (double-buffered by XLA's async dispatch)."""
 
-    #: selectable scan implementations (VERDICT: the serving path must be
-    #: able to run the Pallas kernel, picked by measurement, not by hope).
-    #: "pallas2" = the round-4 class-pair Pallas kernel (half the serial
-    #: steps, class-compressed MXU gather, double-buffered chunk overlap)
-    #: "pallas3" = the raw-byte FUSED kernel (ISSUE 13): uint8 request
-    #: bytes + lengths in, byte→reach mapping and padding handled inside
-    #: the device program — host prep approaches a memcpy; on CPU the
-    #: same math runs as its XLA reference lowering (tests)
-    SCAN_IMPLS = ("pair", "take", "pallas", "pallas2", "pallas3")
+    SCAN_IMPLS = SCAN_IMPLS
 
-    def __init__(self, cr: CompiledRuleset, scan_impl: str = "pair"):
+    def __init__(self, cr: CompiledRuleset, scan_impl: str = "auto"):
         self.ruleset = cr
         self.tables = EngineTables.from_ruleset(cr)
         # head-sliced twin (docs/SCAN_KERNEL.md): word prefix + the
@@ -391,18 +409,8 @@ class DetectionEngine:
         self.head_tables = (
             EngineTables.from_ruleset(cr, head_only=True)
             if 0 < cr.tables.n_head_words < cr.tables.n_words else None)
-        self.scan_impl = scan_impl        # one of SCAN_IMPLS
-        #: {impl: seconds} of the start-up bake-off that chose scan_impl;
-        #: None when the implementation was named, not measured
-        self.bakeoff: Optional[dict] = None
-        self.pallas_interpret = False     # tests force True on CPU
-        self._pallas = None
-        self._pallas2 = None
-        self._pallas3 = None
-        # per-device pallas3 replicas (NamedSharding placement — the
-        # sigpack-replication story extended to the Pallas path):
-        # {device: PallasByteScanner}
-        self._pallas3_dev: dict = {}
+        #: the lowering every launch runs, resolved here once
+        self.scan_impl = resolve_scan_impl(scan_impl, self.tables.scan)
         # per-device replicated tables (docs/MESH_SERVING.md): the
         # sigpack rides to each serve lane's chip ONCE, at first use —
         # {device: (tables, head_tables|None)}
@@ -426,8 +434,6 @@ class DetectionEngine:
         MeshEngine) survives the swap instead of silently reverting to
         the single-chip engine."""
         eng = type(self)(cr, scan_impl=self.scan_impl)
-        eng.pallas_interpret = self.pallas_interpret
-        eng.bakeoff = self.bakeoff
         eng.device_launches = self.device_launches
         eng.request_capacity = self.request_capacity
         return eng
@@ -439,11 +445,6 @@ class DetectionEngine:
         t = self.ruleset.tables
         return {
             "scan_impl": self.scan_impl,
-            # what the host ships per dispatch (ISSUE 13): raw uint8
-            # request bytes for the fused kernel, prepped/padded rows
-            # for everything else
-            "scan_contract": ("raw-bytes" if self.scan_impl == "pallas3"
-                              else "prepped-rows"),
             "n_rules": int(self.ruleset.n_rules),
             "n_factors": int(t.n_factors),
             "n_words": int(t.n_words),
@@ -454,14 +455,9 @@ class DetectionEngine:
         }
 
     def head_slicing_active(self) -> bool:
-        """True iff a head-only dispatch would actually use the sliced
-        tables: the pack is word-tiered AND the scan impl honors the
-        slice (the Pallas kernels are built on the full tables — for
-        them head_only is a no-op, so callers must not key executables
-        or warm twins on it)."""
-        return (self.head_tables is not None
-                and self.scan_impl not in ("pallas", "pallas2",
-                                           "pallas3"))
+        """True iff a head-only dispatch scans the sliced tables: the
+        pack is word-tiered."""
+        return self.head_tables is not None
 
     def swap_ruleset(self, cr: CompiledRuleset) -> None:
         # tables are a jit *argument* (pytree), so a geometry change just
@@ -472,10 +468,6 @@ class DetectionEngine:
         self.head_tables = (
             EngineTables.from_ruleset(cr, head_only=True)
             if 0 < cr.tables.n_head_words < cr.tables.n_words else None)
-        self._pallas = None
-        self._pallas2 = None
-        self._pallas3 = None
-        self._pallas3_dev = {}
         self._device_tables = {}
         self._zero_acc = {}
 
@@ -497,66 +489,20 @@ class DetectionEngine:
             self._device_tables[key] = pair
         return pair
 
-    # ----------------------------------------------------- scan backends
-
-    def _pallas_scanner(self):
-        if self._pallas is None:
-            from ingress_plus_tpu.ops.pallas_scan import PallasScanner
-            self._pallas = PallasScanner(self.tables.scan)
-        return self._pallas
-
-    def _pallas_pair_scanner(self):
-        if self._pallas2 is None:
-            from ingress_plus_tpu.ops.pallas_scan import PallasPairScanner
-            self._pallas2 = PallasPairScanner(self.tables.scan)
-        return self._pallas2
-
-    def _pallas_byte_scanner(self, device=None):
-        """The raw-byte fused scanner (scan_impl "pallas3"); ``device``
-        returns (building once per chip per generation) a replica whose
-        packed tables are NamedSharding-placed on that lane's chip."""
-        if self._pallas3 is None:
-            from ingress_plus_tpu.ops.pallas_scan import PallasByteScanner
-            self._pallas3 = PallasByteScanner(self.tables.scan)
-        if device is None:
-            return self._pallas3
-        sc = self._pallas3_dev.get(device)
-        if sc is None:
-            sc = self._pallas3.for_device(device)
-            self._pallas3_dev[device] = sc
-        return sc
-
-    def _scanner(self, device=None):
-        """The Pallas scanner behind ``scan_impl`` (only pallas3 can be
-        placed on ``device``); None for the XLA lowerings."""
-        if self.scan_impl == "pallas":
-            return self._pallas_scanner()
-        if self.scan_impl == "pallas2":
-            return self._pallas_pair_scanner()
-        if self.scan_impl == "pallas3":
-            return self._pallas_byte_scanner(device)
-        return None
-
     def drop_compiled(self) -> None:
         """Forget every compiled executable (the recompile_storm fault
         site's hammer; also useful to measure cold-dispatch cost) —
         subsequent dispatches pay fresh XLA compiles."""
         jax.clear_caches()
-        self._pallas = None
-        self._pallas2 = None
-        self._pallas3 = None
-        self._pallas3_dev = {}
         self._device_tables = {}
         self._zero_acc = {}
 
-    def scan_words(self, tabs: EngineTables, tokens, lengths, device=None):
+    def scan_words(self, tabs: EngineTables, tokens, lengths):
         """One bucket's sticky match words (B, W) uint32 under
-        ``scan_impl``, as a program of its own — the legacy per-bucket
-        path and the parity checks (ops/parity.py) use it.  ``tabs`` is
-        what the XLA lowerings scan (full or head-sliced, default or
-        per-device)."""
-        return _match_words(self.scan_impl, tabs, self._scanner(device),
-                            tokens, lengths, self.pallas_interpret)
+        ``scan_impl``, as a program of its own, for the parity checks
+        (ops/parity.py).  ``tabs`` is what is scanned (full or
+        head-sliced, default or per-device)."""
+        return _match_words(self.scan_impl, tabs, tokens, lengths)
 
     def _rule_hits_device(self, tokens, lengths, row_req, row_sv,
                           num_requests: int):
@@ -569,10 +515,6 @@ class DetectionEngine:
         lengths = jnp.asarray(lengths)
         row_req = jnp.asarray(row_req)
         row_sv = jnp.asarray(row_sv)
-        if self.scan_impl in ("pallas", "pallas2", "pallas3"):
-            m = self.scan_words(self.tables, tokens, lengths)
-            return map_match_words_jit(self.tables, m, row_req, row_sv,
-                                       num_requests)
         out = detect_rows_jit(self.tables, tokens, lengths, row_req,
                               row_sv, num_requests,
                               scan_impl=self.scan_impl)
@@ -621,10 +563,7 @@ class DetectionEngine:
         (docs/MESH_SERVING.md): the programs run against that device's
         replicated tables (``tables_for``) and accumulators, and the
         buffers follow them there, so N lanes' dispatches execute
-        concurrently on N chips.  The legacy pallas/pallas2 kernels are
-        built on the default device's tables — for them ``device`` is
-        ignored (documented limitation); pallas3 honors it via
-        per-device scanner replicas (NamedSharding placement)."""
+        concurrently on N chips."""
         faults.sleep_if("dispatch_hang")
         faults.raise_if("dispatch_raise")
         # scan_launch: the host→device transfers and the enqueue of
@@ -641,16 +580,9 @@ class DetectionEngine:
         if not buckets:
             return np.zeros((num_requests, max(self.ruleset.n_rules, 1)),
                             bool)
-        pallas = self.scan_impl in ("pallas", "pallas2", "pallas3")
-        # pallas3 is device-aware: its packed tables replicate per chip
-        # like the sigpack, so mesh lanes keep the raw-byte path
-        if device is not None and pallas and self.scan_impl != "pallas3":
-            device = None
         full_tabs, head_tabs = self.tables_for(device)
-        tabs = (head_tabs
-                if head_only and head_tabs is not None
-                and not pallas else full_tabs)
-        scanner = self._scanner(device)
+        tabs = (head_tabs if head_only and head_tabs is not None
+                else full_tabs)
         # committed arguments (a lane's tables and accumulators) place
         # the program, and the host buffer follows them: the numpy
         # array goes to the device inside the bucket's own call
@@ -658,8 +590,7 @@ class DetectionEngine:
         launched = 0
         for packed in buckets:
             req_fh, req_sv = scan_fold_bucket(
-                tabs, scanner, packed, req_fh, req_sv,
-                impl=self.scan_impl, interpret=self.pallas_interpret)
+                tabs, packed, req_fh, req_sv, impl=self.scan_impl)
             launched += 1
         rule_hits = expand_requests_jit(tabs, req_fh, req_sv,
                                         num_requests=num_requests)[0]
@@ -688,115 +619,3 @@ class DetectionEngine:
         in mesh serving, hence the lock)."""
         with self._launch_lock:
             self.device_launches += n
-
-    # ------------------------------------------------- impl auto-select
-
-    def autoselect_scan_impl(self, B: int = 512, L: int = 256,
-                             k: int = 17, n: int = 2,
-                             include_pallas: Optional[bool] = None,
-                             placeable_only: bool = False) -> dict:
-        """Measure each scan implementation on a representative shape on
-        the live backend and install the fastest (VERDICT round-1: the
-        flagship kernel must be picked by a startup microbench, not left
-        as a demo).  Returns {impl: best per-batch seconds} and keeps it
-        as ``bakeoff``; detection output equality across impls is pinned
-        by tests/test_engine_impls.py, so the choice is purely about
-        speed.  A candidate that fails to compile or run raises: a
-        kernel the chip refuses stops the start-up, it is never scored
-        out of the race.
-
-        ``placeable_only`` (serving with more than one lane) keeps the
-        candidates to the implementations that honor ``device`` — the
-        legacy pallas/pallas2 scanners are built on the default device.
-
-        Timing method: K state-chained repetitions inside ONE jit
-        dispatch, reported as the K-difference (utils/microbench), so
-        per-dispatch overhead cancels.
-        """
-        import functools
-
-        from ingress_plus_tpu.utils.microbench import k_diff_time
-        from ingress_plus_tpu.utils.platform import on_tpu
-
-        if include_pallas is None:
-            include_pallas = on_tpu()   # Mosaic compiles nowhere else
-        candidates = ["pair", "take"] + (
-            ["pallas", "pallas2", "pallas3"] if include_pallas else [])
-        if placeable_only:
-            candidates = [c for c in candidates
-                          if c not in ("pallas", "pallas2")]
-        rng = np.random.default_rng(7)
-        tokens = jnp.asarray(rng.integers(32, 127, (B, L)).astype(np.uint8))
-        lengths = jnp.asarray(np.full((B,), L, np.int32))
-        row_req = jnp.asarray((np.arange(B) % 8).astype(np.int32))
-        n_sv = self.tables.rule_sv.shape[1]
-        row_sv = jnp.asarray(np.ones((B, n_sv), np.int8))
-        tables = self.tables
-        scanner = (self._pallas_scanner() if "pallas" in candidates
-                   else None)
-        scanner2 = (self._pallas_pair_scanner() if "pallas2" in candidates
-                    else None)
-        scanner3 = (self._pallas_byte_scanner() if "pallas3" in candidates
-                    else None)
-        interpret = self.pallas_interpret
-
-        def make_chain(impl):
-            # inputs are jit ARGUMENTS, not closure constants — closed-over
-            # device arrays become compile-time constants and XLA spends
-            # seconds constant-folding the scan chain's scatter-max
-            # (BENCH_r02 tail; the serve-startup log showed the same fold
-            # here in jit(chain))
-            @functools.partial(jax.jit, static_argnames=("kk",))
-            def chain(kk: int, tabs, tok, lens, rreq, rsv):
-                def body(i, carry):
-                    acc, state, match = carry
-                    if impl == "pallas":
-                        match, state = scanner(tok, lens,
-                                               state=state, match=match,
-                                               interpret=interpret)
-                        rh, _, _ = map_match_words(
-                            tabs, match, rreq, rsv, 8)
-                    elif impl == "pallas2":
-                        # pair-kernel state contract (scan_pairs): chain
-                        # the sticky match only
-                        match, state = scanner2(tok, lens, match=match,
-                                                interpret=interpret)
-                        rh, _, _ = map_match_words(
-                            tabs, match, rreq, rsv, 8)
-                    elif impl == "pallas3":
-                        # raw-byte fused kernel: same sticky-match chain
-                        match, state = scanner3(tok, lens, match=match,
-                                                interpret=interpret)
-                        rh, _, _ = map_match_words(
-                            tabs, match, rreq, rsv, 8)
-                    elif impl == "pair":
-                        rh, _, _, match, state = detect_rows(
-                            tabs, tok, lens, rreq, rsv, 8,
-                            match=match, scan_impl="pair")
-                    else:
-                        rh, _, _, match, state = detect_rows(
-                            tabs, tok, lens, rreq, rsv, 8,
-                            state=state, match=match, scan_impl="take")
-                    return (acc + match.sum()
-                            + rh.sum().astype(jnp.uint32), state, match)
-
-                z = jnp.zeros((B, tabs.scan.n_words), jnp.uint32)
-                acc, _, _ = jax.lax.fori_loop(
-                    0, kk, body, (jnp.zeros((), jnp.uint32), z, z))
-                return acc
-            return chain
-
-        timings: dict = {}
-        for impl in candidates:
-            chain = make_chain(impl)
-            dt = k_diff_time(
-                lambda kk, rep: chain(kk, tables, tokens, lengths,
-                                      row_req, row_sv), k, n=n)
-            # <=0 means jitter swamped the compute delta — no signal,
-            # not infinitely fast
-            timings[impl] = dt if dt > 0 else float("inf")
-        best = min(timings, key=timings.get)
-        if timings[best] < float("inf"):
-            self.scan_impl = best
-        self.bakeoff = dict(timings)
-        return timings

@@ -372,7 +372,7 @@ class DetectionPipeline:
         fail_open: bool = True,
         paranoia_level: Optional[int] = None,
         tenant_rule_mask: Optional[np.ndarray] = None,  # (T, R) bool
-        scan_impl: str = "pair",
+        scan_impl: str = "auto",
         acl_store: Optional[AclStore] = None,
         tenant_acl: Optional[Dict[int, str]] = None,
         default_acl: str = "",
@@ -637,7 +637,7 @@ class DetectionPipeline:
             def jobs(sig):
                 return [(self.warm_shape, sig)]
         # the first signature runs inline: it builds the engine's lazy
-        # per-device state (table replicas, scanners) before the pool
+        # per-device state (table replicas) before the pool
         # could race to build it twice
         first = jobs(sigs[0])
         for fn, args in first:

@@ -1,24 +1,20 @@
-"""TPU scan kernels: the per-byte automaton hot loop (SURVEY.md §3.3 #2).
+"""The scan: the per-byte automaton hot loop (SURVEY.md §3.3 #2) as the
+bitap recurrence of compiler/bitap.py.
 
-Two interchangeable implementations of the same bitap recurrence
-(compiler/bitap.py):
+- ``scan.py``   — the two XLA lowerings, `lax.scan` over the time axis
+  with the batch x words update vectorized: ``scan_bytes`` (one reach
+  gather per byte; the plain reference, and what carries the NFA state
+  vector across streamed chunks) and ``scan_pairs`` (class-pair stride:
+  one reach gather per two bytes; what serves).  Runs anywhere (CPU
+  tests, TPU) and is what multi-chip sharding wraps.
+- ``pallas_scan.py`` — a hand-scheduled Pallas kernel with
+  ``scan_bytes``' contract; by name only (``scan_impl="pallas"``),
+  nothing serves it (its docstring says why it stays).
+- ``parity.py`` — the one comparison of a scan against ``scan_bytes``
+  (tests, ``chip_smoke.py`` compiled on the chip).
 
-- ``scan.py``         — pure jnp/XLA: `lax.scan` over byte steps, gather for
-  the byte table.  Runs anywhere (CPU tests, TPU), is the reference
-  implementation, and is what multi-chip sharding wraps.
-- ``pallas_scan.py``  — hand-written Pallas TPU kernel: MXU one-hot reach
-  precompute into VMEM scratch + serial VPU shift-AND chain with state
-  resident in VMEM and early exit on ragged tiles.
-
-Measured on v5e (full 1.4k-rule corpus, W=291, see utils/microbench.py):
-XLA `take` ≈ 200 MB/s, Pallas ≈ 163 MB/s (TB=256, CL=8) — both near
-VPU-bound on the (B, W) recurrence; XLA's gather lowering wins, so
-``scan.py`` is the serving default and the kernel is kept as the
-hand-scheduled alternative (it wins on ragged batches via early exit).
-
-Both expose scan(tokens, lengths, state) → (match, state) so streaming
-chunked bodies (benchmark config #5) carry the NFA state vector across
-calls — the framework's sequence-parallel analog (SURVEY.md §5).
+``models/engine.py resolve_scan_impl`` picks the lowering that serves
+from the pack's tables.
 """
 
 from ingress_plus_tpu.ops.scan import (  # noqa: F401

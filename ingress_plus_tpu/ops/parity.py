@@ -1,11 +1,10 @@
-"""Scan-implementation parity against ops/scan.py.
+"""Scan-lowering parity against ops/scan.py ``scan_bytes``.
 
-The ONE comparison behind ``tools/lint.py --only devicegate`` (Mosaic
-interpreter, CPU, every CI run), ``utils/microbench --scan`` and
-``chip_smoke.py``'s compiled-kernel phase (``interpret=False`` on the
-chip), so the CI gate and the chip check cannot drift apart: a scan
-implementation is right when its sticky match words equal
-``scan_bytes``' bit for bit on the same ragged batch.
+The ONE comparison behind the tier parity test
+(tests/test_engine_impls.py, on the CPU) and ``chip_smoke.py``'s
+compiled-parity phase (on the chip), so the two cannot drift apart: a
+lowering is right when its sticky match words equal ``scan_bytes``' bit
+for bit on the same ragged batch.
 """
 
 from __future__ import annotations
@@ -42,45 +41,37 @@ def ragged_batch(B: int, L: int, seed: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def compare_scan(scan: Callable, tables: ScanTables, tokens: np.ndarray,
-                 lengths: np.ndarray, check_state: bool = False) -> dict:
+                 lengths: np.ndarray) -> dict:
     """Run ``scan(tokens, lengths) -> (match, state)`` and the
-    ``scan_bytes`` reference on the default device; report bit
-    equality.  ``check_state`` also compares the carried state (only
-    the byte kernel keeps the full scan_bytes state contract)."""
-    want_m, want_s = scan_bytes_jit(tables, tokens, lengths)
-    got_m, got_s = scan(tokens, lengths)
-    want_m = np.asarray(want_m)
-    case = {
+    ``scan_bytes`` reference on the default device; report bit equality
+    of the match words (the pair stride does not keep ``scan_bytes``'
+    state contract for rows shorter than L, and only the match is
+    consumed)."""
+    want_m = np.asarray(scan_bytes_jit(tables, tokens, lengths)[0])
+    got_m = scan(tokens, lengths)[0]
+    return {
         "B": int(tokens.shape[0]), "L": int(tokens.shape[1]),
         "match_equal": bool(np.array_equal(np.asarray(got_m), want_m)),
         "non_vacuous": bool(want_m.any()),
     }
-    if check_state:
-        case["state_equal"] = bool(
-            np.array_equal(np.asarray(got_s), np.asarray(want_s)))
-    return case
 
 
 def failed(cases: Iterable[dict]) -> List[dict]:
     """The cases that diverged from the reference."""
-    return [c for c in cases
-            if not c["match_equal"] or c.get("state_equal") is False]
+    return [c for c in cases if not c["match_equal"]]
 
 
 def engine_parity(engine, shapes: Iterable[Tuple[int, int]],
-                  interpret: bool, seed: int = 7,
+                  seed: int = 7,
                   workers: int = 1) -> Dict[str, List[dict]]:
     """Every member of ``engine.SCAN_IMPLS`` through the engine's own
-    per-bucket dispatch (``scan_words`` — the scanners and tilings it
-    serves with) at each ``(B, L)`` in ``shapes``, against
-    ``scan_bytes`` on the same device.  ``interpret=False`` compiles
-    the Mosaic kernels.  A scan that fails to compile or run raises.
-    Returns {impl: [case, ...]}."""
+    ``scan_words`` at each ``(B, L)`` in ``shapes``, against
+    ``scan_bytes`` on the same device.  A scan that fails to compile or
+    run raises.  Returns {impl: [case, ...]}."""
     from concurrent.futures import ThreadPoolExecutor
 
     shapes = sorted(set(shapes))
     tables = engine.tables.scan
-    engine.pallas_interpret = interpret
     out: Dict[str, List[dict]] = {}
     saved = engine.scan_impl
     try:
@@ -96,13 +87,9 @@ def engine_parity(engine, shapes: Iterable[Tuple[int, int]],
                 case["impl"] = impl
                 return case
 
-            # the first shape inline builds the engine's lazy scanner;
-            # the rest overlap their compiles on the pool
-            cases = [one(shapes[0])]
+            # the shapes overlap their compiles on the pool
             with ThreadPoolExecutor(max_workers=max(1, workers)) as pool:
-                for fut in [pool.submit(one, s) for s in shapes[1:]]:
-                    cases.append(fut.result())
-            out[impl] = cases
+                out[impl] = list(pool.map(one, shapes))
     finally:
         engine.scan_impl = saved
     return out

@@ -149,9 +149,7 @@ def build_class_pair_tables(byte_table: np.ndarray, init_mask: np.ndarray,
 def classes_for(byte_class: jax.Array, tokens: jax.Array,
                 lengths: jax.Array) -> jax.Array:
     """(B, L) byte rows → (B, L) class ids with padding (pos ≥ length)
-    mapped to the DEAD class via the 256 sentinel — the one byte→class
-    mapping shared by scan_pairs and the Pallas pair kernel so the
-    dead-class convention cannot diverge between them (round-4 review)."""
+    mapped to the DEAD class via the 256 sentinel."""
     L = tokens.shape[1]
     toks = jnp.where(
         jnp.arange(L, dtype=jnp.int32)[None, :]

@@ -209,7 +209,6 @@ class MeshEngine:
         self.mesh = mesh
         self._sharded = ShardedEngine(cr, mesh, scan_impl=scan_impl)
         self._tables = None        # lazy single-chip tables (stream path)
-        self.pallas_interpret = False
 
     # ------------------------------------------------ engine API surface
 
@@ -263,24 +262,18 @@ class MeshEngine:
     def rebuilt(self, cr: CompiledRuleset) -> "MeshEngine":
         """Fresh engine of the SAME kind on a new ruleset (batcher
         hot-swap contract — see DetectionEngine.rebuilt)."""
-        eng = MeshEngine(cr, self.mesh, scan_impl=self.scan_impl)
-        eng.pallas_interpret = self.pallas_interpret
-        return eng
+        return MeshEngine(cr, self.mesh, scan_impl=self.scan_impl)
 
     def autoselect_scan_impl(self, **kw) -> dict:
         """Measure the sharded impls on the live mesh, install the
         winner, and return {impl: seconds} (the server prints it).
         Measures the global-rows step — the variant _dispatch serves
         with — so the bake-off ranks and pre-warms the real program."""
-        self._sync_interpret()
         kw.setdefault("global_rows", True)
         self._sharded.autoselect_scan_impl(**kw)
         return dict(getattr(self._sharded, "last_timings", {}))
 
     # -------------------------------------------------------- dispatch
-
-    def _sync_interpret(self) -> None:
-        self._sharded.pallas_interpret = self.pallas_interpret
 
     def _dispatch(self, tokens, lengths, row_req, row_sv,
                   num_requests: int):
@@ -294,7 +287,6 @@ class MeshEngine:
         the row count — which makes every jit shape a function of
         (B, L, Q) alone, exactly what the batcher's warm_shape replay
         (seen_shapes contract) pre-compiles."""
-        self._sync_interpret()
         eng = self._sharded
         n_data = eng.mesh.shape["data"]
         tokens = np.asarray(tokens)

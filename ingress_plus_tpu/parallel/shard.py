@@ -27,15 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ingress_plus_tpu.compiler.ruleset import CompiledRuleset, N_SV
 from ingress_plus_tpu.compiler.seclang import CLASSES
-from ingress_plus_tpu.ops.pallas_scan import (
-    _pallas_pair_scan,
-    _round_up,
-    check_pair_tiling,
-    pack_pair_tables,
-)
 from ingress_plus_tpu.ops.scan import (
     build_class_pair_tables,
-    classes_for,
     scan_bytes,
     scan_pairs,
 )
@@ -58,8 +51,8 @@ class ShardedTables:
     rule_class: np.ndarray    # (R, C) float32
     rule_no_prefilter: np.ndarray  # (R,) bool
     # ---- per-shard class-pair stride (round-4, VERDICT item #7): the
-    # single-chip bake-off winner (scan_pairs) sharded along words.  Byte
-    # classes are computed PER SHARD from that shard's byte-table slice —
+    # lowering the single chip serves (scan_pairs), sharded along words.
+    # Byte classes are computed PER SHARD from that shard's byte-table slice —
     # a shard sees fewer distinct reach rows than the full table, so its
     # class count k_s is smaller; all shards pad to k_max with the dead
     # class LAST at index k_max (uniform shapes under shard_map).
@@ -152,11 +145,8 @@ class ShardedEngine:
     shared superset NFA (benchmark config #4: 256 Ingress tenants).
     """
 
-    #: "pair"    = class-pair stride via XLA (single-chip bake-off winner)
-    #: "take"    = one-gather-per-byte fallback
-    #: "pallas2" = the class-pair Pallas kernel, run per ruleset shard
-    #:             inside shard_map on that shard's packed tables
-    SCAN_IMPLS = ("pair", "take", "pallas2")
+    #: "pair" = class-pair stride, "take" = one gather per byte
+    SCAN_IMPLS = ("pair", "take")
 
     def __init__(self, cr: CompiledRuleset, mesh: Mesh,
                  tenant_rule_mask: np.ndarray | None = None,
@@ -172,10 +162,6 @@ class ShardedEngine:
             raise ValueError("sharded scan_impl must be one of %s"
                              % (self.SCAN_IMPLS,))
         self.scan_impl = scan_impl
-        # pallas2 tile config + interpret knob (tests force True on CPU)
-        self.p2_TB, self.p2_CL = 64, 16
-        self.p2_MR = check_pair_tiling(self.p2_TB, self.p2_CL, 128)
-        self.pallas_interpret = False
 
         def put(arr, spec):
             return jax.device_put(arr, NamedSharding(mesh, spec))
@@ -197,23 +183,6 @@ class ShardedEngine:
         self.d_ctab = put(st.class_table, P("model", None, None))
         self.d_preach = put(st.pair_reach, P("model", None, None))
         self.d_pfinal = put(st.pair_final, P("model", None, None))
-        # pallas2: per-shard tables packed into the kernel layout (ONE
-        # packing — ops/pallas_scan.pack_pair_tables — shared with the
-        # single-chip scanner).  Shapes are uniform across shards because
-        # every shard pads classes to k_max and words to w_shard.
-        self.p2_Wp = _round_up(max(st.w_shard, 128), 128)
-        planes_l, pinit_l, pfinal_l = [], [], []
-        for s in range(n_model):
-            sl = slice(s * st.w_shard, (s + 1) * st.w_shard)
-            pls, ini, fin, _K1p, _Wp = pack_pair_tables(
-                st.class_table[s], st.init_mask[sl], st.final_mask[sl])
-            planes_l.append(pls)
-            pinit_l.append(ini)
-            pfinal_l.append(fin)
-        self.d_p2planes = put(jnp.asarray(np.stack(planes_l), jnp.bfloat16),
-                              P("model", None, None))
-        self.d_p2init = put(np.stack(pinit_l), P("model", None, None))
-        self.d_p2final = put(np.stack(pfinal_l), P("model", None, None))
         self._steps = {}
         self._step = self._build_step(self.scan_impl)
 
@@ -236,17 +205,12 @@ class ShardedEngine:
         one extra psum over the data axis — placement-free, so batch
         shapes depend only on (B, L, Q) and the batcher's warm_shape
         replay compiles exactly the executables live traffic hits."""
-        key = (scan_impl, self.pallas_interpret, global_rows)
+        key = (scan_impl, global_rows)
         if key in self._steps:
             return self._steps[key]
         mesh = self.mesh
-        TB, CL, MR = self.p2_TB, self.p2_CL, self.p2_MR
-        Wp = self.p2_Wp
-        k_max = self.st.k_max
-        interpret = self.pallas_interpret
 
         def block(byte_table, init, final, bcls, ctab, preach, pfinal,
-                  p2planes, p2init, p2final,
                   fw, fb, fr, rule_sv, score,
                   cls_map, nopf, tenant_mask, tokens, lengths, row_req,
                   row_sv, tenants, num_requests):
@@ -256,10 +220,8 @@ class ShardedEngine:
             w_shard = byte_table.shape[1]
 
             # word-local scan — ZERO communication.  "pair" runs the
-            # single-chip bake-off winner (class-pair stride: one reach
-            # gather per TWO bytes) on this shard's own class tables;
-            # "pallas2" runs the hand kernel on the same per-shard
-            # tables; "take" is the one-gather-per-byte fallback.
+            # class-pair stride (one reach gather per TWO bytes) on this
+            # shard's own class tables; "take" is one gather per byte.
             class _T:  # minimal ScanTables duck-type for the scan kernels
                 n_words = byte_table.shape[1]
             t = _T()
@@ -271,24 +233,6 @@ class ShardedEngine:
                 t.pair_reach = preach[0]
                 t.pair_final = pfinal[0]
                 match, _ = scan_pairs(t, tokens, lengths)
-            elif scan_impl == "pallas2":
-                cls = classes_for(bcls[0], tokens, lengths)   # (B_s, L)
-                B_s, L = cls.shape
-                Bp = -(-max(B_s, TB) // TB) * TB
-                Lp = -(-max(L, CL) // CL) * CL
-                # dead class (zero reach) = index k_max; padding rows
-                # and columns die immediately, like scan_pairs
-                cls_p = jnp.full((Bp, Lp), k_max, jnp.int32)
-                cls_p = cls_p.at[:B_s, :L].set(cls)
-                len_p = jnp.zeros((Bp, 1), jnp.int32)
-                len_p = len_p.at[:B_s, 0].set(lengths.astype(jnp.int32))
-                zeros = jnp.zeros((Bp, Wp), jnp.int32)
-                out_m, _ = _pallas_pair_scan(
-                    cls_p, len_p, p2planes[0], p2init[0], p2final[0],
-                    zeros, zeros, TB=TB, CL=CL, MR=MR,
-                    interpret=interpret)
-                match = jax.lax.bitcast_convert_type(
-                    out_m[:B_s, :w_shard], jnp.uint32)
             else:
                 match, _ = scan_bytes(t, tokens, lengths, gather="take")
 
@@ -354,8 +298,6 @@ class ShardedEngine:
                     P(None, "model"), P("model"), P("model"),      # tables
                     P("model", None), P("model", None, None),      # pair
                     P("model", None, None), P("model", None, None),
-                    P("model", None, None), P("model", None, None),  # p2
-                    P("model", None, None),
                     P("model", None), P("model", None),
                     P("model", None, None),
                     P(None, None), P(None), P(None, None), P(None),
@@ -369,9 +311,7 @@ class ShardedEngine:
             )
             return fn(self.d_byte, self.d_init, self.d_final,
                       self.d_bcls, self.d_ctab, self.d_preach,
-                      self.d_pfinal,
-                      self.d_p2planes, self.d_p2init, self.d_p2final,
-                      self.d_fw,
+                      self.d_pfinal, self.d_fw,
                       self.d_fb, self.d_fr, self.d_rule_sv, self.d_score,
                       self.d_class, self.d_nopf, self.d_tenant,
                       tokens, lengths, row_req, row_sv, tenants)
@@ -381,17 +321,10 @@ class ShardedEngine:
 
     def autoselect_scan_impl(self, B: int = 256, L: int = 256,
                              iters: int = 17,
-                             include_pallas: bool | None = None,
                              global_rows: bool = False) -> str:
-        """Measure the sharded scan impls on THIS mesh and keep the
-        winner — the sharded extension of
-        DetectionEngine.autoselect_scan_impl (round-4, VERDICT item #7:
-        the multi-chip step used the gather scan unconditionally while
-        the single-chip bake-off winner was pair).  K-chained timing
-        like utils/microbench: per-impl, run the jitted step iters times
-        back-to-back and difference, so dispatch overhead mostly
-        cancels.  pallas2 joins the bake-off on a TPU only (interpret
-        mode would never win on CPU)."""
+        """Measure the two sharded lowerings on THIS mesh and keep the
+        winner: per impl, run the jitted step iters times back-to-back,
+        so dispatch overhead mostly cancels."""
         import time as _time
 
         if jax.process_count() > 1:
@@ -399,10 +332,6 @@ class ShardedEngine:
             # detect()); a measurement pass is not worth coordinating
             # across hosts — keep the configured impl
             return self.scan_impl
-        if include_pallas is None:
-            from ingress_plus_tpu.utils.platform import on_tpu
-
-            include_pallas = on_tpu()
         n_data = self.mesh.shape["data"]
         B = -(-B // n_data) * n_data
         rng = np.random.default_rng(7)
@@ -417,9 +346,7 @@ class ShardedEngine:
         tenants = np.zeros((B,), np.int32)
 
         timings = {}
-        candidates = ("take", "pair") + (
-            ("pallas2",) if include_pallas else ())
-        for impl in candidates:
+        for impl in ("take", "pair"):
             # measure the step VARIANT the caller serves with (the mesh
             # adapter runs global_rows=True; timing the local-rows
             # program would rank a program live traffic never executes

@@ -2055,30 +2055,16 @@ class Batcher:
         return out
 
     def device_path_snapshot(self) -> dict:
-        """What the scan plane actually ships per dispatch (ISSUE 13,
-        docs/SCAN_KERNEL.md "Device path"): scan impl, host contract
-        (raw uint8 bytes vs host-prepped rows), live jax backend, and
-        the per-lane device placement — served under /healthz
-        ``robustness.device_path`` so "is the raw-byte device path
-        live on a real chip" is one probe, not a checkpoint read."""
+        """What the scan plane runs on: the scan lowering the engine
+        resolved, the live jax backend, and the per-lane device
+        placement — served under /healthz ``robustness.device_path``."""
         import jax
 
         from ingress_plus_tpu.utils.platform import device_block
 
-        eng = self.pipeline.engine
-        impl = getattr(eng, "scan_impl", "?")
         dev = device_block()
-        bakeoff = getattr(eng, "bakeoff", None)
-        if bakeoff is not None:   # inf (no timing signal) is not JSON
-            bakeoff = {k: (v if v != float("inf") else None)
-                       for k, v in bakeoff.items()}
         return {
-            "scan_impl": impl,
-            "scan_contract": ("raw-bytes" if impl == "pallas3"
-                              else "prepped-rows"),
-            # {impl: seconds} of the start-up bake-off; None when
-            # --scan-impl named the implementation
-            "bakeoff": bakeoff,
+            "scan_impl": getattr(self.pipeline.engine, "scan_impl", "?"),
             "backend": jax.default_backend(),
             "device_kind": dev["device_kind"],
             "device_count": dev["device_count"],

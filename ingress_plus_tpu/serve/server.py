@@ -1626,34 +1626,9 @@ def build_default_batcher(mode: str = "block", rules_dir: Optional[str] = None,
               % (confirm_workers, cores, _os.cpu_count() or 0, n_lanes),
               file=sys.stderr)
     pipeline = DetectionPipeline(
-        cr, mode=mode, engine=engine, confirm_workers=confirm_workers,
+        cr, mode=mode, engine=engine, scan_impl=scan_impl,
+        confirm_workers=confirm_workers,
         confirm_cache_entries=confirm_cache_entries)
-    if mesh_spec:
-        if scan_impl in ("pallas", "pallas3"):
-            # neither the byte kernel nor the raw-byte fused kernel has
-            # a TP-sharded variant; the class-pair kernel is their mesh
-            # counterpart
-            print("mesh serving: --scan-impl %s -> pallas2 "
-                  "(sharded variant)" % scan_impl, file=sys.stderr)
-            scan_impl = "pallas2"
-    if n_lanes > 1 and scan_impl in ("pallas", "pallas2"):
-        raise ValueError(
-            "--scan-impl %s cannot be placed on a lane's device (its "
-            "tables live on the default device): with --lanes > 1 every "
-            "lane would dispatch to one chip" % scan_impl)
-    if scan_impl == "auto":
-        # startup microbench on the LIVE backend picks the serving scan
-        # implementation by measurement; a candidate that fails to
-        # compile fails the start-up
-        timings = pipeline.engine.autoselect_scan_impl(
-            **({"placeable_only": True} if n_lanes > 1 else {}))
-        print("scan impl auto-select: %s  (%s)" % (
-            pipeline.engine.scan_impl,
-            ", ".join("%s=%.2fms" % (k, v * 1e3)
-                      for k, v in sorted(timings.items()))),
-            file=sys.stderr)
-    else:
-        pipeline.engine.scan_impl = scan_impl
     if warmup and n_lanes <= 1:
         warmup_pipeline(pipeline, max_batch)
         # the warmup corpus is synthetic (20% attacks): drop it from
@@ -1808,12 +1783,11 @@ def main(argv=None) -> None:
                          "repeated identical traffic stops paying "
                          "confirm entirely.  0 (default) keeps the "
                          "per-cycle flood memo only")
-    ap.add_argument("--scan-impl", default="auto",
-                    choices=["auto", "pair", "take", "pallas", "pallas2",
-                             "pallas3"],
-                    help="TPU scan implementation; auto = startup "
-                         "microbench on the live backend picks the "
-                         "fastest (pallas excluded on cpu)")
+    ap.add_argument("--scan-impl", default="auto", choices=["auto"],
+                    help="accepted for the benchmark's configurations, "
+                         "which pass it; selects nothing: the scan "
+                         "lowering follows from the pack's tables "
+                         "(models/engine.py resolve_scan_impl)")
     ap.add_argument("--spool-dir", default=None,
                     help="postanalytics spool dir (attacks.jsonl); "
                          "enables the exporter loop")

@@ -13,11 +13,10 @@ import argparse
 import time
 
 import jax
-import numpy as np
 
 from ingress_plus_tpu.compiler.ruleset import compile_ruleset
 from ingress_plus_tpu.compiler.sigpack import load_bundled_rules
-from ingress_plus_tpu.ops.scan import ScanTables, scan_bytes_jit
+from ingress_plus_tpu.ops.scan import ScanTables
 
 
 def best_time(call, k: int, n: int = 2) -> float:
@@ -108,200 +107,6 @@ def bench_pairs(tables: ScanTables, batch: int, length: int,
     per = k_diff_time(
         lambda k, rep: scan_k(jax.random.PRNGKey(100 * k + rep), k), iters)
     return batch * length / per / 1e6
-
-
-def bench_pallas(tables: ScanTables, batch: int, length: int,
-                 iters: int = 65, TB: int = 8, CL: int = 128,
-                 MR: int = 128) -> float:
-    """MB/s for the Pallas kernel (ops/pallas_scan.py), K-diff timed the
-    same way as bench_scan.  Table prep (padding, planes) happens once
-    outside the timed region, as in serving."""
-    import functools
-
-    import jax.numpy as jnp
-
-    from ingress_plus_tpu.ops.pallas_scan import PallasScanner, _pallas_scan
-
-    # reuse the serving scanner's packing so the benchmark always measures
-    # the shipped bit layout (prep is outside the timed region either way)
-    sc = PallasScanner(tables, TB=TB, CL=CL, MR=MR)
-    planes, init, final = sc.planes, sc.init, sc.final
-    Wp, mr = sc.Wp, sc.MR
-
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def scan_k(key, k):
-        tokens = jax.random.randint(key, (batch, length), 32, 127,
-                                    dtype=jnp.int32)
-        lengths = jnp.full((batch, 1), length, dtype=jnp.int32)
-
-        def body(i, carry):
-            s, m = carry
-            m, s = _pallas_scan(tokens, lengths, planes, init, final, s, m,
-                                TB=TB, CL=CL, MR=mr, interpret=False)
-            return (s, m)
-
-        s = jnp.zeros((batch, Wp), jnp.int32)
-        s, m = jax.lax.fori_loop(0, k, body, (s, jnp.zeros_like(s)))
-        return m[0, 0]
-
-    per_scan = k_diff_time(
-        lambda k, rep: scan_k(jax.random.PRNGKey(100 * k + rep), k), iters)
-    return batch * length / per_scan / 1e6
-
-
-def bench_scan_modes(tables: ScanTables = None,
-                     shapes=((512, 64), (256, 128), (128, 256)),
-                     iters: int = 17,
-                     interpret_shape=(8, 64)) -> dict:
-    """Scan-path A/B for the raw-byte device path (ISSUE 13,
-    ``--scan``): per (B, L) — the bundled pack's dominant serving
-    bucket tiers — measure
-
-    * ``xla_scan``: ops/scan.py ``scan_bytes``, the per-byte
-      ``lax.scan`` lowering (the baseline the acceptance gate names);
-    * ``fused``: the pallas3 raw-byte fused program — the compiled
-      Mosaic kernel on TPU backends, its XLA reference lowering on CPU
-      (bit-identical math, the class-pair fold; docs/SCAN_KERNEL.md
-      "Device path").  uint8 tokens generated in-program, tables as
-      jit ARGUMENTS (nothing constant-folds — the BENCH_r02 lesson).
-
-    Plus ONE parity run at a small shape through ops/parity.py (the
-    comparison devicegate and chip_smoke.py share): the compiled kernel
-    on a TPU, the Mosaic interpreter elsewhere, bit-identical against
-    ``scan_bytes``.  K-diff timing throughout; every result names the
-    platform, device kind and device count it ran on.
-    """
-    import functools
-
-    import jax.numpy as jnp
-
-    from ingress_plus_tpu.ops.pallas_scan import PallasByteScanner
-    from ingress_plus_tpu.ops.parity import compare_scan, ragged_batch
-    from ingress_plus_tpu.ops.scan import scan_bytes, scan_pairs
-    from ingress_plus_tpu.utils.platform import device_block
-
-    if tables is None:
-        cr = compile_ruleset(load_bundled_rules())
-        tables = ScanTables.from_bitap(cr.tables)
-    sc = PallasByteScanner(tables)
-    use_kernel = sc._use_kernel()
-    W = tables.n_words
-    out: dict = {
-        "metric": "scan-path MB/s per dominant (B, L) bucket tier, "
-                  "K-diff timed",
-        **device_block(),
-        "fused_lowering": ("mosaic-kernel" if use_kernel
-                           else "xla-reference"),
-        "n_words": int(W),
-        "shapes": [],
-    }
-
-    @functools.partial(jax.jit, static_argnames=("k", "B", "L"))
-    def xla_scan_k(key, k, tabs, lengths, B, L):
-        tokens = jax.random.randint(key, (B, L), 32, 127, dtype=jnp.int32)
-
-        def body(i, carry):
-            s, m = carry
-            m, s = scan_bytes(tabs, tokens, lengths, state=s, match=m)
-            return (s, m)
-
-        z = jnp.zeros((B, W), jnp.uint32)
-        s, m = jax.lax.fori_loop(0, k, body, (z, z))
-        return m.sum()
-
-    @functools.partial(jax.jit, static_argnames=("k", "B", "L"))
-    def fused_ref_k(key, k, tabs, lengths, B, L):
-        tokens = jax.random.randint(
-            key, (B, L), 32, 127, dtype=jnp.int32).astype(jnp.uint8)
-
-        def body(i, m):
-            m2, _ = scan_pairs(tabs, tokens, lengths, None, m)
-            return m2
-
-        m = jax.lax.fori_loop(0, k, body, jnp.zeros((B, W), jnp.uint32))
-        return m.sum()
-
-    def fused_kernel_k(B, L):
-        from ingress_plus_tpu.ops.pallas_scan import _fused_byte_scan
-
-        @functools.partial(jax.jit, static_argnames=("k",))
-        def kk(key, k, planes, init, final, lengths):
-            tokens = jax.random.randint(
-                key, (B, L), 32, 127, dtype=jnp.int32).astype(jnp.uint8)
-
-            def body(i, m):
-                m2, _ = _fused_byte_scan(
-                    tokens, lengths, planes, init, final,
-                    jnp.zeros((B, W), jnp.uint32), m,
-                    TB=sc.TB, CL=sc.CL, MR=sc.MR, interpret=False)
-                return m2
-
-            m = jax.lax.fori_loop(0, k, body,
-                                  jnp.zeros((B, W), jnp.uint32))
-            return m.sum()
-
-        return kk
-
-    fused_wins = True
-    for B, L in shapes:
-        # ragged like serving: 3/4 of the rows fill the tier, the rest
-        # sit at half — both lowerings walk the padded length, so the
-        # comparison stays apples-to-apples
-        lens_np = np.full((B,), L, np.int32)
-        lens_np[::4] = max(1, L // 2)
-        lengths = jnp.asarray(lens_np)
-        row = {"B": B, "L": L}
-        dt = k_diff_time(
-            lambda k, rep: xla_scan_k(
-                jax.random.PRNGKey(100 * k + rep), k, tables, lengths,
-                B, L), iters)
-        row["xla_scan_mb_s"] = (round(B * L / dt / 1e6, 1)
-                                if dt > 0 else None)
-        if use_kernel:
-            kk = fused_kernel_k(B, L)
-            dtf = k_diff_time(
-                lambda k, rep: kk(jax.random.PRNGKey(100 * k + rep), k,
-                                  sc.planes, sc.init, sc.final,
-                                  lengths), iters)
-        else:
-            dtf = k_diff_time(
-                lambda k, rep: fused_ref_k(
-                    jax.random.PRNGKey(100 * k + rep), k, tables,
-                    lengths, B, L), iters)
-        row["fused_mb_s"] = (round(B * L / dtf / 1e6, 1)
-                             if dtf > 0 else None)
-        if row["xla_scan_mb_s"] and row["fused_mb_s"]:
-            row["fused_vs_xla_scan"] = round(
-                row["fused_mb_s"] / row["xla_scan_mb_s"], 3)
-            if row["fused_vs_xla_scan"] < 1.0:
-                fused_wins = False
-        else:
-            row["fused_vs_xla_scan"] = None
-            fused_wins = False
-        out["shapes"].append(row)
-        print("shape B=%-4d L=%-5d  xla_scan=%s MB/s  fused=%s MB/s "
-              "(%sx)  [platform=%s device_kind=%s device_count=%d]"
-              % (B, L, row["xla_scan_mb_s"], row["fused_mb_s"],
-                 row.get("fused_vs_xla_scan"), out["platform"],
-                 out["device_kind"], out["device_count"]))
-    out["fused_wins_all_shapes"] = fused_wins
-
-    # kernel parity at a small shape (full coverage: devicegate in CI,
-    # chip_smoke.py on the chip)
-    B, L = interpret_shape
-    t0 = time.perf_counter()
-    par = compare_scan(
-        lambda t, ln: sc(t, ln, interpret=not use_kernel, mode="kernel"),
-        tables, *ragged_batch(B, L, seed=3))
-    par["ok"] = par["match_equal"]
-    par["wall_ms"] = round((time.perf_counter() - t0) * 1e3, 1)
-    par["lowering"] = "compiled" if use_kernel else "interpreter"
-    out["kernel_parity"] = par
-    print("kernel parity (%dx%d, %s): %s  [platform=%s device_kind=%s "
-          "device_count=%d]"
-          % (B, L, par["lowering"], "OK" if par["ok"] else "DIVERGED",
-             out["platform"], out["device_kind"], out["device_count"]))
-    return out
 
 
 def bench_confirm(n_req: int = 1024, iters: int = 5,
@@ -473,9 +278,7 @@ def main() -> None:
     ap.add_argument("--len", dest="length", type=int, default=1024)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--only", default=None,
-                    choices=[None, "take", "onehot", "pallas", "pair"])
-    ap.add_argument("--tb", type=int, default=8)
-    ap.add_argument("--cl", type=int, default=128)
+                    choices=[None, "take", "onehot", "pair"])
     ap.add_argument("--platform", default=None, choices=[None, "cpu"],
                     help="force CPU in-process (same as JAX_PLATFORMS=cpu "
                          "in the environment)")
@@ -484,13 +287,6 @@ def main() -> None:
                          "sweep: quick-reject / flood-memo toggles over "
                          "full pipeline.detect (docs/CONFIRM_PLANE.md); "
                          "always CPU")
-    ap.add_argument("--scan", action="store_true",
-                    help="raw-byte device-path A/B (ISSUE 13, "
-                         "docs/SCAN_KERNEL.md 'Device path'): the "
-                         "pallas3 fused program vs the XLA lax.scan "
-                         "lowering at the dominant bucket tiers, plus "
-                         "a Mosaic-interpreter parity run; compiled "
-                         "kernel on TPU, reference lowering on CPU")
     ap.add_argument("--retune", action="store_true",
                     help="profile-guided retuning A/B (docs/RETUNE.md): "
                          "static vs profile-priced pack x verdict cache "
@@ -522,28 +318,18 @@ def main() -> None:
         print(json.dumps(out, indent=2))
         return
 
-    if args.scan:
-        import json
-
-        out = bench_scan_modes(iters=max(3, args.iters))
-        print(json.dumps(out, indent=2))
-        return
-
     cr = compile_ruleset(load_bundled_rules())
     tables = ScanTables.from_bitap(cr.tables)
     from ingress_plus_tpu.utils.platform import device_block
 
     print("%s  W=%d words  rules=%d" % (
         device_block(), tables.n_words, cr.n_rules))
-    for gather in ("take", "onehot", "pallas", "pair"):
+    for gather in ("take", "onehot", "pair"):
         if args.only and gather != args.only:
             continue
         for batch in (args.batch, args.batch * 4):
             try:
-                if gather == "pallas":
-                    mbs = bench_pallas(tables, batch, args.length,
-                                       args.iters, TB=args.tb, CL=args.cl)
-                elif gather == "pair":
+                if gather == "pair":
                     mbs = bench_pairs(tables, batch, args.length,
                                       args.iters)
                 else:

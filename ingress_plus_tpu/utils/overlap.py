@@ -199,8 +199,7 @@ def overlap_report(snapshot: dict,
     # kinds (the dispatch thread preps AND launches; a lane worker's
     # span is the chip), so "is host prep what bounds the pipeline" is
     # answered here: host_prep ranking above device_scan in exclusive
-    # busy is exactly the condition the raw-byte device path
-    # (scan_impl pallas3) exists to remove — check_claims() warns on it
+    # busy is the condition check_claims() warns on
     stage_iv: Dict[str, List[Tuple[int, int]]] = {}
     for code, name in ((EV_PREP, "host_prep"),
                        (EV_DEVICE, "device_scan"),
@@ -351,8 +350,6 @@ def check_claims(report: Optional[dict]) -> List[str]:
         out.append(
             "host_prep ranks ABOVE the device lanes (%.0f%% exclusive "
             "busy vs device_scan's %.0f%%) — host prep bounds the "
-            "pipeline; the raw-byte device path (scan_impl pallas3, "
-            "docs/SCAN_KERNEL.md 'Device path') should be absorbing "
-            "this work" % (hp["exclusive_share"] * 100,
+            "pipeline" % (hp["exclusive_share"] * 100,
                            dv["exclusive_share"] * 100))
     return out

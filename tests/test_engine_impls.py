@@ -1,7 +1,7 @@
-"""Scan-implementation selection: pair/take/pallas must be
-indistinguishable at the rule-hit level, and the auto-select must
-install a working impl (VERDICT round-1: the Pallas kernel must sit in
-the serving path, not beside it)."""
+"""The scan lowerings behind the engine: pair/take/pallas must be
+indistinguishable at the rule-hit level, at every tier the serve path
+dispatches, and "auto" is a rule read off the pack's tables — nothing
+is timed at start-up."""
 
 import pytest
 
@@ -18,16 +18,23 @@ def ruleset():
     return compile_ruleset(load_bundled_rules())
 
 
+@pytest.fixture
+def tiny_rules_dir(tmp_path):
+    (tmp_path / "tiny.conf").write_text(
+        'SecRule ARGS "@rx (?i)union\\s+select" "id:1,phase:2,block,'
+        "severity:CRITICAL,tag:'attack-sqli'\"\n")
+    return str(tmp_path)
+
+
 def _verdict_tuple(v):
     return (v.attack, v.blocked, tuple(sorted(v.rule_ids)), v.score)
 
 
-@pytest.mark.parametrize("impl", ["take", "pallas", "pallas2",
-                                  "pallas3"])
+@pytest.mark.parametrize("impl", ["take", "pallas"])
 def test_impl_verdict_parity_with_pair(ruleset, impl):
     """Every impl produces identical verdicts on a mixed corpus (pallas
-    runs in interpret mode on the CPU test backend — same kernel code
-    path as the TPU lowering)."""
+    runs through the Pallas interpreter on the CPU test backend — same
+    kernel code path as the TPU lowering)."""
     reqs = [lr.request for lr in generate_corpus(n=48, seed=11)]
 
     ref = DetectionPipeline(ruleset, mode="block", scan_impl="pair")
@@ -35,73 +42,94 @@ def test_impl_verdict_parity_with_pair(ruleset, impl):
 
     p = DetectionPipeline(ruleset, mode="block", scan_impl=impl,
                           fail_open=False)
-    p.engine.pallas_interpret = True
     got = [_verdict_tuple(v) for v in p.detect(reqs)]
     assert got == want
 
 
-def test_autoselect_installs_fastest(ruleset):
-    eng = DetectionEngine(ruleset)
-    eng.pallas_interpret = True
-    # CPU backend: pallas excluded by default; both remaining impls run
-    timings = eng.autoselect_scan_impl(B=32, L=64, n=1)
-    assert set(timings) == {"pair", "take"}
-    assert eng.scan_impl == min(timings, key=timings.get)
-    assert all(t > 0 for t in timings.values())
+@pytest.mark.parametrize("with_pair_reach", [True, False],
+                         ids=["with_pair_reach", "without"])
+def test_auto_resolves_from_the_tables(ruleset, with_pair_reach, monkeypatch):
+    """``auto`` is ``pair`` where the pack's scan tables carry
+    ``pair_reach`` and ``take`` where they do not; the engine holds the
+    resolved name, and the lowering it names is what a launch runs."""
+    import numpy as np
+
+    from ingress_plus_tpu.models import engine as E
+    from ingress_plus_tpu.ops.scan import ScanTables
+
+    if not with_pair_reach:
+        from_bitap = ScanTables.from_bitap.__func__
+        monkeypatch.setattr(
+            ScanTables, "from_bitap",
+            classmethod(lambda cls, t: from_bitap(cls, t, classes=False)))
+    eng = DetectionEngine(ruleset, scan_impl="auto")
+    assert (eng.tables.scan.pair_reach is not None) == with_pair_reach
+    want = "pair" if with_pair_reach else "take"
+    assert eng.scan_impl == want
+    assert E.resolve_scan_impl("auto", eng.tables.scan) == want
+    assert eng.device_info()["scan_impl"] == want
+    for named in E.SCAN_IMPLS:
+        assert E.resolve_scan_impl(named, eng.tables.scan) == named
+    with pytest.raises(ValueError, match="pallas3"):
+        DetectionEngine(ruleset, scan_impl="pallas3")
+    # the resolved lowering launches (a pack without pair tables would
+    # fail inside scan_pairs if "pair" had been installed)
+    packed = E.empty_bucket(8, 64)
+    tok, lengths, _req, sv = E.bucket_views(packed)
+    tok[0, :20] = np.frombuffer(b"q=1 union select 2 -", np.uint8)
+    lengths[0], sv[0] = 20, 1
+    assert np.asarray(eng.detect_device_multi((packed,), 4))[0].any()
 
 
-def test_autoselect_candidate_that_raises_propagates(ruleset, monkeypatch):
-    """A kernel the backend refuses stops the bake-off (and with it the
-    server's start-up): it is never scored out of the race so that
-    `pair` serves in silence."""
-    import pytest
+def test_building_the_server_times_nothing(tiny_rules_dir, monkeypatch):
+    """``build_default_batcher(scan_impl="auto")`` resolves the lowering
+    without a measurement: it never enters utils.microbench and launches
+    no scan program."""
+    import sys
 
-    from ingress_plus_tpu.ops import pallas_scan
-
-    class Refused(pallas_scan.PallasScanner):
-        def __call__(self, *a, **kw):
-            raise RuntimeError("Mosaic failed to compile TPU kernel")
-
-    monkeypatch.setattr(pallas_scan, "PallasScanner", Refused)
-    eng = DetectionEngine(ruleset)
-    eng.pallas_interpret = True
-    with pytest.raises(RuntimeError, match="Mosaic failed"):
-        eng.autoselect_scan_impl(B=8, L=64, k=2, n=1, include_pallas=True)
-    assert eng.bakeoff is None
-
-
-def test_server_startup_fails_on_a_refused_kernel(tmp_path, monkeypatch):
-    """--scan-impl auto on a backend that runs the kernels: a candidate
-    raising at compile fails build_default_batcher instead of serving
-    `pair`."""
-    import pytest
-
-    from ingress_plus_tpu.ops import pallas_scan
     from ingress_plus_tpu.serve import server
-    from ingress_plus_tpu.utils import platform
 
-    (tmp_path / "tiny.conf").write_text(
-        'SecRule ARGS "@rx (?i)union\\s+select" "id:1,phase:2,block,'
-        "severity:CRITICAL,tag:'attack-sqli'\"\n")
+    monkeypatch.setitem(sys.modules, "ingress_plus_tpu.utils.microbench",
+                        None)   # an import of it now raises ImportError
+    b = server.build_default_batcher(rules_dir=tiny_rules_dir,
+                                     warmup=False, scan_impl="auto")
+    try:
+        assert b.pipeline.engine.scan_impl == "pair"
+        assert b.pipeline.engine.device_launches == 0
+        assert b.device_path_snapshot()["scan_impl"] == "pair"
+    finally:
+        b.close()
+
+
+def test_server_startup_fails_on_a_refused_kernel(tiny_rules_dir,
+                                                  monkeypatch):
+    """A bucket program the backend refuses to compile in the warm-up
+    stops the start-up: build_default_batcher raises instead of serving
+    a path that will compile (and fail) in front of traffic."""
+    from ingress_plus_tpu.models import engine as E
+    from ingress_plus_tpu.serve import server
 
     def refused(*a, **kw):
         raise RuntimeError("Mosaic failed to compile TPU kernel")
 
-    monkeypatch.setattr(pallas_scan, "_pallas_scan", refused)
-    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    monkeypatch.setattr(E, "scan_fold_bucket", refused)
     with pytest.raises(RuntimeError, match="Mosaic failed"):
-        server.build_default_batcher(rules_dir=str(tmp_path),
-                                     warmup=False, scan_impl="auto")
+        server.build_default_batcher(rules_dir=tiny_rules_dir,
+                                     warmup=True, max_batch=8,
+                                     scan_impl="auto")
 
 
-def test_autoselect_placeable_only_skips_unplaceable_kernels(ruleset):
-    eng = DetectionEngine(ruleset)
-    eng.pallas_interpret = True
-    timings = eng.autoselect_scan_impl(B=8, L=64, k=2, n=1,
-                                       include_pallas=True,
-                                       placeable_only=True)
-    assert set(timings) == {"pair", "take", "pallas3"}
-    assert eng.bakeoff == timings
+def test_server_scan_impl_flag_accepts_auto_alone(capsys):
+    """The server's ``--scan-impl`` stays only because the benchmark's
+    configurations pass ``auto``: a lowering's name is refused at the
+    parser, before anything is built."""
+    from ingress_plus_tpu.serve import server
+
+    for name in ("pallas3", "pair"):
+        with pytest.raises(SystemExit) as exc:
+            server.main(["--scan-impl", name])
+        assert exc.value.code == 2
+        assert "--scan-impl" in capsys.readouterr().err
 
 
 def test_scan_impl_survives_hot_swap(ruleset):
@@ -158,7 +186,6 @@ def test_bucket_programs_equal_map_match_words(ruleset, impl, head_only):
 
     pipe = DetectionPipeline(ruleset, mode="block", scan_impl=impl)
     eng = pipe.engine
-    eng.pallas_interpret = True
     buckets, Q = _three_tier_buckets(pipe, bodies=not head_only)
     before = eng.device_launches
     got = np.asarray(eng.detect_device_multi(buckets, Q,
@@ -232,7 +259,7 @@ def test_bucket_program_name_is_found_by_the_scan_roofline(ruleset):
     eng = DetectionEngine(ruleset)
     acc = eng._accumulators(eng.tables, 4, None)
     bucket = E.scan_fold_bucket.lower(
-        eng.tables, None, jnp.asarray(E.empty_bucket(8, 64)), *acc,
+        eng.tables, jnp.asarray(E.empty_bucket(8, 64)), *acc,
         impl="pair").as_text()
     expand = E.expand_requests_jit.lower(
         eng.tables, *acc, num_requests=4).as_text()
@@ -241,3 +268,48 @@ def test_bucket_program_name_is_found_by_the_scan_roofline(ruleset):
     assert scan_program.search(name)
     assert not scan_program.search(
         re.search(r"module @(\w+)", expand).group(1))
+
+
+@pytest.mark.parametrize("head", [False, True], ids=["full", "head"])
+@pytest.mark.parametrize("L", DetectionPipeline.L_BUCKETS)
+@pytest.mark.parametrize("impl", DetectionEngine.SCAN_IMPLS)
+def test_bucket_program_equals_reference_at_every_tier(ruleset, impl, L,
+                                                       head):
+    """One packed bucket of every L tier the serve path dispatches —
+    ragged rows with an empty row, a full row and an odd length, attacks
+    inside the scanned prefix of some rows and inside the padding of
+    others (ops/parity.ragged_batch) — through the served programs
+    (``scan_fold_bucket`` + ``expand_requests``), on the full and the
+    head-sliced tables: bit for bit the (Q, R) rule hits of the plain
+    reference (``scan_bytes`` + ``map_match_words``)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ingress_plus_tpu.compiler.ruleset import N_HEAD_SV, N_SV
+    from ingress_plus_tpu.models import engine as E
+    from ingress_plus_tpu.ops.parity import ragged_batch
+    from ingress_plus_tpu.ops.scan import scan_bytes_jit
+
+    B, Q = (4 if L >= 2048 else 16), 4   # a CPU scans the long tiers
+    eng = DetectionEngine(ruleset, scan_impl=impl)
+    assert eng.head_slicing_active()
+    tabs = eng.head_tables if head else eng.tables
+    tokens, lengths = ragged_batch(B, L, seed=L)
+    packed = E.empty_bucket(B, L)
+    tok, ln, row_req, row_sv = E.bucket_views(packed)
+    tok[:], ln[:], row_req[:] = tokens, lengths, np.arange(B) % Q
+    row_sv[:, :N_HEAD_SV if head else N_SV] = 1
+
+    fh, sv = E.scan_fold_bucket(tabs, packed,
+                                *eng._accumulators(tabs, Q, None),
+                                impl=impl)
+    got = np.asarray(E.expand_requests_jit(tabs, fh, sv,
+                                           num_requests=Q)[0])
+
+    match = scan_bytes_jit(tabs.scan, tokens, lengths)[0]
+    assert np.asarray(match).any()        # non-vacuous
+    assert not np.asarray(match)[1].any()   # the empty row
+    want = np.asarray(E.map_match_words_jit(
+        tabs, match, jnp.asarray(row_req), jnp.asarray(row_sv), Q)[0])
+    assert got.shape == want.shape == (Q, ruleset.n_rules)
+    assert np.array_equal(got, want)

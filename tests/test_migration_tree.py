@@ -54,7 +54,7 @@ def test_migration_tree_loads_and_serves(tmp_path):
         [sys.executable, "-m", "ingress_plus_tpu.serve",
          "--socket", sock_path, "--http-port", "0",
          "--rules-dir", str(tree / "modsecurity.conf"),
-         "--platform", "cpu", "--scan-impl", "pair",
+         "--platform", "cpu",
          "--max-delay-us", "1000", "--no-warmup"],
         cwd=str(REPO), env=env, stderr=subprocess.PIPE, text=True)
     try:

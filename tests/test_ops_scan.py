@@ -149,3 +149,58 @@ def test_scan_pairs_match_parity(tables):
     m_p2, _ = scan_pairs(st, tokens, lengths, match=jnp.asarray(seed))
     assert (np.asarray(m_b2) == np.asarray(m_p2)).all()
     assert (np.asarray(m_b2) & seed == seed).all()  # sticky
+
+
+def test_scan_pairs_sticky_match_chaining(tables):
+    """Chained calls accumulate the sticky match: a second scan_pairs
+    call seeded with the first call's match over OTHER rows' bytes ends
+    at the OR of both scans, exactly as scan_bytes chains."""
+    import jax.numpy as jnp
+
+    from ingress_plus_tpu.ops.scan import scan_pairs
+
+    st = ScanTables.from_bitap(tables)
+    rows = corpus(random.Random(3), n=9)
+    tokens, lengths = pad_rows(rows, round_to=64)
+    tokens, lengths = np.asarray(tokens), np.asarray(lengths)
+    # second call: the same batch rolled by one row, so every row's
+    # accumulator meets different bytes
+    tokens2, lengths2 = np.roll(tokens, 1, axis=0), np.roll(lengths, 1)
+    w1, _ = scan_bytes(st, tokens, lengths)
+    want, _ = scan_bytes(st, tokens2, lengths2, match=w1)
+    m1, _ = scan_pairs(st, tokens, lengths)
+    m2, _ = scan_pairs(st, tokens2, lengths2, match=m1)
+    m1, m2, want = np.asarray(m1), np.asarray(m2), np.asarray(want)
+    assert (m2 == want).all()
+    assert (m2 & m1 == m1).all()          # sticky
+    assert (m2 != m1).any()               # and the second call added bits
+    # one call over the same bytes twice changes nothing
+    m3, _ = scan_pairs(st, tokens2, lengths2, match=jnp.asarray(m2))
+    assert (np.asarray(m3) == m2).all()
+
+
+def test_scan_pairs_odd_length_remainder(tables):
+    """A row of odd length ends on the FIRST byte of its last pair: the
+    match completing there must be collected, and the pair's second
+    byte (padding, here deliberately live bytes that would complete
+    another match) must stay invisible."""
+    from ingress_plus_tpu.ops.scan import scan_pairs
+
+    st = ScanTables.from_bitap(tables)
+    row = bytearray(b"a" * 64)
+    row[38:49] = b"/etc/passwd"          # ends at byte 48: length 49 is odd
+    row[49:58] = b"/../etc/p"            # beyond the length
+    rows = np.frombuffer(bytes(row), np.uint8)[None, :].repeat(4, axis=0)
+    lengths = np.asarray([49, 48, 47, 1], np.int32)
+    want, _ = scan_bytes(st, rows, lengths)
+    got, _ = scan_pairs(st, rows, lengths)
+    want, got = np.asarray(want), np.asarray(got)
+    assert (got == want).all()
+    assert want[0].any()
+    # the row cut before the match's last byte matches less
+    assert (want[0] != want[2]).any()
+    # nothing after the length leaks in: the same prefix over dead
+    # padding gives the same words
+    clean = rows.copy()
+    clean[:, 49:] = 0
+    assert (np.asarray(scan_pairs(st, clean, lengths)[0]) == got).all()

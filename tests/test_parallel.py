@@ -204,23 +204,27 @@ def test_sp_ring_scan_config5_mixed_1mb_batch(ruleset):
     assert merged[0].any() and merged[1].any()
 
 
-def test_tp_pallas2_shard_parity(ruleset):
-    """Round-4: the per-shard Pallas class-pair kernel must produce the
-    same verdicts as the XLA scans through the full sharded step
-    (interpret mode on the CPU test mesh — same kernel code path as the
-    TPU lowering)."""
+def test_sharded_pair_odd_length_padded():
+    """ShardedEngine(pair) must accept odd-L host batches (one dead-class
+    padding column, the pre-pair contract)."""
+    from ingress_plus_tpu.compiler.ruleset import N_SV
+
+    cr = compile_ruleset(parse_seclang(
+        'SecRule ARGS "@rx (?i)union\\s+select" "id:1,phase:2,block,'
+        "severity:CRITICAL,tag:'attack-sqli'\"\n"))
     mesh = make_mesh(n_data=2, n_model=4)
-    eng = ShardedEngine(ruleset, mesh, scan_impl="take")
-    tokens, lengths, row_req, row_sv = _mk_batch(ruleset)
-    local_req = row_req % 4   # detect() takes SHARD-LOCAL request ids
-    tenants = np.zeros((8,), np.int32)
-    out_take = eng.detect(tokens, lengths, local_req, row_sv, tenants, 8)
-    assert np.asarray(out_take[2]).max() > 0   # parity must be non-vacuous
-    eng.pallas_interpret = True
-    eng.set_scan_impl("pallas2")
-    out_p2 = eng.detect(tokens, lengths, local_req, row_sv, tenants, 8)
-    for a, b in zip(out_take, out_p2):
-        assert (np.asarray(a) == np.asarray(b)).all()
+    eng = ShardedEngine(cr, mesh, scan_impl="pair")
+    row = b"q=1 union  select password from users"
+    tokens, lengths = pad_rows([row], round_to=64)
+    tokens = np.asarray(tokens)[:, :63]          # force odd L
+    lengths = np.minimum(np.asarray(lengths), 63)
+    tokens = np.repeat(tokens, 2, axis=0)        # one row per data shard
+    lengths = np.repeat(lengths, 2)
+    sv = np.ones((2, N_SV), np.int8)
+    rh, ch, sc = eng.detect(tokens, lengths,
+                            np.zeros((2,), np.int32), sv,
+                            np.zeros((2,), np.int32), 2)
+    assert rh[0].any()
 
 
 def test_tp_scan_impl_parity_and_autoselect(ruleset):

@@ -118,3 +118,46 @@ def test_hot_swap_ruleset(ruleset, pipeline):
     p.swap_ruleset(ruleset)
     v = p.detect([ATTACKS[0][1]])[0]
     assert v.attack
+
+
+def test_verdicts_across_tiers_and_hot_swap(ruleset):
+    """The served lowering (``scan_impl="auto"``) through
+    ``DetectionPipeline.detect`` over requests whose rows land in every
+    L tier, a truncated oversized row among them: every verdict equals
+    the one the plain reference lowering (``take``) gives, and — for
+    the rows scanned whole — the confirm-only CPU walk's; before and
+    after a hot swap onto a fresh generation of the pack."""
+    reqs = [lr.request for lr in generate_corpus(n=40, seed=13)]
+    hdr = {"host": "shop.example", "user-agent": "Mozilla/5.0",
+           "accept": "*/*", "content-type": "text/plain"}
+    reqs.append(Request(uri="/big?q=" + "A" * 600 + "+union+select+1",
+                        headers=hdr))
+    reqs.append(Request(uri="/mid?q=" + "D" * 380 + "+or+1%3D1",
+                        headers=hdr))
+    reqs.append(Request(method="POST", uri="/huge", headers=hdr,
+                        body=b"B" * 3000 + b"<script>x</script>"))
+    reqs.append(Request(method="POST", uri="/over", headers=hdr,
+                        body=b"C" * 20000
+                        + b" 1 union select password from users"))
+
+    def vt(v):
+        assert not v.fail_open
+        return (v.attack, v.blocked, tuple(sorted(v.rule_ids)), v.score)
+
+    p = DetectionPipeline(ruleset, mode="block", fail_open=False)
+    ref = DetectionPipeline(ruleset, mode="block", fail_open=False,
+                            scan_impl="take")
+    assert (p.engine.scan_impl, ref.engine.scan_impl) == ("pair", "take")
+    want = [vt(v) for v in ref.detect(reqs)]
+    assert any(w[0] for w in want) and not all(w[0] for w in want)
+    # the oversized row's tail lies past the scanned 16,384 bytes
+    assert [vt(v) for v in p.detect_cpu_only(reqs[:-1])] == want[:-1]
+    assert [vt(v) for v in p.detect(reqs)] == want
+    assert all(p.stats.bucket_rows.get(L, 0) > 0 for L in p.L_BUCKETS), \
+        p.stats.bucket_rows
+    launches = p.engine.device_launches
+    assert launches > 0
+    p.swap_ruleset(ruleset)
+    assert p.engine.scan_impl == "pair"
+    assert [vt(v) for v in p.detect(reqs)] == want
+    assert p.engine.device_launches > launches

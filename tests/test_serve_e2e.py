@@ -135,6 +135,22 @@ def test_health_and_metrics(server):
     assert "ipt_ruleset_info" in metrics
 
 
+def test_healthz_device_path_names_the_resolved_lowering(server):
+    """The server runs its default ``--scan-impl auto``: /healthz
+    ``robustness.device_path.scan_impl`` (the key benchmark/run.py
+    reads) and /rules/stats ``device.scan_impl`` carry the lowering the
+    engine resolved from the pack's tables, never ``auto``."""
+    health = json.loads(urllib.request.urlopen(
+        "http://127.0.0.1:19901/healthz", timeout=10).read())
+    path = health["robustness"]["device_path"]
+    assert path["scan_impl"] == "pair"
+    assert set(path) == {"scan_impl", "backend", "device_kind",
+                         "device_count", "lane_devices"}
+    stats = json.loads(urllib.request.urlopen(
+        "http://127.0.0.1:19901/rules/stats", timeout=10).read())
+    assert stats["device"]["scan_impl"] == "pair"
+
+
 def test_wallarm_status_and_spool(server):
     """Postanalytics read side: counters endpoint + exporter spool
     (the /wallarm-status† + export-attacks† analogs, SURVEY.md §3.4/§3.5).

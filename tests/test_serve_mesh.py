@@ -117,7 +117,7 @@ def test_mesh_serving_over_wire(tmp_path):
         [sys.executable, "-m", "ingress_plus_tpu.serve",
          "--socket", sock_path, "--http-port", "0",
          "--rules-dir", str(rules_dir), "--platform", "cpu",
-         "--mesh", "2x4", "--scan-impl", "pair",
+         "--mesh", "2x4",
          "--max-delay-us", "1000", "--no-warmup"],
         cwd=str(repo), env=env, stderr=subprocess.PIPE, text=True)
     try:

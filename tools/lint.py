@@ -57,13 +57,6 @@ Four gates, one verdict:
              with zero new false negatives vs the fixed CRS weights,
              and flag strictly fewer benign requests at the calibrated
              threshold (reports/MODELGATE.json)
-  devicegate Pallas device-path parity (ISSUE 13, docs/SCAN_KERNEL.md
-             "Device path"): every Pallas kernel runs in Mosaic
-             INTERPRET mode — the same kernel program the TPU lowering
-             compiles — over a seeded corpus of ragged batches and
-             must produce match words BIT-IDENTICAL to the ops/scan.py
-             XLA reference; divergence fails the build before any TPU
-             time is spent (reports/DEVICEGATE.json)
   promlint   Prometheus exposition hygiene (analysis/promlint.py):
              /metrics scraped from an in-process server after real
              multi-tenant traffic — ipt_ prefix, _total on counters,
@@ -505,113 +498,6 @@ def run_modelgate(write_report: bool) -> dict:
     return result
 
 
-#: seeded SecLang fixture for the devicegate (compact on purpose: the
-#: gate's job is KERNEL parity, not CRS coverage — the bundled-pack
-#: geometry case below covers the multi-tile padding paths)
-_DEVICEGATE_RULES = """
-SecRule ARGS "@rx (?i)union\\s+select" "id:1,phase:2,block,severity:CRITICAL,tag:'attack-sqli'"
-SecRule ARGS "@rx (?i)<script[^>]*>" "id:2,phase:2,block,severity:CRITICAL,tag:'attack-xss'"
-SecRule ARGS "@rx /etc/(?:passwd|shadow)" "id:3,phase:2,block,severity:CRITICAL,tag:'attack-lfi'"
-SecRule ARGS "@pm sleep( benchmark( xp_cmdshell load_file(" "id:4,phase:2,block,severity:ERROR,tag:'attack-sqli'"
-SecRule ARGS "@rx (?:;|\\|)\\s*(?:cat|ls|id)\\b" "id:5,phase:2,block,severity:ERROR,tag:'attack-rce'"
-"""
-
-
-def run_devicegate(write_report: bool) -> dict:
-    """Pallas device-path parity gate (ISSUE 13): interpret-mode
-    kernels — the code path the TPU lowering compiles — vs the
-    ops/scan.py XLA reference, bit-identical match words over seeded
-    ragged batches (ops/parity.py, the comparison chip_smoke.py runs
-    compiled on the chip): the three kernels at small tilings on the
-    compact fixture pack, then every member of SCAN_IMPLS through the
-    engine's own dispatch at the bundled pack's real multi-tile
-    geometry.  Writes reports/DEVICEGATE.json; any divergence fails
-    the build."""
-    t0 = time.time()
-    from ingress_plus_tpu.utils.platform import (
-        device_block,
-        force_cpu_devices,
-    )
-
-    force_cpu_devices(1)
-    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
-    from ingress_plus_tpu.compiler.seclang import parse_seclang
-    from ingress_plus_tpu.compiler.sigpack import load_bundled_rules
-    from ingress_plus_tpu.models.engine import DetectionEngine
-    from ingress_plus_tpu.ops.parity import (
-        compare_scan,
-        engine_parity,
-        failed,
-        ragged_batch,
-    )
-    from ingress_plus_tpu.ops.pallas_scan import (
-        PallasByteScanner,
-        PallasPairScanner,
-        PallasScanner,
-    )
-    from ingress_plus_tpu.ops.scan import ScanTables
-
-    tables = ScanTables.from_bitap(
-        compile_ruleset(parse_seclang(_DEVICEGATE_RULES)).tables)
-    kernels = {
-        "pallas": PallasScanner(tables, TB=8, CL=64),
-        "pallas2": PallasPairScanner(tables, TB=8, CL=16, MR=8),
-        "pallas3": PallasByteScanner(tables, TB=8, CL=16, MR=8),
-    }
-    cases = []
-    for seed, (B, L) in enumerate(((13, 320), (13, 192), (5, 64))):
-        tokens, lengths = ragged_batch(B, L, seed)
-        for name, sc in kernels.items():
-            case = compare_scan(
-                lambda t, ln, sc=sc: sc(t, ln, interpret=True),
-                tables, tokens, lengths,
-                # the byte kernel preserves the full scan_bytes state
-                # contract; the pair kernels' dead-padding state is a
-                # documented difference (only match is consumed)
-                check_state=name == "pallas")
-            case.update(pack="fixture", impl=name, seed=seed)
-            cases.append(case)
-    # bundled-pack geometry: the real serving width (multi-tile Wp,
-    # K1p padding) — the shapes and scanners the chip compiles
-    cr = compile_ruleset(load_bundled_rules())
-    pack = "bundled (%d rules, %d words)" % (cr.n_rules, cr.tables.n_words)
-    for impl_cases in engine_parity(DetectionEngine(cr), [(8, 128)],
-                                    interpret=True).values():
-        for case in impl_cases:
-            case.update(pack=pack, seed=7)
-            cases.append(case)
-    bad = failed(cases) + [c for c in cases if not c["non_vacuous"]]
-    dev = device_block()
-    report = {
-        "passed": not bad,
-        **dev,
-        "cases": cases,
-        "divergent": bad,
-        "note": "interpret mode executes the same Mosaic kernel "
-                "program the TPU lowering compiles; chip_smoke.py runs "
-                "this comparison compiled, on the chip",
-    }
-    result = {
-        "status": "OK" if not bad else "FAIL",
-        "seconds": round(time.time() - t0, 2),
-        "cases": len(cases),
-        **dev,
-        "detail": ("; ".join(
-            "%s/%s seed %s DIVERGED" % (c["pack"], c["impl"],
-                                        c["seed"]) for c in bad) or
-            "%d interpret-vs-reference cases bit-identical (incl. "
-            "bundled-pack geometry)" % len(cases))
-        + " [platform=%(platform)s device_kind=%(device_kind)s "
-          "device_count=%(device_count)d]" % dev,
-    }
-    if write_report:
-        out = REPO / "reports" / "DEVICEGATE.json"
-        out.parent.mkdir(parents=True, exist_ok=True)
-        out.write_text(json.dumps(report, indent=2) + "\n")
-        result["report"] = str(out.relative_to(REPO))
-    return result
-
-
 def run_promlint() -> dict:
     """Prometheus exposition hygiene gate (ISSUE 12 satellite,
     analysis/promlint.py): scrape /metrics from an IN-PROCESS serve
@@ -975,7 +861,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only",
                     choices=["ruff", "mypy", "rulecheck", "concheck",
                              "evasiongate", "deadrules", "faultmatrix",
-                             "swapdrill", "modelgate", "devicegate",
+                             "swapdrill", "modelgate",
                              "promlint", "benchtrend", "retunegate",
                              "fleetgate", "fleetdrill"],
                     default=None)
@@ -1000,8 +886,6 @@ def main(argv=None) -> int:
         gates["swapdrill"] = run_swapdrill(write_report=args.ci)
     if args.only in (None, "modelgate"):
         gates["modelgate"] = run_modelgate(write_report=args.ci)
-    if args.only in (None, "devicegate"):
-        gates["devicegate"] = run_devicegate(write_report=args.ci)
     if args.only in (None, "promlint"):
         gates["promlint"] = run_promlint()
     if args.only in (None, "retunegate"):
