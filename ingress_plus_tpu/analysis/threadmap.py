@@ -93,13 +93,16 @@ class ThreadRoot:
 THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
     ThreadRoot(
         name="dispatch",
-        entries=("serve/batcher.py::Batcher._run",
-                 "serve/batcher.py::Batcher._run_mesh"),
+        entries=("serve/batcher.py::Batcher._run",),
         concurrent=False,
-        description="the ipt-batcher dispatch thread: drains admission, "
-                    "launches/collects device cycles, resolves verdict "
-                    "futures (sole owner of stream state and the mesh "
-                    "double buffer)"),
+        description="the ipt-batcher dispatch thread, one pipelined loop "
+                    "for every lane count: drains admission, preps and "
+                    "launches a cycle on the lanes' workers while the "
+                    "one before confirms on the walkers, collects a "
+                    "landed scan into the free confirm stage, resolves "
+                    "verdict futures; waits a batch window at a time "
+                    "(sole owner of stream state, the cycles in flight "
+                    "and the service-time estimate)"),
     ThreadRoot(
         name="lane_worker",
         entries=("serve/lanes.py::LaneWorker._run",

@@ -606,11 +606,14 @@ class ConfirmJob:
 
     __slots__ = ("requests", "rule_hits", "results", "pending", "memo",
                  "launch_us", "share_workers", "memo_hits", "memo_misses",
-                 "cycle")
+                 "cycle", "t0")
 
     def __init__(self, requests, rule_hits) -> None:
         self.requests = requests
         self.rule_hits = rule_hits
+        #: when the batch was launched (perf_counter): the shares' one
+        #: hang budget runs from here, whenever the join comes
+        self.t0 = time.perf_counter()
         self.results: List[Optional[ConfirmResult]] = [None] * len(requests)
         #: [(worker, request indices, when its share was sent (ns),
         #:   LanePending or None where the walker was gone already)]
@@ -833,7 +836,7 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
     thread (the classic serial path).  Pooled: each share is put on its
     worker's pipe from this thread, its walker process walks it, its
     waiter thread blocks on the answer, and the call returns
-    immediately — the batcher's mesh loop overlaps the in-flight
+    immediately — the batcher's loop overlaps the in-flight
     confirm with the next cycle's scan dispatch, the same software-
     pipelining move PR 7 made for host→device transfer."""
     job = ConfirmJob(requests, rule_hits)
@@ -919,8 +922,10 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
 
 def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
     """Bounded-join the confirm shares.  ONE shared deadline for the
-    whole batch (the shares launched together — k wedged workers cost
-    one hang budget, not k; the lane-collection lesson of PR 7).  A
+    whole batch, counted from its launch (the shares launched together
+    — k wedged workers cost one hang budget, not k, the lane-collection
+    lesson of PR 7; and a join that comes late, the confirm having been
+    held open across the next scan, adds no budget of its own).  A
     share past the deadline: its worker is abandoned (the walker
     process killed) and replaced, its requests' results stay None (the
     fold fails exactly those open), ``stats.confirm_hangs`` counts it.
@@ -931,7 +936,7 @@ def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
     if not job.pending:
         return job.results
     pool = pl.confirm_pool
-    deadline = time.perf_counter() + pool.hang_budget_s
+    deadline = job.t0 + pool.hang_budget_s
     err: Optional[BaseException] = None
     confirms = pl.confirms
     for worker, idxs, t_out, pending in job.pending:

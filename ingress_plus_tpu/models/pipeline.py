@@ -233,6 +233,15 @@ class _FinishJob:
     cjob: object = None
     t0: float = 0.0
 
+    def shares(self) -> list:
+        """The confirm shares in flight on pool workers (their
+        ``LanePending`` handles); empty where there is no confirm stage
+        to hold open: immediate verdicts, an inline walk."""
+        if self.cjob is None:
+            return []
+        return [p for _w, _idxs, _t, p in self.cjob.pending
+                if p is not None]
+
 
 def warm_sizes(max_batch: int) -> List[int]:
     """The ONE Q-pad warmup tier ladder — 1, then the pow2 tiers up to
@@ -866,17 +875,20 @@ class DetectionPipeline:
         Q_pad = self._pad_q(len(requests))
         engine = self.engine
         multi = getattr(engine, "detect_device_multi", None)
-        lane_key = lane.index if lane is not None else None
+        device = lane.device if lane is not None else None
+        # executables are bound to a device, not to a lane: a lane that
+        # pins none (the one lane of a one-chip server) runs the default
+        # device's, the ones ``warm_shape`` compiled
+        lane_key = lane.index if device is not None else None
         st.engine_us += bucket_us   # pad/pack rides the scan stage
         st.engine_compiles += self._count_new_executables(
             bucket_shapes, Q_pad, head_ok, fused=multi is not None,
             lane_key=lane_key)
-        if lane is not None:
+        if lane_key is not None:
             self.seen_lane_shapes.add(
-                (lane.index, bucket_shapes, Q_pad, head_ok))
+                (lane_key, bucket_shapes, Q_pad, head_ok))
         else:
             self.seen_shapes.add((bucket_shapes, Q_pad, head_ok))
-        device = lane.device if lane is not None else None
         # flight recorder: the cycle id travels with the closure onto
         # the lane worker (read HERE on the dispatch thread)
         trace_cycle = flight.cycle()
@@ -969,8 +981,8 @@ class DetectionPipeline:
     def detect_collect_join(self, fin) -> List[Verdict]:
         """Second half of :meth:`detect_collect`: bounded-join the
         confirm shares and fold verdicts.  With ``--confirm-workers``
-        > 1 the batcher's mesh loop calls this one drain later than
-        the launch, so cycle N's confirm overlaps cycle N+1's scan
+        > 1 the batcher's loop calls this once the shares have
+        answered, so cycle N's confirm overlaps cycle N+1's scan
         dispatch (docs/CONFIRM_PLANE.md)."""
         if fin.verdicts is not None:
             return fin.verdicts

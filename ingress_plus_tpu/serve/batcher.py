@@ -6,9 +6,15 @@ the queue into a batch when either (a) max_batch requests are waiting or
 (b) the oldest request has waited max_delay.  Batches go through the
 DetectionPipeline (TPU scan + CPU confirm) and verdict futures resolve.
 
-Double-buffered dispatch (the PP stage pipeline): while batch N executes
-on device, batch N+1 accumulates — the queue IS the buffer; the dispatch
-thread never sleeps while work is pending.
+One dispatch loop, software-pipelined (``Batcher._run``): a dispatch
+never waits for the stage before it.  A cycle is launched on the lanes'
+workers (prep, pack and hand-over on this thread), scans there, is
+collected into a free confirm stage and walks in the walker processes,
+and one cycle may scan while the one before it confirms; while a scan
+is in flight batch N+1 accumulates — the queue IS the buffer.  The
+dispatch thread waits a batch window at a time and handles whatever has
+ripened, verdicts first; an idle tail resolves as soon as its walkers
+answer.
 
 Fail-open (wallarm-fallback): pipeline errors or a dispatch deadline
 overrun produce pass-and-flag verdicts, never dropped requests.
@@ -22,18 +28,15 @@ fallback, half-open = single canary batches); and a monitor thread
 backstops the dispatch thread itself.  Every path keeps the one
 invariant: an admitted request resolves to exactly one verdict.
 
-Mesh serving (docs/MESH_SERVING.md): with ``n_lanes > 1`` the SAME
-admission queue feeds N per-device lanes (serve/lanes.py) — each
-drained cycle is sharded across the healthy lanes (scan rows travel
-with their requests, balanced by scanned bytes), every lane has its own
-watchdog budget and circuit breaker, and host→device transfer is
-double-buffered: the dispatch loop launches cycle N on the lanes
-asynchronously and preps/pads/packs cycle N+1 while the devices crunch,
-finalizing N only when N+1's launch is in flight.  A hung or erroring
-chip degrades CAPACITY (its share fails open once, its breaker trips,
-the splitter routes around it, the half-open canary brings it back),
-never the service; the CPU confirm-only fallback engages only when
-every lane is down.
+Lanes (docs/MESH_SERVING.md): the admission queue feeds ``n_lanes``
+per-device lanes (serve/lanes.py), one by default — each drained cycle
+is sharded across the healthy lanes (scan rows travel with their
+requests, balanced by scanned bytes), and every lane has its own
+watchdog budget and circuit breaker.  A hung or erroring chip degrades
+CAPACITY (its share fails open once, its breaker trips, the splitter
+routes around it, the half-open canary brings it back), never the
+service; the CPU confirm-only fallback engages only when every lane is
+down — with one lane, when its breaker is open.
 
 Tenant isolation (docs/ROBUSTNESS.md "Tenant isolation"): admission is
 TENANT-FAIR — the queue is per-tenant sub-queues drained by deficit
@@ -91,8 +94,8 @@ from ingress_plus_tpu.utils.trace import (
     STAGES,
     SUBSTAGES,
     BatchTrace,
-    Ewma,
     Histogram,
+    RecentMedian,
     SlowRing,
     TraceRing,
     flight,
@@ -305,9 +308,11 @@ class _TenantFairQueue:
             return self._pop_locked()
 
 
-class _MeshCycle:
-    """One in-flight mesh dispatch cycle: launched on the lanes,
-    finalized one drain later (the double buffer)."""
+class _Cycle:
+    """One dispatch cycle in flight: launched on the lanes (its scan
+    stage), collected once every share's scan has landed (which starts
+    its confirm stage), resolved once every confirm share has
+    answered."""
 
     __slots__ = (
         "cid", "t0", "guard", "route", "pipeline", "ro", "cand_items",
@@ -316,33 +321,64 @@ class _MeshCycle:
         "max_queue_delay_us", "engine_us0", "confirm_us0", "prep_us0",
         "compiles0", "launch_d_engine", "launch_d_prep",
         "launch_d_compiles", "overlap_drain_s",
-        # confirm-overlap phase state (docs/CONFIRM_PLANE.md): shares
-        # whose scan collected and confirm launched, the verdicts
-        # already resolved during collection, and the collection
-        # window's stage deltas (folded into the trace at resolve)
+        # when the lanes' one hang budget for this cycle's scans runs
+        # out (perf_counter; counted from the hand-over)
+        "scan_deadline",
+        # confirm-stage state (docs/CONFIRM_PLANE.md): shares whose
+        # scan collected and confirm launched, the verdicts already
+        # resolved during collection, the collection window's stage
+        # deltas (folded into the trace at resolve), when the confirm
+        # shares' budget runs out, and whether a later cycle was
+        # launched while this confirm stage was open
         "pending_fins", "done", "cand_verdicts",
         "collect_d_engine", "collect_d_confirm", "collect_d_prep",
-        "collect_d_compiles",
+        "collect_d_compiles", "confirm_deadline", "held_open",
         # the cycle's flight-recorder envelope span and the process's
         # GC pause total when it began
         "span", "gc_us0",
         # µs of the dispatch thread's own work for this cycle so far
-        # (launch + collect + resolve, without the waits for the lanes'
-        # results and without the drains between the phases)
+        # (launch + collect + resolve, without the loop's waits)
         "own_us",
     )
 
     def __init__(self):
         self.overlap_drain_s = 0.0
         self.cid = 0   # flight-recorder cycle id (stats.batches stamp)
+        self.held_open = False
+
+    @staticmethod
+    def _all_done(waits, timeout: float) -> bool:
+        """Up to ``timeout`` for every one of ``waits`` (bounded waits
+        that say whether their call has ended) to say yes."""
+        end = time.perf_counter() + timeout
+        return all(w(max(end - time.perf_counter(), 0.0)) for w in waits)
+
+    def wait_scan(self, timeout: float) -> bool:
+        """Up to ``timeout`` for every lane share's scan result to be
+        on the host: True once collecting will not block."""
+        return self._all_done(
+            [job.pending.wait_done for _ln, _rt, _part, job
+             in self.lane_parts if job.pending is not None], timeout)
+
+    def confirm_shares(self) -> List:
+        """This cycle's confirm shares out on pool workers: empty where
+        there is no confirm stage to hold open."""
+        return [p for _ln, _part, fin in self.pending_fins
+                for p in fin.shares()]
+
+    def wait_confirm(self, timeout: float) -> bool:
+        """Up to ``timeout`` for every confirm share to have answered:
+        True once resolving will not block."""
+        return self._all_done([p.wait_done for p in self.confirm_shares()],
+                              timeout)
 
 
 class _CycleGuard:
     """One armed dispatch cycle the watchdog monitor backstops: the
     futures to release fail-open if the cycle blows past its grace.
-    With the double-buffered mesh loop up to two cycles are armed at
-    once (the launched-but-not-finalized one plus the one being
-    launched), so guards live in a list instead of a single slot."""
+    The pipelined loop keeps up to three armed at once (a held-open
+    confirm, a scan in flight, the one being launched), so guards live
+    in a list instead of a single slot."""
 
     __slots__ = ("deadline", "items", "fired")
 
@@ -375,6 +411,12 @@ class BatcherStats:
     hangs: int = 0                 # device-lane hang-budget overruns
     cpu_fallback_batches: int = 0  # batches served breaker-open (CPU)
     watchdog_released: int = 0     # futures force-released by the monitor
+    # cycles resolved (ipt_cycles_total{confirm=}): "held" where a
+    # later cycle was launched while this one's confirm stage was open
+    # (its walk overlapped that scan), else "direct" (an idle tail, an
+    # inline confirm)
+    cycles_held: int = 0
+    cycles_direct: int = 0
     #: admission-side counters (submitted / stream ingress) are bumped
     #: by ARBITRARY caller threads (Batcher.submit is a declared
     #: thread-safe API), so those bumps serialize on this lock — the
@@ -415,6 +457,9 @@ class Batcher:
     # buffered body the same way†)
     OVERSIZE_THRESHOLD = DetectionPipeline.L_BUCKETS[-1]
     OVERSIZE_CHUNK = 64 << 10
+    #: the loop's waits while a cycle is in flight last one batch
+    #: window, and no less than this (a window of zero must not spin)
+    MIN_SLICE_S = 0.0002
 
     def __init__(
         self,
@@ -454,10 +499,12 @@ class Batcher:
         # drain_idle per drain, reply per request): the same metric
         # family, their own dict — a sum over STAGES must not meet them
         self.subhist: dict = {s: Histogram() for s in SUBSTAGES}
-        # what lanes add (mesh loop only; ipt_lane_stage_us,
+        # what more than one lane adds to /metrics (ipt_lane_stage_us,
         # ipt_lane_cycle_us): {(lane, sub-stage): [µs, cycles]} and
-        # {"scan_wall" | "dispatch_own": [µs, cycles]}.  The dispatch
-        # thread is the one writer; a scrape reads a copy
+        # {"scan_wall" | "dispatch_own": [µs, cycles]}; one lane's
+        # cycle IS its lane's, and prints no second copy of it.  The
+        # dispatch thread is the one writer; a scrape reads a copy
+        self._lane_series = n_lanes > 1
         self.lane_stage_us: Dict[tuple, List[int]] = {}
         self.lane_cycle_us: Dict[str, List[int]] = {}
         # µs the dispatch thread has waited for work since the last
@@ -466,10 +513,10 @@ class Batcher:
         self.batch_size_hist = Histogram(bounds=BATCH_SIZE_BUCKETS)
         self.slow = SlowRing(capacity=32)
         # fail-safe plane (docs/ROBUSTNESS.md): BOUNDED admission queue,
-        # per-cycle service-time EWMA (the queue math deadline shedding
-        # divides by), brownout ladder thresholds derived from the serve
-        # deadline, watchdogged device lane + circuit breaker, and a
-        # monitor thread backstopping the dispatch thread itself
+        # per-cycle service-time estimate (the queue math deadline
+        # shedding divides by), brownout ladder thresholds derived from
+        # the serve deadline, watchdogged device lane + circuit breaker,
+        # and a monitor thread backstopping the dispatch thread itself
         # tenant-fair admission (docs/ROBUSTNESS.md "Tenant isolation"):
         # per-tenant DRR sub-queues + the flood guard.  tenant_queue_cap
         # 0 = the global cap (single-tenant behavior unchanged);
@@ -488,18 +535,17 @@ class Batcher:
                 TenantGuardConfig(policy=str(tenant_guard)))
         if self.tenant_guard is not None:
             self.tenant_guard.configure_depth(self._q.tenant_cap)
-        self._batch_ewma = Ewma(alpha=0.2)
-        self._batch_ewma_n = 0   # samples seen; shedding needs a floor
-        # when the mesh loop last resolved a cycle (perf_counter): its
+        self._service = RecentMedian(9)
+        # when the loop last resolved a cycle (perf_counter): its
         # service-time samples run from there (dispatch thread only)
         self._last_resolve = 0.0
         self._drain_since_resolve = 0.0   # interleaved drains since then
         self.pipeline.load_controller.configure_deadline(hard_deadline_s)
         # per-device lane plane (serve/lanes.py, docs/MESH_SERVING.md):
-        # n_lanes == 1 is the classic single-lane fail-safe plane of
-        # PR 4 (the pool's primary breaker IS self.breaker); n_lanes > 1
-        # shards each cycle across per-chip lanes behind this one
-        # admission queue.  lane_devices defaults to the local jax
+        # one lane rides the default device (the pool's primary breaker
+        # IS self.breaker); more shard each cycle across per-chip lanes
+        # behind this one admission queue, through the same loop.
+        # lane_devices defaults to the local jax
         # devices when the pool is actually multi-lane; too few of them
         # is an error (LanePool), never a silent pile-up on one chip.
         if n_lanes > 1 and lane_devices is None:
@@ -510,8 +556,8 @@ class Batcher:
                               failure_threshold=breaker_failures,
                               cooldown_s=breaker_cooldown_s)
         # armed dispatch cycles — the monitor releases a cycle's futures
-        # fail-open when it blows past its grace (the double-buffered
-        # mesh loop keeps up to two armed at once)
+        # fail-open when it blows past its grace (the pipelined loop
+        # keeps up to three armed at once)
         self._active_guards: List[_CycleGuard] = []
         # a pooled confirm phase adds its own bounded wait to a cycle's
         # worst-case life (join_confirm's shared deadline) — the
@@ -601,21 +647,23 @@ class Batcher:
 
     def _est_wait_s(self, depth: int) -> float:
         """Queue math for admission-time deadline shedding: batches
-        ahead of a new arrival x the EWMA cycle time, plus one cycle
-        for the dispatch already in flight.  Zero until the estimator
-        has a sample floor — never shed on a cold (or nearly cold,
-        first-cycle-seeded) estimator."""
-        if self._batch_ewma_n < 8:
+        ahead of a new arrival x the cycle time, plus one cycle for the
+        dispatch already in flight.  The cycle time is the median of
+        the last nine service samples (``RecentMedian``): one stalled
+        cycle, of any length, sheds nothing — a request that sat
+        through it gets its real verdict, late — and five slow cycles
+        in a row do.  Zero until the estimator has a sample floor —
+        never shed on a cold (or nearly cold, first-cycle-seeded)
+        estimator."""
+        if self._service.n < 8:
             return 0.0
-        per_batch = self._batch_ewma.get(0.0)
+        per_batch = self._service.get(0.0)
         if per_batch <= 0.0:
             return 0.0
         batches_ahead = (depth + self.max_batch - 1) // self.max_batch
-        # the mesh loop with a pooled confirm holds a cycle's confirm
-        # open across the next cycle's launch: one more cycle between
-        # an arrival and its verdict
-        held = (1 if self.lanes.n > 1
-                and self.pipeline.confirm_pool.n_workers > 1 else 0)
+        # a pooled confirm is held open across the next cycle's launch:
+        # one more cycle between an arrival and its verdict
+        held = 1 if self.pipeline.confirm_pool.n_workers > 1 else 0
         return (batches_ahead + 1 + held) * per_batch
 
     def _shed(self, request: Request, fut: "Future[Verdict]",
@@ -1109,9 +1157,9 @@ class Batcher:
     def _drain(self, first_timeout: float = 0.05) -> List:
         """Block up to ``first_timeout`` for the first item, then
         collect until max_batch or the first item's deadline.  The
-        double-buffered mesh loop drains with a tight first timeout
-        while a launched cycle is still in flight — finalizing the
-        previous cycle must not wait out a full idle tick."""
+        loop drains with a tight first timeout while a launched cycle
+        is still in flight — finalizing it must not wait out a full
+        idle tick."""
         try:
             first = self._q.get(timeout=first_timeout)
         except queue.Empty:
@@ -1154,7 +1202,7 @@ class Batcher:
         if flight.enabled:
             sub["drain_idle"] = self._drain_idle_us
         self._drain_idle_us = 0
-        if self.lanes.n > 1:
+        if self._lane_series:
             # the same spans by the lane they ran for, one observation
             # a cycle each (ipt_lane_stage_us{device=,stage=})
             for key, us in lanes.items():
@@ -1215,79 +1263,19 @@ class Batcher:
                 out.append((h, v))
             return out
 
-    def _detect_guarded(self, requests: List[Request],
-                        route: str) -> List[Verdict]:
-        """One batch through the breaker-routed device path.
-
-        "device"/"canary" → the watchdogged lane runs detect_strict
-        with the hang budget; a hang fails the batch open, trips the
-        breaker and abandons the lane; an error fails the batch open
-        and counts toward the breaker.  "fallback" (breaker open) →
-        the CPU confirm-only path, no device touched."""
-        p = self.pipeline
-        lane = self.lanes.primary
-        if route == "fallback":
-            self.stats.cpu_fallback_batches += 1
-            return p.detect_cpu_only(requests)
-        try:
-            # per-device telemetry on the single-lane path too (the
-            # device="0" series must describe real traffic, and the
-            # 1-lane mesh-scale baseline reads busy_us for utilization
-            # — reviewer catch: these stayed zero); row deltas are safe
-            # to sample here — the caller holds the swap lock
-            rows0 = p.stats.live_rows
-            padded0 = p.stats.padded_rows
-            tb0 = time.perf_counter()
-            cid = flight.cycle()
-            verdicts = lane.call(
-                lambda: flight.scoped(cid, p.detect_strict, requests),
-                self.hang_budget_s)
-            lane.breaker.record_success()
-            st = lane.stats
-            st.requests += len(requests)
-            st.busy_us += int((time.perf_counter() - tb0) * 1e6)
-            # max(…, 0): a concurrent reset_detection_observations can
-            # zero the live counters mid-call — clamp, never go negative
-            st.rows += max(p.stats.live_rows - rows0, 0)
-            st.padded_rows += max(p.stats.padded_rows - padded0, 0)
-            return verdicts
-        except DeviceHang:
-            # the stuck batch fails open NOW (the client-side budget is
-            # long blown); the zombie lane worker is abandoned
-            # (lane.call) and the breaker opens so the next batches go
-            # to the CPU fallback
-            self.stats.hangs += 1
-            lane.stats.hangs += 1
-            lane.breaker.trip("hang")
-        except Exception:
-            # batcher-level fail-open regardless of the pipeline's own
-            # fail_open flag (the serve plane's contract) — but the
-            # breaker gets to COUNT the failure first, which is why this
-            # path calls detect_strict rather than detect
-            lane.stats.errors += 1
-            lane.breaker.record_failure()
-        p.stats.count_fail_open(len(requests))
-        return [_fail_open_verdict(r.request_id) for r in requests]
-
-    def _detect_candidate(self, requests: List[Request], ro,
-                          route: str,
-                          lane: Optional[Lane] = None) -> List[Verdict]:
+    def _detect_candidate(self, requests: List[Request], ro, cand,
+                          route: str, lane: Lane) -> List[Verdict]:
         """Candidate-generation dispatch for the canary ramp
-        (control/rollout.py).  Rides a watchdogged lane (the primary,
-        or the mesh cycle's serving lane) and follows the cycle's
-        breaker route (breaker open → the candidate scans CPU-only too:
-        a suspect device must not be probed by the canary either) — but
-        failures are attributed to the CANDIDATE: they count toward the
-        rollout's rollback triggers and NEVER toward the shared
-        breaker, so a bad candidate pack cannot push the incumbent path
-        onto its CPU fallback."""
-        cand = ro.candidate
-        if lane is None:
-            lane = self.lanes.primary
-        if cand is None:
-            # rolled back between split and dispatch: serve these
-            # through the incumbent — the generation they now belong to
-            return self._detect_guarded(requests, route)
+        (control/rollout.py), for a share whose candidate ``cand``
+        still stood when the cycle collected (a rolled-back share rides
+        the incumbent instead, :meth:`_collect_cycle`).  Rides a
+        watchdogged lane (the cycle's serving lane) and follows the
+        cycle's breaker route (breaker open → the candidate scans
+        CPU-only too: a suspect device must not be probed by the canary
+        either) — but failures are attributed to the CANDIDATE: they
+        count toward the rollout's rollback triggers and NEVER toward
+        the shared breaker, so a bad candidate pack cannot push the
+        incumbent path onto its CPU fallback."""
         if route == "fallback":
             return cand.detect_cpu_only(requests)
         try:
@@ -1310,8 +1298,7 @@ class Batcher:
         return g
 
     def _classify_batch(self, batch: List, t0: float):
-        """Shared cycle prologue (single-lane loop AND mesh launch —
-        one copy, not two drifting ones): split the drained items by
+        """A cycle's prologue: split the drained items by
         kind, book the admission counters, arm the watchdog guard.
         Returns (reqs, deg_reqs, begins, chunks, finishes, guard) —
         ``deg_reqs`` are quarantined tenants' requests ("req_deg"),
@@ -1442,235 +1429,82 @@ class Batcher:
             pass
 
     def _run(self) -> None:
+        """The dispatch loop, one for every lane count
+        (docs/MESH_SERVING.md "The loop"): a dispatch never waits for
+        the stage before it.  A cycle goes through three stages —
+        launch (prep, pack and the hand-over to the lanes, on this
+        thread), scan (the lane workers and the chips), confirm (the
+        pool's walkers, docs/CONFIRM_PLANE.md) — and one cycle may be
+        in its scan stage while the one before is in its confirm stage:
+        a walk overlaps the next scan.  While a scan is in flight
+        arrivals stay in the admission queue (it IS the buffer, and
+        its queue math sees them): a lane is a serial resource with a
+        fixed cost per dispatch, and the batch grows while it is busy.
+
+        This thread never sleeps on a stage while another has work it
+        could start, and every wait it makes while a cycle is in flight
+        is bounded by one batch window: for a batch (the drain) where
+        the scan stage is free, else for the stage in the way, a slice
+        at a time.  Whatever has ripened is handled on waking, verdicts
+        first: a confirm whose shares have all answered resolves, a
+        scan whose shares have all landed is collected into the free
+        confirm stage (inline confirm, or nothing out on the walkers:
+        resolved there and then), and a stage past its hang budget goes
+        to the same calls, which fail the wedged share open.  An idle
+        tail so resolves as soon as its walkers answer; nothing can be
+        stranded on a lost wake-up, because nothing waits for one."""
         flight.register_thread("dispatch")
-        if self.lanes.n > 1:
-            self._run_mesh()
-            return
+        scanning: Optional[_Cycle] = None    # scan in flight
+        confirming: Optional[_Cycle] = None  # confirm in flight
         while not self._stop.is_set():
-            flight.set_cycle(0)
-            batch = self._drain_idle()
-            if not batch:
-                # idle drain: feed the brownout ladder a zero so the
-                # queue-delay EWMA decays and the ladder can step back
-                # down once pressure is gone
-                self.pipeline.load_controller.observe(0.0)
-                continue
-            t0 = time.perf_counter()
-            gc_us0 = gc_watch.pause_us()
-            # prologue + arm the monitor: if THIS cycle wedges past
-            # every budget, the watchdog releases its futures fail-open
-            reqs, deg_reqs, begins, chunks, finishes, guard = \
-                self._classify_batch(batch, t0)
-            # flight recorder: the cycle envelope — every span below
-            # stitches to this id (stats.batches, the cycle counter)
-            cid = self.stats.batches
-            flight.set_cycle(cid)
-            cycle_span = flight.span(
-                EV_CYCLE, cycle=cid, arg=len(reqs) + len(deg_reqs)).begin()
-            # one breaker decision per cycle: requests AND stream scan
-            # work follow it (a wedged device must not be probed twice)
-            route = self.breaker.route()
-            done: List = []   # (submit_ts, request, verdict) this cycle
-            with self._swap_lock:
-                # stage-delta capture INSIDE the lock: the oversized
-                # side worker also mutates pipeline stats (under this
-                # lock, per step) — sampling outside would attribute its
-                # work to this batch's stage histograms
-                ps = self.pipeline.stats
-                engine_us0, confirm_us0 = ps.engine_us, ps.confirm_us
-                prep_us0 = ps.prep_us
-                compiles0 = ps.engine_compiles
-                finish_verdicts = self._stream_step_guarded(
-                    begins, chunks, finishes, route)
-                # quarantined tenants' share: prefilter-only, before
-                # the canary split (the candidate generation must never
-                # serve tenant-degraded traffic — its rollback triggers
-                # key on verdict quality)
-                self._detect_tenant_degraded(deg_reqs, done, route)
-                # partition: oversized bodies go through the stream
-                # engine inline; everything else batches as usual
-                normal = []
-                for item in reqs:
-                    ts, r, fut = item
-                    try:
-                        plan = self._reroute_plan(r)
-                    except Exception:
-                        plan = None   # fall back to the batched path
-                    if plan is not None:
-                        self._submit_oversized(ts, r, plan, fut)
-                    else:
-                        normal.append(item)
-                # canary generation split (control/rollout.py): during a
-                # ramp, the deterministic request-id hash sends this
-                # cycle's share of requests through the CANDIDATE
-                # pipeline instead — each request is served by exactly
-                # one generation; idle rollout = one attribute read
-                ro = self.rollout
-                cand_items: List = []
-                if ro is not None and ro.canary_active:
-                    normal, cand_items = ro.split(normal)
-                requests = [r for _, r, _ in normal]
-                if requests:
-                    try:
-                        verdicts = self._detect_guarded(requests, route)
-                    except Exception:
-                        verdicts = [_fail_open_verdict(r.request_id)
-                                    for r in requests]
-                    for (ts, r, fut), v in zip(normal, verdicts):
-                        _safe_set(fut, v)
-                        done.append((ts, r, v, 0))
-                cand_verdicts: List[Verdict] = []
-                if cand_items:
-                    creqs = [r for _, r, _ in cand_items]
-                    try:
-                        cand_verdicts = self._detect_candidate(
-                            creqs, ro, route)
-                    except Exception:
-                        cand_verdicts = [_fail_open_verdict(r.request_id)
-                                         for r in creqs]
-                    for (ts, r, fut), v in zip(cand_items, cand_verdicts):
-                        _safe_set(fut, v)
-                        done.append((ts, r, v, 0))
-                # end-delta sample, still under the lock (stats object
-                # survives hot-swaps; the side lane can't interleave)
-                ps = self.pipeline.stats
-                d_engine = ps.engine_us - engine_us0
-                d_confirm = ps.confirm_us - confirm_us0
-                d_prep = ps.prep_us - prep_us0
-                d_compiles = ps.engine_compiles - compiles0
-            # rollout hooks OFF the swap lock: shadow mirroring (never
-            # on the verdict path — the futures above already resolved),
-            # canary accounting, and the deferred-promotion pump (tick
-            # needs the swap lock the dispatch thread just released)
-            if ro is not None:
-                if ro.shadow_active:
-                    flight.begin(EV_MIRROR, cycle=cid, arg=len(done))
-                    for _ts, r, v, _lane in done:
-                        ro.mirror(r, v)
-                    flight.end(EV_MIRROR, cycle=cid)
-                if cand_items:
-                    ro.observe_canary(len(cand_items), cand_verdicts)
-                ro.tick()
-            self._clear_guard(guard)
-            cycle_span.end()
-            t_end = time.perf_counter()
-            took = t_end - t0
-            # fail-safe plane signals: cycle-time EWMA feeds the
-            # admission queue math; the oldest request's queue delay
-            # feeds the brownout ladder.  Cycles that paid a serve-time
-            # XLA compile are EXCLUDED from both — a cold-start compile
-            # is warmup, not load, and folding its seconds-long stall
-            # into the service-rate estimate made admission shed (and
-            # the ladder brown out) every request behind a first
-            # dispatch (the --no-warmup e2e showed exactly this)
-            if d_compiles == 0:
-                # clamp the service-time sample too: a cycle that blew
-                # past 2x the deadline is a stall (stream-shape compile,
-                # CPU pause), not the steady-state service rate — a
-                # genuinely slow plane still converges well above the
-                # shed horizon
-                self._batch_ewma.update(
-                    min(took, 2.0 * self.hard_deadline_s))
-                self._batch_ewma_n += 1
-                self.pipeline.load_controller.observe(
-                    self._ladder_signal(batch, t0))
-            self.stats.batch_us_sum += int(took * 1e6)
-            n_served = len(reqs) + len(deg_reqs) + len(finishes)
-            if took > self.hard_deadline_s:
-                self.stats.deadline_overruns += n_served
-            self.stats.completed += n_served
-            batch_us = int(took * 1e6)
-            trace = BatchTrace(
-                ts=time.time(),
-                n_requests=len(reqs) + len(deg_reqs),
-                n_stream_items=len(begins) + len(chunks) + len(finishes),
-                queue_delay_us=int((t0 - min(ts for _, ts, _, _ in batch))
-                                   * 1e6),
-                batch_us=batch_us,
-                engine_us=d_engine,
-                confirm_us=d_confirm,
-                prep_us=d_prep,
-                sub_us=self._sub_spans(cid),
-                gc_us=gc_watch.pause_us() - gc_us0,
-                # only requests this batch actually scanned (`normal` +
-                # the tenant-degraded prefilter-only share + stream
-                # finishes): an oversized-rerouted id here would make
-                # /traces/request attribute the side lane's work to
-                # this batch's spans — those ids resolve via their
-                # /debug/slow exemplar instead
-                request_ids=[r.request_id for _, r, _ in normal]
-                + [r.request_id for _, r, _ in cand_items]
-                + [r.request_id for _, r, _ in deg_reqs]
-                + [h.request.request_id for h, _ in finish_verdicts])
-            self.traces.record(trace)
-            self._observe(trace, done, finish_verdicts, t0, t_end)
-
-    # ------------------------------------------------- mesh (N lanes)
-
-    def _run_mesh(self) -> None:
-        """Double-buffered per-lane dispatch loop (docs/MESH_SERVING.md)
-        — the mesh-mode twin of ``_run``.  Software-pipelined with
-        depth 1: cycle N's device dispatch is launched asynchronously
-        on the lane workers, then THIS thread drains and preps cycle
-        N+1 (normalize/pad/pack — the host-CPU cost) while the chips
-        crunch, and only then finalizes N (bounded per-lane waits,
-        confirm, verdict futures).  Under load the host prep and the
-        device scan fully overlap; idle, the pending cycle finalizes
-        after at most one batch window.
-
-        With ``--confirm-workers`` > 1 the pipeline deepens one more
-        stage (docs/CONFIRM_PLANE.md): collecting cycle N launches its
-        confirm on the pool workers and the verdicts resolve one drain
-        later — so cycle N's CPU confirm overlaps cycle N+1's device
-        scan, exactly the move that overlapped host→device transfer in
-        PR 7.  The extra stage only engages while a next cycle is in
-        flight; an idle tail resolves immediately."""
-        pending: Optional[_MeshCycle] = None     # scan in flight
-        confirming: Optional[_MeshCycle] = None  # confirm in flight
-        while not self._stop.is_set():
-            if pending is None and confirming is None:
+            if scanning is not None:
+                # the confirm stage first: verdicts first, and a scan
+                # is collected into a confirm stage that is free
+                self._wait_stage(confirming.wait_confirm
+                                 if confirming is not None
+                                 else scanning.wait_scan)
+            elif confirming is not None:
+                td0 = time.perf_counter()
+                batch = self._drain_idle(first_timeout=self._slice_s())
+                # waiting for a batch with a free scan stage is the
+                # loop starved, not the open cycle's service time:
+                # excluded from its clock and from the queue math's
+                # samples (reviewer catch)
+                dt = time.perf_counter() - td0
+                self._drain_since_resolve += dt
+                confirming.overlap_drain_s += dt
+                if batch:
+                    scanning = self._launch_cycle(batch)
+                    confirming.held_open = True
+            else:
                 flight.set_cycle(0)
                 batch = self._drain_idle()
                 if not batch:
                     # idle drain: decay the brownout ladder's signal
                     self.pipeline.load_controller.observe(0.0)
                     continue
-            else:
-                td0 = time.perf_counter()
-                # the interleaved drain IS the double-buffer overlap
-                # window — the flight recorder's drain-occupancy signal
-                batch = self._drain_idle(first_timeout=self.max_delay_s)
-                # the interleaved drain wait is the double buffer's
-                # idle window, not the in-flight cycles' service time —
-                # excluded from their clocks so the queue-math EWMA and
-                # the deadline-overrun accounting describe real work
-                # (reviewer catch)
-                dt = time.perf_counter() - td0
-                self._drain_since_resolve += dt
-                if pending is not None:
-                    pending.overlap_drain_s += dt
-                if confirming is not None:
-                    confirming.overlap_drain_s += dt
-            cycle = self._launch_cycle(batch) if batch else None
-            if confirming is not None:
-                # cycle N-1's confirm ran while N launched above —
-                # resolve its futures before blocking on N's lanes
+                scanning = self._launch_cycle(batch)
+            now = time.perf_counter()
+            if confirming is not None and (
+                    confirming.wait_confirm(0.0)
+                    or now >= confirming.confirm_deadline):
                 self._resolve_cycle(confirming)
                 confirming = None
-            if pending is not None:
-                self._collect_cycle(pending)
-                if cycle is not None and \
-                        self.pipeline.confirm_pool.n_workers > 1:
-                    # hold the confirm open: it crunches on the pool
-                    # workers while the freshly launched cycle's scan
+            if scanning is not None and confirming is None and (
+                    scanning.wait_scan(0.0)
+                    or now >= scanning.scan_deadline):
+                self._collect_cycle(scanning)
+                if scanning.confirm_shares():
+                    # the confirm is out on the walkers: held open, it
+                    # crunches there while the next cycle's scan
                     # crunches on the chips
-                    confirming = pending
+                    confirming = scanning
                 else:
-                    self._resolve_cycle(pending)
-            pending = cycle
+                    self._resolve_cycle(scanning)
+                scanning = None
         # shutdown with cycles in flight: their futures must still
         # resolve (exactly-one-verdict outlives the loop)
-        for c, full in ((confirming, False), (pending, True)):
+        for c, full in ((confirming, False), (scanning, True)):
             if c is None:
                 continue
             try:
@@ -1684,8 +1518,19 @@ class Batcher:
                         _safe_set(fut, _fail_open_verdict(rid))
                 self._clear_guard(c.guard)
 
-    def _launch_cycle(self, batch: List) -> "_MeshCycle":
-        """Phase A of a mesh cycle: classify the drained batch, run the
+    def _wait_stage(self, wait) -> None:
+        """One slice (a batch window) of waiting for a stage, under the
+        ``drain_idle`` span like the drains: the loop with nothing it
+        could start."""
+        with flight.span(EV_DRAIN) as sp:
+            wait(self._slice_s())
+        self._drain_idle_us += sp.us
+
+    def _slice_s(self) -> float:
+        return max(self.max_delay_s, self.MIN_SLICE_S)
+
+    def _launch_cycle(self, batch: List) -> "_Cycle":
+        """Phase A of a cycle: classify the drained batch, run the
         pinned-lane stream step, reroute oversized bodies, canary-split,
         shard the remaining requests across the serving lanes (balanced
         by scanned bytes, half-open lanes capped to a canary share) and
@@ -1693,7 +1538,7 @@ class Batcher:
         touching any device result — the transfer/compute runs while
         the caller preps the next cycle."""
         t0 = time.perf_counter()
-        c = _MeshCycle()
+        c = _Cycle()
         c.t0 = t0
         reqs, deg_reqs, begins, chunks, finishes, c.guard = \
             self._classify_batch(batch, t0)
@@ -1776,48 +1621,52 @@ class Batcher:
                     normal, targets,
                     weight=lambda it: len(it[1].body) + len(it[1].uri)
                     + 64)
-                first_share = True
                 for (lane, lroute), part in zip(targets, shares):
-                    if not part:
-                        continue
-                    # what this thread does for the share books to its
-                    # lane too (scan_pack here; walk, fold and the
-                    # hand-off back at collect and resolve)
-                    flight.set_lane(lane.index)
-                    try:
-                        flight.begin(EV_LAUNCH, cycle=c.cid,
-                                     tag=lane.index, arg=len(part))
-                        try:
-                            job = c.pipeline.detect_launch(
-                                [r for _, r, _ in part], lane=lane,
-                                count_batch=first_share)
-                        finally:
-                            flight.end(EV_LAUNCH, cycle=c.cid,
-                                       tag=lane.index)
-                        first_share = False
-                    except Exception:
-                        # host prep died for this share: fail it open
-                        # and count the failure against THIS lane only
-                        lane.stats.errors += 1
-                        lane.breaker.record_failure()
-                        c.pipeline.stats.count_fail_open(len(part))
-                        for _ts, r, fut in part:
-                            _safe_set(fut,
-                                      _fail_open_verdict(r.request_id))
-                        continue
-                    lane.stats.requests += len(part)
-                    lane.stats.rows += job.live_rows
-                    lane.stats.padded_rows += job.padded_rows
-                    c.lane_parts.append((lane, lroute, part, job))
-                flight.set_lane(-1)
+                    if part:
+                        self._launch_share(c, lane, lroute, part)
             c.launch_d_engine = ps.engine_us - c.engine_us0
             c.launch_d_prep = ps.prep_us - c.prep_us0
             c.launch_d_compiles = ps.engine_compiles - c.compiles0
-        c.own_us = int((time.perf_counter() - t0) * 1e6)
+        t_handed = time.perf_counter()
+        c.scan_deadline = t_handed + self.hang_budget_s
+        c.own_us = int((t_handed - t0) * 1e6)
         return c
 
-    def _collect_cycle(self, c: "_MeshCycle") -> None:
-        """Phase B1 of a mesh cycle: bounded per-lane SCAN collection
+    def _launch_share(self, c: "_Cycle", lane: Lane, lroute: str,
+                      part: List) -> None:
+        """One lane's share of cycle ``c``: host prep on this thread,
+        the scan handed to the lane's worker (caller holds the swap
+        lock).  A share whose prep dies fails open here and counts
+        against THIS lane only."""
+        # what this thread does for the share books to its lane too
+        # (scan_pack here; walk, fold and the hand-off back at collect
+        # and resolve)
+        flight.set_lane(lane.index)
+        try:
+            flight.begin(EV_LAUNCH, cycle=c.cid, tag=lane.index,
+                         arg=len(part))
+            try:
+                # one admission cycle = one batch, however many shares
+                job = c.pipeline.detect_launch(
+                    [r for _, r, _ in part], lane=lane,
+                    count_batch=not c.lane_parts)
+            finally:
+                flight.end(EV_LAUNCH, cycle=c.cid, tag=lane.index)
+                flight.set_lane(-1)
+        except Exception:
+            lane.stats.errors += 1
+            lane.breaker.record_failure()
+            c.pipeline.stats.count_fail_open(len(part))
+            for _ts, r, fut in part:
+                _safe_set(fut, _fail_open_verdict(r.request_id))
+            return
+        lane.stats.requests += len(part)
+        lane.stats.rows += job.live_rows
+        lane.stats.padded_rows += job.padded_rows
+        c.lane_parts.append((lane, lroute, part, job))
+
+    def _collect_cycle(self, c: "_Cycle") -> None:
+        """Phase B1 of a cycle: bounded per-lane SCAN collection
         (wait, mask) + confirm LAUNCH on the pool, per-lane breaker
         accounting, the global CPU fallback share, and the canary
         candidate share.  Shares whose lane wedged or raised resolve
@@ -1827,14 +1676,14 @@ class Batcher:
         # tenant-degraded share already resolved at launch
         done: List = list(c.deg_done)
         p = c.pipeline
-        # ONE hang budget for the whole collection: the lanes dispatched
-        # concurrently at launch, so they share the deadline — k
-        # simultaneously wedged lanes must stall the dispatch thread
-        # for one budget, not k stacked budgets (reviewer catch); a
-        # healthy lane that finished long ago returns instantly
-        # regardless of what its siblings burned
+        # ONE hang budget for the whole collection, counted from the
+        # hand-over: the lanes dispatched concurrently at launch, so
+        # they share the deadline — k simultaneously wedged lanes must
+        # stall the dispatch thread for one budget, not k stacked
+        # budgets (reviewer catch); a healthy lane that finished long
+        # ago returns instantly regardless of what its siblings burned
         t_collect = time.perf_counter()
-        collect_deadline = t_collect + self.hang_budget_s
+        collect_deadline = c.scan_deadline
         fins: List = []   # (lane, part, _FinishJob)
         scans: List = []  # (lane index, submit ns, result ns) collected
         waited_us = 0     # blocked on the lanes' results
@@ -1843,19 +1692,34 @@ class Batcher:
             ps = p.stats
             e0, cf0 = ps.engine_us, ps.confirm_us
             pp0, cp0 = ps.prep_us, ps.engine_compiles
+            cand = c.ro.candidate if c.cand_items else None
+            cand_lane = (c.lane_parts[0][0] if c.lane_parts
+                         else self.lanes.primary)
+            if c.cand_items and cand is None:
+                # rolled back between the split and here: the share
+                # belongs to the incumbent now, and rides this cycle as
+                # one more share of it — the same launch, collection,
+                # fail-open and breaker accounting as its siblings
+                if c.route == "fallback":
+                    c.fallback_items = c.fallback_items + c.cand_items
+                else:
+                    self._launch_share(c, cand_lane, "device",
+                                       c.cand_items)
+                    collect_deadline = t_collect + self.hang_budget_s
+                c.cand_items = []
             for lane, lroute, part, job in c.lane_parts:
                 flight.set_lane(lane.index)
                 try:
-                    flight.begin(EV_COLLECT, cycle=c.cid,
-                                 tag=lane.index)
+                    # a span, so that a profiler trace names this
+                    # thread's wait for the lane (ipt:lane_collect)
                     try:
-                        fin = p.detect_collect_launch(
-                            job, timeout=max(
-                                collect_deadline - time.perf_counter(),
-                                0.001))
+                        with flight.span(EV_COLLECT, cycle=c.cid,
+                                         tag=lane.index):
+                            fin = p.detect_collect_launch(
+                                job, timeout=max(
+                                    collect_deadline
+                                    - time.perf_counter(), 0.001))
                     finally:
-                        flight.end(EV_COLLECT, cycle=c.cid,
-                                   tag=lane.index)
                         waited_us += job.wait_us
                     if job.t_done_ns:
                         scans.append((lane.index, job.t_submit_ns,
@@ -1884,7 +1748,7 @@ class Batcher:
             self._scan_spans(c.cid, scans)
             if c.fallback_items:
                 # every lane down: exact CPU confirm-only verdicts, the
-                # PR 4 fallback as the mesh's last resort
+                # PR 4 fallback as the last resort
                 self.stats.cpu_fallback_batches += 1
                 freqs = [r for _, r, _ in c.fallback_items]
                 try:
@@ -1898,11 +1762,9 @@ class Batcher:
             cand_verdicts: List[Verdict] = []
             if c.cand_items:
                 creqs = [r for _, r, _ in c.cand_items]
-                cand_lane = (c.lane_parts[0][0] if c.lane_parts
-                             else self.lanes.primary)
                 try:
                     cand_verdicts = self._detect_candidate(
-                        creqs, c.ro, c.route, lane=cand_lane)
+                        creqs, c.ro, cand, c.route, cand_lane)
                 except Exception:
                     cand_verdicts = [_fail_open_verdict(r.request_id)
                                      for r in creqs]
@@ -1916,18 +1778,21 @@ class Batcher:
         c.pending_fins = fins
         c.done = done
         c.cand_verdicts = cand_verdicts
+        c.confirm_deadline = (time.perf_counter()
+                              + p.confirm_pool.hang_budget_s)
         c.own_us += max(
             int((time.perf_counter() - t_collect) * 1e6) - waited_us, 0)
 
     def _scan_spans(self, cid: int, scans: List) -> None:
-        """What only a cycle over several lanes has: each collected
-        share's scan interval (``lane_scan``: handed to the lane's
+        """What only a cycle over several lanes has (one lane's scan is
+        its cycle's, and books nothing here): each collected share's
+        scan interval (``lane_scan``: handed to the lane's
         worker → result on the host, summed per lane) and the cycle's
         wall span of the same (``scan_wall``: first share handed over →
         last result).  Their ratio says how far the lanes' scans
         overlapped: N when all were in flight together, 1 when they ran
         one after another."""
-        if not scans or not flight.enabled:
+        if not (scans and flight.enabled and self._lane_series):
             return
         for lane_idx, t_submit, t_done in scans:
             flight.span_at(EV_LANE_SCAN, t_submit, t_done, cycle=cid,
@@ -1939,13 +1804,13 @@ class Batcher:
         flight.span_at(EV_SCAN_WALL, t0, t1, cycle=cid, arg=len(scans))
         self._count_us(self.lane_cycle_us, "scan_wall", (t1 - t0) // 1000)
 
-    def _resolve_cycle(self, c: "_MeshCycle") -> None:
-        """Phase B2 of a mesh cycle: bounded-join the confirm shares,
+    def _resolve_cycle(self, c: "_Cycle") -> None:
+        """Phase B2 of a cycle: bounded-join the confirm shares,
         resolve the remaining verdict futures, rollout hooks, and the
         cycle's observability.  With an inline confirm pool this runs
         back-to-back with B1 (the confirm already completed inside the
-        launch — the classic PR 7 loop); with pool workers it runs one
-        drain later, the confirm having overlapped the next cycle's
+        launch); with pool workers and a next cycle launched it runs
+        one drain later, the confirm having overlapped that cycle's
         scan dispatch."""
         done = c.done
         p = c.pipeline
@@ -1990,8 +1855,12 @@ class Batcher:
             ro.tick()
         self._clear_guard(c.guard)
         c.span.end()
+        if c.held_open:
+            self.stats.cycles_held += 1
+        else:
+            self.stats.cycles_direct += 1
         t_end = time.perf_counter()
-        if flight.enabled:
+        if flight.enabled and self._lane_series:
             # the serial host work of the cycle: this thread's launch,
             # collect and resolve phases for all lanes, without its
             # waits for their results
@@ -1999,18 +1868,16 @@ class Batcher:
                            c.own_us + int((t_end - t_resolve) * 1e6))
         took = max(t_end - c.t0 - c.overlap_drain_s, 0.0)
         # the queue math's service time is what the loop takes PER
-        # CYCLE.  ``took`` is this cycle's launch → resolve, and under
-        # the double buffer that stretch also holds the collect and
-        # resolve of the cycle before and the launch of the one after:
+        # CYCLE.  ``took`` is this cycle's launch → resolve, and with
+        # two cycles in flight that stretch also holds the resolve of
+        # the cycle before and the launch and scan of the one after:
         # about two cycles' work.  Fed to the estimator it doubled the
         # estimated wait, and admission shed at half the deadline (on
         # the four-chip host 1.7-2.3% of a window's requests, after
         # each long collection pause).  So: since the last resolve —
         # or since this cycle's launch, where the loop stood idle
-        # before it — less the drains in between.  Those are the
-        # loop's since that resolve, not the cycle's own: with the
-        # confirm held open a cycle lives through two drains, and only
-        # the later one lies in the stretch
+        # before it — less the loop's starved drains in between (not
+        # its waits for a stage: those are the service)
         if self._last_resolve > c.t0:
             service = max(t_end - self._last_resolve
                           - self._drain_since_resolve, 0.0)
@@ -2019,9 +1886,10 @@ class Batcher:
         self._last_resolve = t_end
         self._drain_since_resolve = 0.0
         if d_compiles == 0:
-            self._batch_ewma.update(
-                min(service, 2.0 * self.hard_deadline_s))
-            self._batch_ewma_n += 1
+            # cycles that paid a serve-time XLA compile are no samples,
+            # for the queue math or the ladder: a cold-start compile is
+            # warm-up, not load
+            self._service.update(service)
             self.pipeline.load_controller.observe(c.max_queue_delay_us)
         self.stats.batch_us_sum += int(took * 1e6)
         if took > self.hard_deadline_s:

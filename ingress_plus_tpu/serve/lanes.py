@@ -64,6 +64,12 @@ class LanePending:
     def done(self) -> bool:
         return self._ev.is_set()
 
+    def wait_done(self, timeout: float) -> bool:
+        """Block up to ``timeout`` for the call to end, without taking
+        its result: True once it has (the dispatch loop's sliced waits
+        on a stage; ``wait`` then returns at once)."""
+        return self._ev.wait(timeout)
+
     def wait(self, timeout: Optional[float]):
         t_wait = time.monotonic_ns()
         if not self._ev.wait(timeout):

@@ -77,6 +77,9 @@ _OVERFLOW = object()        # sentinel: stream rejected by the cap
 METRIC_HELP = {
     "ipt_requests_total": "requests served to a verdict",
     "ipt_batches_total": "dispatch cycles executed",
+    "ipt_cycles_total":
+        "dispatch cycles resolved: held = a later cycle was launched "
+        "while this one's confirm stage was open, else direct",
     "ipt_queue_delay_us_sum": "cumulative admission-queue wait (us)",
     "ipt_batch_us_sum": "cumulative dispatch-cycle wall time (us)",
     "ipt_max_batch": "largest batch seen since startup",
@@ -483,6 +486,9 @@ class ServeLoop:
             "ipt_requests_total %d" % s.completed,
             "# TYPE ipt_batches_total counter",
             "ipt_batches_total %d" % s.batches,
+            "# TYPE ipt_cycles_total counter",
+            'ipt_cycles_total{confirm="held"} %d' % s.cycles_held,
+            'ipt_cycles_total{confirm="direct"} %d' % s.cycles_direct,
             "# TYPE ipt_queue_delay_us_sum counter",
             "ipt_queue_delay_us_sum %d" % s.queue_delay_us_sum,
             "# TYPE ipt_batch_us_sum counter",
@@ -1773,8 +1779,8 @@ def main(argv=None) -> None:
                          "where that leaves fewer than two.  1 runs the "
                          "classic serial confirm inline.  A wedged "
                          "worker fails only its request share open; "
-                         "with the mesh loop, confirm overlaps the next "
-                         "cycle's scan")
+                         "more than one, and a cycle's confirm overlaps "
+                         "the next cycle's scan")
     ap.add_argument("--confirm-cache", type=int, default=0,
                     help="cross-cycle verdict cache entries "
                          "(docs/RETUNE.md): bounded confirm-outcome "

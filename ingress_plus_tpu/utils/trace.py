@@ -605,6 +605,37 @@ class Ewma:
             self.value = None
 
 
+class RecentMedian:
+    """Median of the last ``window`` samples — the batcher's per-cycle
+    service time (admission-time deadline shedding divides by it).
+
+    A median, because what it feeds decides whether a request is shed:
+    fewer than half a window of outliers, however long each was, leave
+    it where it stood (one stalled cycle is no service rate), and more
+    than half a window of slow cycles in a row ARE the service rate and
+    move it at once (five of nine).  An EWMA with a clamp could do one
+    or the other, depending on how many cycles stand between an arrival
+    and its verdict.  Single writer (the dispatch thread); ``get`` is a
+    float read."""
+
+    __slots__ = ("n", "value", "_last")
+
+    def __init__(self, window: int = 9):
+        self.n = 0                      # samples seen
+        self.value: Optional[float] = None
+        self._last: deque = deque(maxlen=window)
+
+    def update(self, x: float) -> float:
+        self._last.append(x)
+        self.n += 1
+        self.value = out = sorted(self._last)[len(self._last) // 2]
+        return out
+
+    def get(self, default: float = 0.0) -> float:
+        v = self.value
+        return default if v is None else v
+
+
 def bounded_counter_series(name: str, label: str,
                            counts: Dict[str, int], cap: int = 30,
                            extra: Optional[Dict[str, str]] = None,

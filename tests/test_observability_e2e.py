@@ -111,12 +111,20 @@ def test_surfaces_agree_on_stage_timings(server):
     assert got[4100]["attack"]
 
     # --- /metrics: Prometheus stage histograms with real observations
-    metrics = _get("/metrics").decode()
+    # (a cycle's verdicts go out before its histograms are fed: the
+    # last cycle's observations may be a moment behind its replies)
+    from ingress_plus_tpu.utils.trace import stage_breakdown_from_metrics
+    deadline = time.monotonic() + 10
+    while True:
+        metrics = _get("/metrics").decode()
+        sb = stage_breakdown_from_metrics(metrics)
+        if (sb and sb["e2e"]["count"] >= len(reqs)) \
+                or time.monotonic() > deadline:
+            break
+        time.sleep(0.05)
     for stage in ("queue", "prep", "scan", "confirm", "batch", "e2e"):
         assert 'ipt_stage_us_bucket{stage="%s"' % stage in metrics, stage
     assert "ipt_batch_size_bucket" in metrics
-    from ingress_plus_tpu.utils.trace import stage_breakdown_from_metrics
-    sb = stage_breakdown_from_metrics(metrics)
     assert sb is not None
     assert sb["e2e"]["count"] >= len(reqs)
     assert sb["queue"]["count"] >= len(reqs)
@@ -128,7 +136,11 @@ def test_surfaces_agree_on_stage_timings(server):
     assert "4100" in tr["batch"]["request_ids"]
     stages = tr["stages"]
     assert stages["batch_us"] > 0
-    assert stages["batch_us"] >= stages["scan_us"] + stages["confirm_us"]
+    # the pipelined loop drains for the next cycle while this one's
+    # scan runs on the lane's worker: those drains are taken off the
+    # cycle's own clock, and lie in its drain_idle
+    assert stages["batch_us"] + stages["drain_idle_us"] >= \
+        stages["scan_us"] + stages["confirm_us"]
 
     # --- /debug/slow: the same request's exemplar, with matching spans
     slow = json.loads(_get("/debug/slow"))["slowest"]
@@ -416,7 +428,7 @@ def test_debug_profile_writes_a_trace_holding_the_spans(server):
     assert spans["ipt:scan_launch"], sorted(spans)
     for name in ("ipt:cycle", "ipt:host_prep", "ipt:scan_pack",
                  "ipt:scan_dispatch", "ipt:scan_wait", "ipt:confirm_walk",
-                 "ipt:finalize_join", "ipt:confirm_fold", "ipt:lane_call",
+                 "ipt:finalize_join", "ipt:confirm_fold", "ipt:lane_collect",
                  "ipt:drain_idle"):
         assert name in spans, (name, sorted(spans))
     # per-request instants stay out of the profiler's trace

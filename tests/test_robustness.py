@@ -217,8 +217,8 @@ def test_deadline_shed_by_queue_math(cr):
     # queued, the estimator is set by hand
     b._stop.set()
     b._thread.join(timeout=5)
-    b._batch_ewma.update(1.0)   # "one second per cycle" service rate
-    b._batch_ewma_n = 8         # past the cold-estimator sample floor
+    for _ in range(8):          # past the cold-estimator sample floor
+        b._service.update(1.0)  # "one second per cycle" service rate
     f1 = b.submit(Request(uri="/a", request_id="a"))   # depth 0: admitted
     f2 = b.submit(Request(uri="/b", request_id="b"))   # est 2s > 0.25: shed
     assert not f1.done()
@@ -330,11 +330,11 @@ def test_watchdog_releases_wedged_dispatch_thread(cr):
     orig = b._stream_step_guarded
     release = threading.Event()
 
-    def wedged(begins, chunks, finishes, route):
+    def wedged(begins, chunks, finishes, route, lane=None):
         # runs ON the dispatch thread (unlike _stream_step, which now
         # rides the watchdogged lane) — this wedges the dispatcher
         release.wait(timeout=4.0)
-        return orig(begins, chunks, finishes, route)
+        return orig(begins, chunks, finishes, route, lane=lane)
 
     b._stream_step_guarded = wedged
     try:
