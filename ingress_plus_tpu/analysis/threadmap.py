@@ -139,6 +139,14 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
         description="ipt-oversized side worker: inflates and "
                     "chunk-scans oversized bodies off the batch path"),
     ThreadRoot(
+        name="oversized_finish",
+        entries=("serve/batcher.py::Batcher._run_oversized_finish",),
+        concurrent=False,
+        description="ipt-oversized-finish: confirms a scanned oversized "
+                    "body (the walk out on a walker process) and "
+                    "resolves its verdict while the side worker scans "
+                    "the next one"),
+    ThreadRoot(
         name="shadow",
         entries=("control/rollout.py::RolloutController._shadow_run",),
         concurrent=False,

@@ -52,11 +52,13 @@ one tenant on the box all of this collapses to the PR 4 behavior.
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
 from collections import deque
 from concurrent.futures import Future
+from contextlib import contextmanager
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional
 
@@ -86,11 +88,16 @@ from ingress_plus_tpu.utils.trace import (
     EV_OVERSIZED,
     EV_QUEUE,
     EV_SCAN_WALL,
+    EV_SIDE_CONFIRM,
+    EV_SIDE_LOCK,
+    EV_SIDE_SCAN,
+    EV_SIDE_WAIT,
     EV_STREAM,
     EV_SUBMIT,
     EV_VERDICT,
     EV_WATCHDOG,
     PER_DISPATCH,
+    SIDE_STAGES,
     STAGES,
     SUBSTAGES,
     BatchTrace,
@@ -104,6 +111,10 @@ from ingress_plus_tpu.utils.trace import (
     named_lock,
     request_tag,
 )
+
+#: the stream engine's own unpack stages (serve/stream.py StreamState):
+#: off for a side-lane body, which arrives unpacked
+_STREAM_UNPACKERS = frozenset({"gzip", "base64", "json"})
 
 #: backward-compat alias — the single-device worker grew into
 #: serve/lanes.LaneWorker when the lane plane went per-chip
@@ -405,8 +416,15 @@ class BatcherStats:
     stream_chunks: int = 0
     stream_bytes: int = 0
     # non-streamed requests whose body exceeded the batched L tiers and
-    # was auto-routed through the stream engine
-    oversized_rerouted: int = 0
+    # was auto-routed through the stream engine, and their body bytes as
+    # they arrived, by reroute kind ("raw": the body itself is over the
+    # threshold; "unpack": it unpacks past it) — /metrics
+    # ipt_oversized_rerouted_total{kind=}, ipt_oversized_bytes_total{kind=}
+    # (side worker only)
+    oversized_requests: Dict[str, int] = field(
+        default_factory=lambda: {"raw": 0, "unpack": 0})
+    oversized_bytes: Dict[str, int] = field(
+        default_factory=lambda: {"raw": 0, "unpack": 0})
     # fail-safe plane (docs/ROBUSTNESS.md)
     hangs: int = 0                 # device-lane hang-budget overruns
     cpu_fallback_batches: int = 0  # batches served breaker-open (CPU)
@@ -426,6 +444,10 @@ class BatcherStats:
         default_factory=lambda: named_lock("BatcherStats._lock"),
         repr=False, compare=False)
 
+    @property
+    def oversized_rerouted(self) -> int:
+        return sum(self.oversized_requests.values())
+
     def count_submitted(self) -> None:
         with self._lock:
             self.submitted += 1
@@ -441,6 +463,7 @@ class BatcherStats:
 
     def snapshot(self) -> dict:
         d = {k: v for k, v in self.__dict__.items() if k != "_lock"}
+        d["oversized_rerouted"] = self.oversized_rerouted
         if self.batches:
             d["avg_batch"] = self.completed / self.batches
             d["avg_batch_us"] = self.batch_us_sum / self.batches
@@ -457,6 +480,13 @@ class Batcher:
     # buffered body the same way†)
     OVERSIZE_THRESHOLD = DetectionPipeline.L_BUCKETS[-1]
     OVERSIZE_CHUNK = 64 << 10
+    #: a side-lane scan stream is scanned as this many pieces side by
+    #: side (``_side_parts``): a quarter of the sequential device steps
+    SIDE_PIECES = 4
+    SIDE_PIECE_MIN = 4096
+    #: bytes either side of a cut that its seam part holds: over 32
+    #: (the longest factor) x 9 (the longest escape of one byte)
+    SIDE_SEAM = 512
     #: the loop's waits while a cycle is in flight last one batch
     #: window, and no less than this (a window of zero must not spin)
     MIN_SLICE_S = 0.0002
@@ -499,6 +529,9 @@ class Batcher:
         # drain_idle per drain, reply per request): the same metric
         # family, their own dict — a sum over STAGES must not meet them
         self.subhist: dict = {s: Histogram() for s in SUBSTAGES}
+        # the oversized side lane's stages, per rerouted request (the
+        # side worker observes them; the same metric family)
+        self.sidehist: dict = {s: Histogram() for s in SIDE_STAGES}
         # what more than one lane adds to /metrics (ipt_lane_stage_us,
         # ipt_lane_cycle_us): {(lane, sub-stage): [µs, cycles]} and
         # {"scan_wall" | "dispatch_own": [µs, cycles]}; one lane's
@@ -593,9 +626,18 @@ class Batcher:
         # thread (submit side) and the oversized worker (release side).
         self._oversized_by_tenant: Dict[int, int] = {}
         self._oversized_lock = named_lock("Batcher._oversized_lock")
+        # two stages, a thread each, so that one request's confirm walk
+        # (out on a walker process) overlaps the next one's scan; a
+        # request holds its slot through both, so the finish queue is
+        # bounded by the slots
+        self._oversized_fin_q: "queue.Queue" = queue.Queue()
         self._oversized_thread = threading.Thread(
             target=self._run_oversized, daemon=True, name="ipt-oversized")
         self._oversized_thread.start()
+        self._oversized_fin_thread = threading.Thread(
+            target=self._run_oversized_finish, daemon=True,
+            name="ipt-oversized-finish")
+        self._oversized_fin_thread.start()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="ipt-batcher")
         self._thread.start()
@@ -627,7 +669,8 @@ class Batcher:
         per-lane stats that the dispatch thread mutates under this same
         lock — a bare reset raced a mid-cycle fold (concheck
         conc.unguarded-mutation, ISSUE 11)."""
-        for h in (*self.hist.values(), *self.subhist.values()):
+        for h in (*self.hist.values(), *self.subhist.values(),
+                  *self.sidehist.values()):
             h.reset()
         self.lane_stage_us = {}
         self.lane_cycle_us = {}
@@ -808,35 +851,106 @@ class Batcher:
                 self._oversized_by_tenant.pop(tenant, None)
 
     def _run_oversized(self) -> None:
+        """The side lane's scan stage: one request at a time, handed
+        on to the finish stage with its stream scanned."""
         flight.register_thread("oversized")
         while not self._stop.is_set():
             try:
                 ts, request, plan, fut = self._oversized_q.get(timeout=0.1)
             except queue.Empty:
                 continue
+            handed_on = False
             try:
                 flight.begin(EV_OVERSIZED, cycle=0, tag=request.tenant,
                              arg=len(request.body))
                 try:
-                    self._detect_oversized(ts, request, plan, fut)
+                    handed_on = self._detect_oversized(ts, request, plan, fut)
                 finally:
                     flight.end(EV_OVERSIZED, cycle=0,
                                tag=request.tenant)
             finally:
-                self._release_oversized_slot(request.tenant)
+                if not handed_on:
+                    self._release_oversized_slot(request.tenant)
+
+    def _run_oversized_finish(self) -> None:
+        """The side lane's finish stage: confirm, verdict, slot."""
+        flight.register_thread("oversized_finish")
+        while not self._stop.is_set():
+            try:
+                item = self._oversized_fin_q.get(timeout=0.1)
+            except queue.Empty:
+                continue
+            try:
+                self._finish_oversized(*item)
+            finally:
+                self._release_oversized_slot(item[1].tenant)
+
+    @classmethod
+    def _side_parts(cls, scanned: bytes) -> List[bytes]:
+        """A side-lane scan stream cut into up to ``SIDE_PIECES`` pieces
+        of at least ``SIDE_PIECE_MIN`` bytes, and a seam around each cut
+        (``SIDE_SEAM`` bytes either side).  Every factor occurrence of
+        the whole stream, in any variant, is one of some part: a factor
+        is at most 32 bytes of a variant's output, a byte of output
+        comes from at most 9 of input (``&entity;``), so an occurrence
+        that lies across a cut lies inside its seam, where it decodes as
+        in the whole; any other lies inside one piece.  What a part's
+        own edges add (an escape cut in half decodes otherwise) are
+        candidates the whole stream has not: the confirm stage, which
+        walks the request itself, decides as ever."""
+        n = min(cls.SIDE_PIECES, max(1, len(scanned) // cls.SIDE_PIECE_MIN))
+        cuts = [len(scanned) * k // n for k in range(n + 1)]
+        return ([scanned[a:b] for a, b in zip(cuts, cuts[1:])]
+                + [scanned[c - cls.SIDE_SEAM:c + cls.SIDE_SEAM]
+                   for c in cuts[1:-1]])
+
+    @contextmanager
+    def _side_hold(self, held: List[int]):
+        """The swap lock as the side lane takes it: one hold, its
+        ``side_lock`` span, and its µs added to ``held[0]`` (the
+        request's sum, observed once in ``_finish_oversized``)."""
+        with self._swap_lock:
+            t0 = time.perf_counter()
+            with flight.span(EV_SIDE_LOCK):
+                yield
+            held[0] += int((time.perf_counter() - t0) * 1e6)
 
     def _detect_oversized(self, ts: float, request: Request, plan,
-                          fut: "Future[Verdict]") -> None:
-        """Run one oversized request through the stream engine (the
-        oversized worker thread).  The swap lock is taken per STEP, not
-        for the whole body — batches interleave between chunks, so a
-        16MB body adds at most one chunk-scan of latency to any cycle
-        (round-2 advisor head-of-line fix).  The inflate runs entirely
-        off-lock.  A ruleset hot-swap mid-body is detected by the stream
-        engine's version check at finish and fails open, same as
-        in-flight wire streams."""
+                        fut: "Future[Verdict]") -> bool:
+        """Scan one oversized request through the stream engine (the
+        side lane's scan thread) and hand it to the finish stage; False
+        where its verdict is resolved here, fail-open.
+
+        What is scanned: the batched path's own scan stream of the
+        body, ``unpack_body(body, headers, parsers_off)`` with its
+        scan-only segments (inflated base, url-decoded form copy,
+        extracted JSON / XML strings, base64 decode), for BOTH reroute
+        kinds, cut into pieces and seams (``_side_parts``) that are fed
+        side by side through the stream's incremental variant chain in
+        CHUNK_L waves with the automaton state carried between them:
+        the candidates hold every one the batched prefilter would have
+        found had its rows no 16 KiB bound.  What is confirmed
+        (``_finish_oversized``): the request as it arrived
+        (``confirm_request``), which the confirm twin unpacks once
+        itself — never the unpacked copy, whose url-decoded segment it
+        would decode a second time (ROADMAP F1).
+
+        The swap lock is taken once a wave for the generation check
+        (and for the head's prefilter and the verdict's fold), never for
+        a chunk, a wave's launch or device time, or the confirm walk: a
+        batched cycle never waits out a wave.  The unpack runs off-lock.  A ruleset hot-swap
+        mid-body is detected by the stream engine's version check and
+        fails open, same as in-flight wire streams."""
         kind, body, headers = plan
-        self.stats.oversized_rerouted += 1
+        t_taken = time.perf_counter()
+        st = self.stats
+        st.oversized_requests[kind] += 1
+        st.oversized_bytes[kind] += len(body)
+        now_ns = time.monotonic_ns()
+        wait_us = int((t_taken - ts) * 1e6)
+        flight.span_at(EV_SIDE_WAIT, now_ns - wait_us * 1000, now_ns,
+                       tag=request.tenant)
+        self.sidehist["side_wait"].observe(wait_us)
         if self.breaker.state != CircuitBreaker.CLOSED:
             # oversized scans ride the DEFAULT device (the stream
             # engine is not lane-pinned), whose health the PRIMARY
@@ -845,33 +959,74 @@ class Batcher:
             # Healthy sibling lanes don't help here (reviewer catch:
             # an any-lane-closed gate let this worker scan a wedged
             # default device).
-            self.pipeline.stats.count_fail_open()
-            _safe_set(fut, _fail_open_verdict(request.request_id))
-            return
+            self._resolve_oversized(ts, request, fut, None)
+            return False
+        held = [0]
+        hold = functools.partial(self._side_hold, held)
+        engine = self.stream_engine
         try:
-            if kind == "unpack":
-                # full DoS-bounded inflate + extraction, OFF the lock;
-                # Content-Encoding must go, or the stream's sniffer
-                # would re-inflate plaintext
-                body = unpack_body(body, headers, request.parsers_off)
-                headers = {k: v for k, v in headers.items()
-                           if k.lower() != "content-encoding"}
-            meta = replace(request, body=b"", headers=headers)
-            with self._swap_lock:
-                h = self.stream_engine.begin(meta, body_cap=len(body))
-                h.base_hits = self.pipeline.prefilter([meta])[0]
-            for i in range(0, len(body), self.OVERSIZE_CHUNK):
-                inc = h.feed(body[i:i + self.OVERSIZE_CHUNK])
-                with self._swap_lock:
-                    self.stream_engine.scan(inc)
-            with self._swap_lock:
-                self.stream_engine.scan(h.flush())
-                v = self.stream_engine.finish(h)
+            with flight.span(EV_SIDE_SCAN, arg=len(body)) as sp_scan:
+                # DoS-bounded inflate + extraction, OFF the lock.  The
+                # stream's own unpackers stay off: what it is fed IS
+                # unpacked, and a second inflate, base64 or gRPC pass
+                # over it would scan rows the batched path never has
+                scanned = unpack_body(body, headers, request.parsers_off)
+                meta = replace(request, body=b"",
+                               parsers_off=frozenset(request.parsers_off)
+                               | _STREAM_UNPACKERS)
+                parts = self._side_parts(scanned)
+                with hold():
+                    handles = [engine.begin(meta, confirm_request=request)
+                               for _ in parts]
+                    h = handles[0]
+                    h.base_hits = self.pipeline.prefilter([meta])[0]
+                # the parts are scanned side by side, as rows of the
+                # same waves: the sequential steps a body costs are its
+                # longest part's, not its length's
+                for i in range(0, max(map(len, parts)), self.OVERSIZE_CHUNK):
+                    engine.scan(
+                        [item for hp, part in zip(handles, parts)
+                         for item in hp.feed(
+                             part[i:i + self.OVERSIZE_CHUNK])],
+                        hold=hold)
+                engine.scan([item for hp in handles for item in hp.flush()],
+                            hold=hold)
+                for hp in handles[1:]:
+                    h.match |= hp.match
+                    h.truncated |= hp.truncated
+                    h.error |= hp.error
+            self.sidehist["side_scan"].observe(sp_scan.us)
         except Exception:
+            self._resolve_oversized(ts, request, fut, None)
+            return False
+        self._oversized_fin_q.put((ts, request, fut, h, held))
+        return True
+
+    def _finish_oversized(self, ts: float, request: Request,
+                          fut: "Future[Verdict]", h: StreamState,
+                          held: List[int]) -> None:
+        """Confirm one scanned request and resolve it (the side lane's
+        finish thread): the walk goes to a walker process where the
+        pool has them, this thread waits for its answer."""
+        try:
+            with flight.span(EV_SIDE_CONFIRM) as sp_confirm:
+                v = self.stream_engine.finish(
+                    h, hold=functools.partial(self._side_hold, held),
+                    lone_to_walker=True)
+            self.sidehist["side_confirm"].observe(sp_confirm.us)
+            self.sidehist["side_lock"].observe(held[0])
+        except Exception:
+            v = None
+        self._resolve_oversized(ts, request, fut, v)
+
+    def _resolve_oversized(self, ts: float, request: Request,
+                           fut: "Future[Verdict]",
+                           v: Optional[Verdict]) -> None:
+        """Set a side-lane request's verdict (``None``: fail open) and
+        book its latency."""
+        if v is None:
             self.pipeline.stats.count_fail_open()
-            v = Verdict(request_id=request.request_id, blocked=False,
-                        attack=False, classes=[], rule_ids=[], score=0,
-                        fail_open=True)
+            v = _fail_open_verdict(request.request_id)
         _safe_set(fut, v)
         e2e_us = int((time.perf_counter() - ts) * 1e6)
         self.hist["e2e"].observe(e2e_us)
@@ -996,6 +1151,9 @@ class Batcher:
             engine=old.engine.rebuilt(ruleset))
         for shape in sorted(getattr(old, "seen_shapes", ())):
             new.warm_shape(*shape)
+        if self.stream_engine.warmed:
+            # the wave programs key on the pack's word count too
+            StreamEngine(new).warm()
         # mesh lanes: the incumbent's per-lane shapes warm on the NEW
         # pack too, each lane on its own ephemeral thread so the 8
         # device-bound compiles overlap instead of serializing in front
@@ -1134,6 +1292,7 @@ class Batcher:
             self.rollout.close()
         self._thread.join(timeout=5)
         self._oversized_thread.join(timeout=5)
+        self._oversized_fin_thread.join(timeout=5)
         self._watchdog.join(timeout=5)
         self.lanes.close()
         self.pipeline.confirm_pool.close()
@@ -1144,13 +1303,18 @@ class Batcher:
         # items still queued on the side lane would strand their futures
         # (connection handlers block forever) — resolve them fail-open
         # (round-3 review)
-        while True:
-            try:
-                _ts, request, _plan, fut = self._oversized_q.get_nowait()
-            except queue.Empty:
-                break
-            self.pipeline.stats.count_fail_open()
-            _safe_set(fut, _fail_open_verdict(request.request_id))
+        # (the scan stage's items carry their future last, the finish
+        # stage's third)
+        for side_q, fut_at in ((self._oversized_q, 3),
+                               (self._oversized_fin_q, 2)):
+            while True:
+                try:
+                    item = side_q.get_nowait()
+                except queue.Empty:
+                    break
+                self.pipeline.stats.count_fail_open()
+                _safe_set(item[fut_at],
+                          _fail_open_verdict(item[1].request_id))
 
     # ------------------------------------------------------------ loop
 

@@ -99,6 +99,17 @@ METRIC_HELP = {
         "per-stage and per-sub-stage latency histogram (log2 us buckets)",
     "ipt_device_launches_total":
         "device programs enqueued by the scan dispatch",
+    "ipt_oversized_rerouted_total":
+        "requests rerouted to the oversized side lane, by kind (raw: "
+        "the body is over the last batched tier; unpack: it unpacks "
+        "past it)",
+    "ipt_oversized_bytes_total":
+        "body bytes, as they arrived, of the rerouted requests, by kind",
+    "ipt_stream_waves_total":
+        "stream-engine scan waves launched (one scan_bytes_jit program "
+        "each, 2048 bytes a row)",
+    "ipt_stream_wave_rows_total": "live rows in those waves",
+    "ipt_stream_wave_bytes_total": "bytes those rows carried",
     "ipt_device_memory_peak_bytes":
         "peak device memory in use, highest over local devices",
     "ipt_gc_pause_us_total":
@@ -505,6 +516,20 @@ class ServeLoop:
             "ipt_stream_chunks_total %d" % s.stream_chunks,
             "# TYPE ipt_stream_bytes_total counter",
             "ipt_stream_bytes_total %d" % s.stream_bytes,
+            "# TYPE ipt_oversized_rerouted_total counter",
+            *('ipt_oversized_rerouted_total{kind="%s"} %d' % kv
+              for kv in s.oversized_requests.items()),
+            "# TYPE ipt_oversized_bytes_total counter",
+            *('ipt_oversized_bytes_total{kind="%s"} %d' % kv
+              for kv in s.oversized_bytes.items()),
+            "# TYPE ipt_stream_waves_total counter",
+            "ipt_stream_waves_total %d" % self.batcher.stream_engine.waves,
+            "# TYPE ipt_stream_wave_rows_total counter",
+            "ipt_stream_wave_rows_total %d"
+            % self.batcher.stream_engine.wave_rows,
+            "# TYPE ipt_stream_wave_bytes_total counter",
+            "ipt_stream_wave_bytes_total %d"
+            % self.batcher.stream_engine.wave_bytes,
             "# TYPE ipt_scan_rows_total counter",
             "ipt_scan_rows_total %d" % p.rows,
             "# TYPE ipt_scan_bytes_total counter",
@@ -762,7 +787,8 @@ class ServeLoop:
         if flight.enabled:
             # sub-stages (flight.span accumulators): inside scan and
             # confirm or beside the cycle, never part of a stage sum
-            for stage, hist in self.batcher.subhist.items():
+            for stage, hist in (*self.batcher.subhist.items(),
+                                *self.batcher.sidehist.items()):
                 lines += hist.prometheus("ipt_stage_us", {"stage": stage})
         lines += [
             "# TYPE ipt_device_launches_total counter",
@@ -1694,6 +1720,13 @@ def build_default_batcher(mode: str = "block", rules_dir: Optional[str] = None,
         batcher.warm_lanes()
         print("warmup: %d-lane serve shapes in %.1fs"
               % (n_lanes, _t.time() - t0), file=sys.stderr)
+    if warmup:
+        # the stream engine's wave programs (the oversized side lane,
+        # wire streams): the batched grid above does not hold them
+        t0 = time.time()
+        n = batcher.stream_engine.warm()
+        print("stream warmup: %d wave shapes in %.1fs"
+              % (n, time.time() - t0), file=sys.stderr)
     # guarded-rollout controller: idle until an admit; makes STAGED the
     # default semantics of /configuration/ruleset on this server
     cfg = RolloutConfig(fail_on=rollout_fail_on, lkg_dir=lkg_dir)

@@ -19,8 +19,9 @@ Pieces:
   the next chunk completes it.  Guaranteed: concat(feed*, flush) ==
   variant_chain(concat(chunks)) — the equivalence test's contract.
 - ``StreamState`` — per-request carry: per-variant (match, state) word
-  vectors + decoder tails + the capped raw body kept for the CPU confirm
-  stage.
+  vectors + decoder tails + what the CPU confirm stage walks: the capped
+  raw body of a wire stream, or the request itself where the caller
+  holds it whole (the batcher's oversized side lane).
 - ``StreamEngine`` — batches chunk scans across concurrent streams into
   fixed-shape ``scan_bytes_jit`` dispatches (CHUNK_L-wide waves, pow2 row
   padding: few executables, any chunk size), and at stream end folds the
@@ -38,6 +39,7 @@ from __future__ import annotations
 
 import re
 import time
+from contextlib import nullcontext
 from dataclasses import replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -151,8 +153,13 @@ class StreamState:
                  variants: Sequence[Tuple[int, int, int]],
                  n_words: int, version: str, body_cap: int,
                  scan_cap: int = DEFAULT_SCAN_CAP,
-                 pb_kind: Optional[str] = None):
+                 pb_kind: Optional[str] = None,
+                 confirm_request: Optional[Request] = None):
         self.request = request          # body stays b"" (scanned separately)
+        # the request the confirm stage walks, where the caller holds it
+        # whole: what is fed is then only what is SCANNED (it may be an
+        # unpacked copy) and no raw body is accumulated
+        self.confirm_request = confirm_request
         # [(variant_id, sv_id, src)] — src 0 scans the (inflated) body,
         # src 1 scans its incremental base64 decode (same sv ids: decoded
         # base64 is just another normalization of the body stream)
@@ -229,11 +236,12 @@ class StreamState:
         """Raw chunk → per-variant scan increments."""
         self.chunks += 1
         self.body_len += len(data)
-        room = self.body_cap - len(self.acc)
-        if room > 0:
-            self.acc += data[:room]
-        if len(data) > max(room, 0):
-            self.truncated = True
+        if self.confirm_request is None:
+            room = self.body_cap - len(self.acc)
+            if room > 0:
+                self.acc += data[:room]
+            if len(data) > max(room, 0):
+                self.truncated = True
         base = self._unpack(data)
         scan_room = self.scan_cap - self.scanned_len
         if scan_room <= 0:
@@ -291,21 +299,67 @@ class StreamState:
 
 
 class StreamEngine:
-    """Chunk-batch scanner + stream finisher, driven by the batcher's
-    dispatch thread under its swap lock."""
+    """Chunk-batch scanner + stream finisher.  Two callers, both under
+    the batcher's swap lock: its dispatch thread (wire streams: holds
+    the lock around the whole step) and its oversized side worker, which
+    passes ``hold`` and takes the lock once a wave, for the generation
+    check and the counters (the launch itself runs outside it).
+
+    What is scanned and what is confirmed: ``scan`` sees exactly the
+    bytes that were fed (through the incremental unpack and variant
+    chain); ``finish`` confirms on ``StreamState.confirm_request`` where
+    the caller gave one, else on the request with the accumulated raw
+    body in place.  The side lane feeds the batched path's own scan
+    stream (``unpack_body`` of the whole body, scan-only segments
+    included) and confirms on the request as it arrived, so both of its
+    stages see what the batched path's stages see."""
+
+    #: row tiers ``warm`` compiles, powers of two from 8: a wire
+    #: stream's rows are its needed body variants times up to three
+    #: sources (body, base64, gRPC); a side-lane body's are its variants
+    #: times its pieces and their seams (``Batcher.SIDE_PIECES``)
+    WARM_MAX_ROWS = 64
 
     def __init__(self, pipeline: DetectionPipeline,
                  body_cap: int = DEFAULT_BODY_CAP):
         self.pipeline = pipeline
         self.body_cap = body_cap
+        # scan waves launched, the live rows in them and the bytes
+        # those rows carried (/metrics ipt_stream_wave*_total); bumped
+        # under the swap lock by either caller
+        self.waves = 0
+        self.wave_rows = 0
+        self.wave_bytes = 0
+        self.warmed = False
+
+    def warm(self) -> int:
+        """Compile every ``scan_bytes_jit`` shape one stream's scan can
+        launch (rows 8 .. ``WARM_MAX_ROWS`` by CHUNK_L), so the first
+        oversized request pays no compile.  Returns the shapes made."""
+        tables = self.pipeline.engine.tables.scan
+        W = self.pipeline.ruleset.tables.n_words
+        n, B = 0, 8
+        while B <= self.WARM_MAX_ROWS:
+            tokens, lengths = pad_rows([b""] * B, max_len=CHUNK_L,
+                                       round_to=CHUNK_L)
+            zeros = np.zeros((B, W), np.uint32)
+            # as ``scan`` calls it: the first wave's carry comes from
+            # the host, every later one from the wave before
+            match, state = scan_bytes_jit(tables, tokens, lengths, zeros,
+                                          zeros)
+            np.asarray(scan_bytes_jit(tables, tokens, lengths, state,
+                                      match)[0])
+            n, B = n + 1, B * 2
+        self.warmed = True
+        return n
 
     # -------------------------------------------------------- lifecycle
 
     def begin(self, request: Request,
-              body_cap: Optional[int] = None) -> StreamState:
-        """``body_cap`` overrides the confirm-buffer bound — the batcher's
-        oversized-reroute path already holds the full body in memory, so
-        capping the confirm copy below it would only lose the tail."""
+              confirm_request: Optional[Request] = None) -> StreamState:
+        """``confirm_request``: the caller holds the whole request (the
+        batcher's oversized reroute) — ``finish`` confirms on it, and
+        what is fed is only scanned, never accumulated."""
         p = self.pipeline
         si = STREAM_INDEX[getattr(request, "body_stream", "body")]
         base = [(v, si * len(VARIANTS) + v, 0) for v in range(len(VARIANTS))
@@ -324,15 +378,23 @@ class StreamEngine:
             variants += [(v, sv, 2) for v, sv, _ in base]
         return StreamState(request, variants, p.ruleset.tables.n_words,
                            p.ruleset.version,
-                           body_cap if body_cap is not None
-                           else self.body_cap, pb_kind=pb_kind)
+                           self.body_cap, pb_kind=pb_kind,
+                           confirm_request=confirm_request)
 
     # ------------------------------------------------------------ scan
 
-    def scan(self, items: List[Tuple[StreamState, int, bytes]]) -> None:
+    def scan(self, items: List[Tuple[StreamState, int, bytes]],
+             hold=None) -> int:
         """Scan increments for many (stream, variant) rows, batched into
         CHUNK_L-wide waves.  Items for the same (stream, variant) are
-        concatenated in arrival order (state carry makes that exact)."""
+        concatenated in arrival order (state carry makes that exact).
+        ``hold``: a context-manager factory entered once a wave (the
+        side worker's hold of the swap lock: the version check, the
+        tables of that generation and the counters; the launch follows
+        outside it, and nothing waits for the device before the last
+        wave); None where the caller holds the lock already.  Returns
+        the waves launched."""
+        hold = hold or nullcontext
         merged: Dict[Tuple[int, int], List] = {}
         for st, vi, data in items:
             if st.aborted or st.error:
@@ -346,7 +408,7 @@ class StreamEngine:
                 data)
         all_rows = list(merged.values())
         if not all_rows:
-            return
+            return 0
         # Dedup identical scan work — the streaming twin of merge_rows'
         # one-shot row dedup: rows whose (state, match, pending bytes) are
         # byte-identical produce identical results (pure recurrence), so
@@ -361,51 +423,80 @@ class StreamEngine:
             groups.setdefault(key, []).append(r)
         rows = [g[0] for g in groups.values()]
         followers = {id(g[0]): g[1:] for g in groups.values()}
-        tables = self.pipeline.engine.tables.scan
-        offs = [0] * len(rows)
-        while True:
-            wave = [(i, r) for i, r in enumerate(rows)
-                    if offs[i] < len(r[2])]
-            if not wave:
-                break
-            chunks = []
-            for i, r in wave:
-                seg = bytes(r[2][offs[i] : offs[i] + CHUNK_L])
-                offs[i] += len(seg)
-                chunks.append(seg)
-            B = 8
-            while B < len(wave):
-                B *= 2
+        # one row set for the whole call: a row that runs out of bytes
+        # rides on with length 0 (padded steps are the identity on
+        # state and match), so the carry stays ON THE DEVICE from wave
+        # to wave and comes back to the host once, after the last one;
+        # nothing in between waits for the device
+        B = 8
+        while B < len(rows):
+            B *= 2
+        W = rows[0][0].state.shape[1]
+        state = np.zeros((B, W), np.uint32)
+        match = np.zeros_like(state)
+        for j, (st, vi, _data) in enumerate(rows):
+            state[j] = st.state[vi]
+            match[j] = st.match[vi]
+        waves = 0
+        for off in range(0, max(len(r[2]) for r in rows), CHUNK_L):
+            chunks = [bytes(r[2][off:off + CHUNK_L]) for r in rows]
             tokens, lengths = pad_rows(
-                chunks + [b""] * (B - len(wave)),
+                chunks + [b""] * (B - len(rows)),
                 max_len=CHUNK_L, round_to=CHUNK_L)
-            W = wave[0][1][0].state.shape[1]
-            state = np.zeros((B, W), np.uint32)
-            match = np.zeros_like(state)
-            for j, (i, r) in enumerate(wave):
-                st, vi = r[0], r[1]
-                state[j] = st.state[vi]
-                match[j] = st.match[vi]
-            m_out, s_out = scan_bytes_jit(tables, tokens, lengths,
-                                          state, match)
-            m_out = np.asarray(m_out)
-            s_out = np.asarray(s_out)
-            for j, (i, r) in enumerate(wave):
-                for st, vi, _ in (r, *followers[id(r)]):
-                    st.state[vi] = s_out[j]
-                    st.match[vi] = m_out[j]
+            with hold():
+                p = self.pipeline
+                if any(r[0].version != p.ruleset.version for r in rows):
+                    # swapped since this scan began: only a caller that
+                    # takes the lock per wave can see it, and its rows
+                    # are one stream's
+                    for r in rows:
+                        for st, _vi, _ in (r, *followers[id(r)]):
+                            st.error = True
+                    return waves
+                tables = p.engine.tables.scan
+                self.waves += 1
+                self.wave_rows += sum(1 for c in chunks if c)
+                self.wave_bytes += sum(len(c) for c in chunks)
+            # launched outside the hold: the program runs on the tables
+            # of the generation just checked whatever is installed while
+            # it does, and on this machine the call into JAX returns
+            # only after milliseconds, which a batched cycle must not
+            # wait out
+            match, state = scan_bytes_jit(tables, tokens, lengths, state,
+                                          match)
+            waves += 1
+        m_out = np.asarray(match)
+        s_out = np.asarray(state)
+        for j, r in enumerate(rows):
+            for st, vi, _ in (r, *followers[id(r)]):
+                st.state[vi] = s_out[j]
+                st.match[vi] = m_out[j]
+        return waves
 
     # ---------------------------------------------------------- finish
 
-    def finish(self, st: StreamState) -> Verdict:
+    def _failed_open(self, st: StreamState) -> Verdict:
+        """A stream that errored, or whose ruleset was swapped under it."""
+        self.pipeline.stats.count_fail_open()
+        return Verdict(request_id=st.request.request_id, blocked=False,
+                       attack=False, classes=[], rule_ids=[], score=0,
+                       fail_open=True, elapsed_us=int(
+                           (time.perf_counter() - st.t0) * 1e6))
+
+    def finish(self, st: StreamState, hold=None,
+               lone_to_walker: bool = False) -> Verdict:
+        """Fold the stream's match words into rule hits and confirm.
+        ``hold`` as in :meth:`scan`: given, the confirm walk runs
+        OUTSIDE the lock and only the mask and the single-threaded fold
+        take it.  ``lone_to_walker``: the walk may go to a walker
+        process though it is a batch of one (a body of tens of KB walks
+        for tens of ms, on the interpreter lock the dispatch thread
+        needs)."""
+        hold = hold or nullcontext
         p = self.pipeline
         req = st.request
         if st.error or st.version != p.ruleset.version:
-            p.stats.count_fail_open()
-            return Verdict(request_id=req.request_id, blocked=False,
-                           attack=False, classes=[], rule_ids=[], score=0,
-                           fail_open=True, elapsed_us=int(
-                               (time.perf_counter() - st.t0) * 1e6))
+            return self._failed_open(st)
         cr = p.ruleset
         bt = cr.tables
         R = cr.n_rules
@@ -423,20 +514,33 @@ class StreamEngine:
         hits = body_hits
         if st.base_hits is not None:
             hits = hits | st.base_hits
-        hits = p.mask_hits([req], hits[None])
-
-        # confirm runs on the accumulated (capped) raw body
-        # parsers_off must carry over: the confirm stage re-unpacks the
-        # accumulated body and must not run a decoder the scan stage had
-        # disabled (the "both stages see identical bytes" contract)
+        # the confirm twin: the request as the caller holds it, else
+        # the accumulated (capped) raw body in the stream's request.
+        # parsers_off carries over either way: the confirm stage
+        # re-unpacks the body and must not run a decoder the scan stage
+        # had disabled (the "both stages see identical bytes" contract).
         # dataclasses.replace keeps every other field AND the concrete
         # type (a Response reroutes through here too — its confirm twin
         # must stay a Response so resp_* streams rebuild)
-        confirm_req = replace(req, body=bytes(st.acc))
-        v = p.finalize([confirm_req], hits, st.t0)[0]
+        confirm_req = (st.confirm_request if st.confirm_request is not None
+                       else replace(req, body=bytes(st.acc)))
+        with hold():
+            if st.version != p.ruleset.version:
+                return self._failed_open(st)
+            hits = p.mask_hits([req], hits[None])
+        cjob = p.finalize_launch([confirm_req], hits,
+                                 lone_to_walker=lone_to_walker)
+        # the walker's answer is waited for OUTSIDE the hold (the join
+        # below then finds it there, or fails the share open past the
+        # pool's hang budget, which runs from the launch)
+        for _worker, _idxs, _t_out, share in cjob.pending:
+            if share is not None:
+                share.wait_done(p.confirm_pool.hang_budget_s)
+        with hold():
+            v = p.finalize_join(cjob, st.t0)[0]
+            p.stats.requests += 1
         # scan/confirm caps were hit: the verdict is based on a prefix —
         # surface it the fail-open way (pass-and-flag, never silently)
         if st.truncated and not v.attack:
             v.fail_open = True
-        p.stats.requests += 1
         return v

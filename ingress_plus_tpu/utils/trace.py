@@ -775,6 +775,10 @@ EV_LANE_CALL = 25  # dispatch thread blocked in a lane call, tag=lane
 EV_LANE_SCAN = 26  # one lane share's scan, submit → result on the host, tag=lane
 EV_SCAN_WALL = 27  # a mesh cycle's scan: first share's submit → last result
 EV_CONFIRM_IPC = 28  # a confirm share's hop: send → answer less the walker's walk, tag=worker
+EV_SIDE_WAIT = 29  # side lane: submit → the worker takes the request (back-dated)
+EV_SIDE_SCAN = 30  # side lane: begin → last wave and flush, the head's prefilter inside, arg=body bytes
+EV_SIDE_CONFIRM = 31  # side lane: finish's confirm walk and fold
+EV_SIDE_LOCK = 32  # side lane: one hold of the batcher's swap lock
 
 EVENT_NAMES: Dict[int, str] = {
     EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
@@ -789,6 +793,8 @@ EVENT_NAMES: Dict[int, str] = {
     EV_HANDOFF: "lane_handoff", EV_REPLY: "reply", EV_GC: "gc",
     EV_LANE_CALL: "lane_call", EV_LANE_SCAN: "lane_scan",
     EV_SCAN_WALL: "scan_wall", EV_CONFIRM_IPC: "confirm_ipc",
+    EV_SIDE_WAIT: "side_wait", EV_SIDE_SCAN: "side_scan",
+    EV_SIDE_CONFIRM: "side_confirm", EV_SIDE_LOCK: "side_lock",
 }
 
 #: span codes whose elapsed µs accumulate per cycle id for the batcher's
@@ -823,6 +829,17 @@ PER_DISPATCH: Tuple[str, ...] = tuple(ACCUMULATED.values()) + ("drain_idle",)
 #: its _sum over a window is the loop's idle time inside it); reply =
 #: verdict resolved → reply frame written, per request (event loop).
 SUBSTAGES: Tuple[str, ...] = PER_DISPATCH + ("reply",)
+
+#: the oversized side lane's stages (serve/batcher.py), observed once
+#: per REROUTED request on the side worker, rendered under the same
+#: ``ipt_stage_us{stage=}`` family, kept out of SUBSTAGES because no
+#: batched dispatch observes them: side_wait = submit → the worker takes
+#: the request; side_scan = stream begin → last wave and flush, the
+#: head's prefilter inside; side_confirm = finish's confirm walk and
+#: fold; side_lock = the request's holds of the swap lock, summed.
+SIDE_STAGES: Tuple[str, ...] = tuple(
+    EVENT_NAMES[c] for c in (EV_SIDE_WAIT, EV_SIDE_SCAN, EV_SIDE_CONFIRM,
+                             EV_SIDE_LOCK))
 
 #: the profiler-trace names, built once (``ipt:<name>``)
 _ANNOTATION_NAMES: Dict[int, str] = {
