@@ -221,6 +221,6 @@ def test_rehearsal_of_the_cell():
     assert set(v) == want
     assert v["dispatch.compiles_in_window"] == 0
     assert 0 < v["sidelane.request_share"] <= 100
-    assert v["sidelane.waves_per_request"] >= 2
+    assert v["sidelane.waves_per_request"] >= 8
     assert v["sidelane.scan_ms"] > 0 and v["sidelane.confirm_ms"] > 0
     assert 0 < v["sidelane.lock_hold_share"] <= 100

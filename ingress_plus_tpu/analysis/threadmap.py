@@ -143,7 +143,7 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
         entries=("serve/batcher.py::Batcher._run_oversized_finish",),
         concurrent=False,
         description="ipt-oversized-finish: confirms a scanned oversized "
-                    "body (the walk out on a walker process) and "
+                    "body (a batch of one: the walk inline) and "
                     "resolves its verdict while the side worker scans "
                     "the next one"),
     ThreadRoot(

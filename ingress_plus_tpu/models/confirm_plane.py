@@ -718,25 +718,21 @@ class ConfirmPool:
                 pass    # failed_at is stamped: respawned at a later call
         return ready, posted
 
-    def deal(self, pl, n: int,
-             lone_to_walker: bool = False) -> List[_ConfirmWorker]:
+    def deal(self, pl, n: int) -> List[_ConfirmWorker]:
         """The workers a batch of ``n`` requests of ``pl`` goes out to,
         a share each (request ``i`` to share ``i % len``): no worker
         gets more than ``ceil(n / N)`` and no more workers are used
         than that takes.  Empty: the caller walks inline — an inline
         pool, a batch of one (nothing to spread, and the hop would cost
         as much as the walk), or fewer than two walkers holding the
-        generation yet.  ``lone_to_walker``: a batch of one whose walk
-        is long (the oversized side lane's) goes out all the same, to
-        the LAST ready worker: batches are dealt from the first, so a
-        long walk keeps out of a small batch's way."""
-        if not self._workers or n < (1 if lone_to_walker else 2):
+        generation yet."""
+        if not self._workers or n < 2:
             return []
         ready, _posted = self._post_installs(pl)
         if len(ready) < 2:
             return []
         per = -(-n // len(ready))
-        dealt = ready[:-(-n // per)] if n > 1 else ready[-1:]
+        dealt = ready[:-(-n // per)]
         now = next(self._clock)
         for w in dealt:
             # concheck: ok GIL-atomic dict store; the LRU stamp
@@ -834,12 +830,10 @@ def _walk_inline(pl, job: ConfirmJob, tt: bool, trace_cycle: int) -> None:
     flight.span_at(EV_CONFIRM_IPC, now, now, cycle=trace_cycle)
 
 
-def launch_confirm(pl, requests, rule_hits: np.ndarray,
-                   lone_to_walker: bool = False) -> ConfirmJob:
+def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
     """Start one finalize batch's confirm phase.  Inline (an inline
-    pool, or a batch of one unless ``lone_to_walker``): the whole walk
-    runs NOW on the calling thread (the classic serial path).  Pooled:
-    each share is put on its
+    pool, or a batch of one): the whole walk runs NOW on the calling
+    thread (the classic serial path).  Pooled: each share is put on its
     worker's pipe from this thread, its walker process walks it, its
     waiter thread blocks on the answer, and the call returns
     immediately — the batcher's loop overlaps the in-flight
@@ -862,7 +856,7 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray,
     # scan still stitches to the cycle whose verdicts it computes
     trace_cycle = job.cycle = flight.cycle()
     trace_lane = flight.lane()
-    workers = pool.deal(pl, n, lone_to_walker)
+    workers = pool.deal(pl, n)
     k = len(workers)
     job.share_workers = [w.worker_index for w in workers]
     if k == 0:

@@ -1237,18 +1237,14 @@ class DetectionPipeline:
         return rule_hits & self.paranoia_mask[None, :]
 
     def finalize_launch(self, requests: List[Request],
-                        rule_hits: np.ndarray,
-                        lone_to_walker: bool = False):
+                        rule_hits: np.ndarray):
         """Start the confirm phase for one batch of already-masked
         prefilter hits (docs/CONFIRM_PLANE.md): the per-request
         candidate walks run on the confirm pool — inline (the classic
         serial path) at ``--confirm-workers 1`` and for a batch of one,
         as request shares in the workers' walker processes otherwise.
-        ``lone_to_walker``: a batch of one goes to a walker too (the
-        oversized side lane's: its one body walks for tens of ms).
         Returns the job for :meth:`finalize_join`."""
-        return launch_confirm(self, requests, rule_hits,
-                              lone_to_walker=lone_to_walker)
+        return launch_confirm(self, requests, rule_hits)
 
     def finalize(self, requests: List[Request], rule_hits: np.ndarray,
                  t0: float, observe_rules: bool = True) -> List[Verdict]:

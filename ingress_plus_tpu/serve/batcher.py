@@ -480,13 +480,6 @@ class Batcher:
     # buffered body the same way†)
     OVERSIZE_THRESHOLD = DetectionPipeline.L_BUCKETS[-1]
     OVERSIZE_CHUNK = 64 << 10
-    #: a side-lane scan stream is scanned as this many pieces side by
-    #: side (``_side_parts``): a quarter of the sequential device steps
-    SIDE_PIECES = 4
-    SIDE_PIECE_MIN = 4096
-    #: bytes either side of a cut that its seam part holds: over 32
-    #: (the longest factor) x 9 (the longest escape of one byte)
-    SIDE_SEAM = 512
     #: the loop's waits while a cycle is in flight last one batch
     #: window, and no less than this (a window of zero must not spin)
     MIN_SLICE_S = 0.0002
@@ -627,7 +620,7 @@ class Batcher:
         self._oversized_by_tenant: Dict[int, int] = {}
         self._oversized_lock = named_lock("Batcher._oversized_lock")
         # two stages, a thread each, so that one request's confirm walk
-        # (out on a walker process) overlaps the next one's scan; a
+        # overlaps the next one's waves on the device; a
         # request holds its slot through both, so the finish queue is
         # bounded by the slots
         self._oversized_fin_q: "queue.Queue" = queue.Queue()
@@ -885,25 +878,6 @@ class Batcher:
             finally:
                 self._release_oversized_slot(item[1].tenant)
 
-    @classmethod
-    def _side_parts(cls, scanned: bytes) -> List[bytes]:
-        """A side-lane scan stream cut into up to ``SIDE_PIECES`` pieces
-        of at least ``SIDE_PIECE_MIN`` bytes, and a seam around each cut
-        (``SIDE_SEAM`` bytes either side).  Every factor occurrence of
-        the whole stream, in any variant, is one of some part: a factor
-        is at most 32 bytes of a variant's output, a byte of output
-        comes from at most 9 of input (``&entity;``), so an occurrence
-        that lies across a cut lies inside its seam, where it decodes as
-        in the whole; any other lies inside one piece.  What a part's
-        own edges add (an escape cut in half decodes otherwise) are
-        candidates the whole stream has not: the confirm stage, which
-        walks the request itself, decides as ever."""
-        n = min(cls.SIDE_PIECES, max(1, len(scanned) // cls.SIDE_PIECE_MIN))
-        cuts = [len(scanned) * k // n for k in range(n + 1)]
-        return ([scanned[a:b] for a, b in zip(cuts, cuts[1:])]
-                + [scanned[c - cls.SIDE_SEAM:c + cls.SIDE_SEAM]
-                   for c in cuts[1:-1]])
-
     @contextmanager
     def _side_hold(self, held: List[int]):
         """The swap lock as the side lane takes it: one hold, its
@@ -925,11 +899,13 @@ class Batcher:
         body, ``unpack_body(body, headers, parsers_off)`` with its
         scan-only segments (inflated base, url-decoded form copy,
         extracted JSON / XML strings, base64 decode), for BOTH reroute
-        kinds, cut into pieces and seams (``_side_parts``) that are fed
-        side by side through the stream's incremental variant chain in
-        CHUNK_L waves with the automaton state carried between them:
-        the candidates hold every one the batched prefilter would have
-        found had its rows no 16 KiB bound.  What is confirmed
+        kinds, fed as ONE sequence through the stream's incremental
+        variant chain in CHUNK_L waves with the automaton state carried
+        from each wave to the next (on the device inside a chunk of
+        ``OVERSIZE_CHUNK`` bytes, through the host between chunks): the
+        candidates are the ones the batched prefilter would have found
+        had its rows no 16 KiB bound, however many bytes a variant
+        deletes between two halves of a factor.  What is confirmed
         (``_finish_oversized``): the request as it arrived
         (``confirm_request``), which the confirm twin unpacks once
         itself — never the unpacked copy, whose url-decoded segment it
@@ -938,9 +914,9 @@ class Batcher:
         The swap lock is taken once a wave for the generation check
         (and for the head's prefilter and the verdict's fold), never for
         a chunk, a wave's launch or device time, or the confirm walk: a
-        batched cycle never waits out a wave.  The unpack runs off-lock.  A ruleset hot-swap
-        mid-body is detected by the stream engine's version check and
-        fails open, same as in-flight wire streams."""
+        batched cycle never waits out a wave.  The unpack runs off-lock.
+        A ruleset hot-swap mid-body is detected by the stream engine's
+        version check and fails open, same as in-flight wire streams."""
         kind, body, headers = plan
         t_taken = time.perf_counter()
         st = self.stats
@@ -974,27 +950,13 @@ class Batcher:
                 meta = replace(request, body=b"",
                                parsers_off=frozenset(request.parsers_off)
                                | _STREAM_UNPACKERS)
-                parts = self._side_parts(scanned)
                 with hold():
-                    handles = [engine.begin(meta, confirm_request=request)
-                               for _ in parts]
-                    h = handles[0]
+                    h = engine.begin(meta, confirm_request=request)
                     h.base_hits = self.pipeline.prefilter([meta])[0]
-                # the parts are scanned side by side, as rows of the
-                # same waves: the sequential steps a body costs are its
-                # longest part's, not its length's
-                for i in range(0, max(map(len, parts)), self.OVERSIZE_CHUNK):
-                    engine.scan(
-                        [item for hp, part in zip(handles, parts)
-                         for item in hp.feed(
-                             part[i:i + self.OVERSIZE_CHUNK])],
-                        hold=hold)
-                engine.scan([item for hp in handles for item in hp.flush()],
-                            hold=hold)
-                for hp in handles[1:]:
-                    h.match |= hp.match
-                    h.truncated |= hp.truncated
-                    h.error |= hp.error
+                for i in range(0, len(scanned), self.OVERSIZE_CHUNK):
+                    engine.scan(h.feed(scanned[i:i + self.OVERSIZE_CHUNK]),
+                                hold=hold)
+                engine.scan(h.flush(), hold=hold)
             self.sidehist["side_scan"].observe(sp_scan.us)
         except Exception:
             self._resolve_oversized(ts, request, fut, None)
@@ -1006,13 +968,13 @@ class Batcher:
                           fut: "Future[Verdict]", h: StreamState,
                           held: List[int]) -> None:
         """Confirm one scanned request and resolve it (the side lane's
-        finish thread): the walk goes to a walker process where the
-        pool has them, this thread waits for its answer."""
+        finish thread): a batch of one, so the walk runs inline on this
+        thread, outside the swap lock, while the scan thread scans the
+        next request."""
         try:
             with flight.span(EV_SIDE_CONFIRM) as sp_confirm:
                 v = self.stream_engine.finish(
-                    h, hold=functools.partial(self._side_hold, held),
-                    lone_to_walker=True)
+                    h, hold=functools.partial(self._side_hold, held))
             self.sidehist["side_confirm"].observe(sp_confirm.us)
             self.sidehist["side_lock"].observe(held[0])
         except Exception:

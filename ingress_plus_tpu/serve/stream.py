@@ -314,11 +314,11 @@ class StreamEngine:
     included) and confirms on the request as it arrived, so both of its
     stages see what the batched path's stages see."""
 
-    #: row tiers ``warm`` compiles, powers of two from 8: a wire
-    #: stream's rows are its needed body variants times up to three
-    #: sources (body, base64, gRPC); a side-lane body's are its variants
-    #: times its pieces and their seams (``Batcher.SIDE_PIECES``)
-    WARM_MAX_ROWS = 64
+    #: row tiers ``warm`` compiles, powers of two from 8: a side-lane
+    #: body's rows are its needed body variants (five: 8 rows); a wire
+    #: stream's are those times up to three sources (body, base64,
+    #: gRPC: 16), and two wire streams may share a wave (32)
+    WARM_MAX_ROWS = 32
 
     def __init__(self, pipeline: DetectionPipeline,
                  body_cap: int = DEFAULT_BODY_CAP):
@@ -483,15 +483,11 @@ class StreamEngine:
                        fail_open=True, elapsed_us=int(
                            (time.perf_counter() - st.t0) * 1e6))
 
-    def finish(self, st: StreamState, hold=None,
-               lone_to_walker: bool = False) -> Verdict:
+    def finish(self, st: StreamState, hold=None) -> Verdict:
         """Fold the stream's match words into rule hits and confirm.
-        ``hold`` as in :meth:`scan`: given, the confirm walk runs
-        OUTSIDE the lock and only the mask and the single-threaded fold
-        take it.  ``lone_to_walker``: the walk may go to a walker
-        process though it is a batch of one (a body of tens of KB walks
-        for tens of ms, on the interpreter lock the dispatch thread
-        needs)."""
+        ``hold`` as in :meth:`scan`: given, the confirm walk (a batch of
+        one: inline, on the calling thread) runs OUTSIDE the lock and
+        only the mask and the single-threaded fold take it."""
         hold = hold or nullcontext
         p = self.pipeline
         req = st.request
@@ -528,14 +524,9 @@ class StreamEngine:
             if st.version != p.ruleset.version:
                 return self._failed_open(st)
             hits = p.mask_hits([req], hits[None])
-        cjob = p.finalize_launch([confirm_req], hits,
-                                 lone_to_walker=lone_to_walker)
-        # the walker's answer is waited for OUTSIDE the hold (the join
-        # below then finds it there, or fails the share open past the
-        # pool's hang budget, which runs from the launch)
-        for _worker, _idxs, _t_out, share in cjob.pending:
-            if share is not None:
-                share.wait_done(p.confirm_pool.hang_budget_s)
+        # the walk of a body of tens of KB lasts tens of ms: outside
+        # the hold, so a batched cycle never waits it out
+        cjob = p.finalize_launch([confirm_req], hits)
         with hold():
             v = p.finalize_join(cjob, st.t0)[0]
             p.stats.requests += 1

