@@ -143,7 +143,8 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
         entries=("serve/batcher.py::Batcher._run_oversized_finish",),
         concurrent=False,
         description="ipt-oversized-finish: confirms a scanned oversized "
-                    "body (a batch of one: the walk inline) and "
+                    "body (a batch of one, walked in a walker process "
+                    "where one holds the generation, else inline) and "
                     "resolves its verdict while the side worker scans "
                     "the next one"),
     ThreadRoot(

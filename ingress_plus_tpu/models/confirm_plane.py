@@ -605,8 +605,8 @@ class ConfirmJob:
     its walker died (the fold fails exactly those requests open)."""
 
     __slots__ = ("requests", "rule_hits", "results", "pending", "memo",
-                 "launch_us", "share_workers", "memo_hits", "memo_misses",
-                 "cycle", "t0")
+                 "launch_us", "join_us", "share_workers", "memo_hits",
+                 "memo_misses", "cycle", "t0")
 
     def __init__(self, requests, rule_hits) -> None:
         self.requests = requests
@@ -622,6 +622,8 @@ class ConfirmJob:
         #: reports the share's counts (``memo_hits``/``memo_misses``)
         self.memo: Optional[ConfirmMemo] = None
         self.launch_us = 0
+        #: µs spent in :func:`join_confirm` waiting for the shares
+        self.join_us = 0
         #: the worker index of each share dealt to a walker process
         #: (request qi went to share ``qi % len``); empty = walked
         #: inline by the caller
@@ -640,11 +642,17 @@ class ConfirmPool:
     waiter thread in front of a walker process
     (models/confirm_walker.py) and a batch of more than one request is
     dealt into shares of ``ceil(n / N)`` requests, one share a worker;
-    a batch of one is walked inline by the caller whatever N is, and so
-    is a batch that finds fewer than two workers holding its generation
-    (walkers still starting, a generation nobody installed ahead of its
-    traffic: the install goes out and a later batch finds it).  So a
-    share's hang budget covers a walk and nothing else.  The pool is
+    a batched dispatch of one is walked inline by the caller whatever N
+    is, and so is a batch that finds fewer than two workers holding its
+    generation (walkers still starting, a generation nobody installed
+    ahead of its traffic: the install goes out and a later batch finds
+    it).  The one caller that asks otherwise (``lone_to_walker``) is
+    the oversized side lane's finish thread: its lone request is a body
+    past 16 KiB unpacked, whose walk of tens of ms would hold the
+    interpreter lock beside the side lane's scan thread, so it goes to
+    one walker holding the generation and the caller blocks on the
+    pipe; with none such it is walked inline as well.  So a share's
+    hang budget covers a walk and nothing else.  The pool is
     ruleset-free — the batcher carries ONE pool across hot swaps like
     the stats object, installing each new generation in the walkers
     before it serves (:meth:`install`)."""
@@ -718,26 +726,44 @@ class ConfirmPool:
                 pass    # failed_at is stamped: respawned at a later call
         return ready, posted
 
-    def deal(self, pl, n: int) -> List[_ConfirmWorker]:
+    def deal(self, pl, n: int,
+             lone_to_walker: bool = False) -> List[_ConfirmWorker]:
         """The workers a batch of ``n`` requests of ``pl`` goes out to,
         a share each (request ``i`` to share ``i % len``): no worker
         gets more than ``ceil(n / N)`` and no more workers are used
         than that takes.  Empty: the caller walks inline — an inline
         pool, a batch of one (nothing to spread, and the hop would cost
-        as much as the walk), or fewer than two walkers holding the
-        generation yet."""
-        if not self._workers or n < 2:
+        as much as the walk of a batched request), or fewer than two
+        walkers holding the generation yet.  ``lone_to_walker``: a
+        batch of one goes to one walker that holds the generation, the
+        last of them (batched shares are dealt from the first), so that
+        a long walk leaves the caller's interpreter lock alone; the
+        side lane's finish asks it, and nothing else does."""
+        if not self._workers or n < (1 if lone_to_walker else 2):
             return []
         ready, _posted = self._post_installs(pl)
-        if len(ready) < 2:
+        if n == 1:
+            dealt = ready[-1:]
+        elif len(ready) < 2:
             return []
-        per = -(-n // len(ready))
-        dealt = ready[:-(-n // per)]
+        else:
+            per = -(-n // len(ready))
+            dealt = ready[:-(-n // per)]
         now = next(self._clock)
         for w in dealt:
             # concheck: ok GIL-atomic dict store; the LRU stamp
             w.held[pl.confirm_gen] = now
         return dealt
+
+    def count(self, n: int, process: bool) -> None:
+        """Book ``n`` requests walked in a walker process or inline.
+        Two threads deal (the dispatch thread and the side lane's
+        finish thread), so the counts take the pool's lock."""
+        with self._lock:
+            if process:
+                self.requests_process += n
+            else:
+                self.requests_inline += n
 
     def install(self, pl, wait_s: float = 0.0) -> None:
         """Install ``pl``'s generation in every walker ahead of its
@@ -830,7 +856,8 @@ def _walk_inline(pl, job: ConfirmJob, tt: bool, trace_cycle: int) -> None:
     flight.span_at(EV_CONFIRM_IPC, now, now, cycle=trace_cycle)
 
 
-def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
+def launch_confirm(pl, requests, rule_hits: np.ndarray,
+                   lone_to_walker: bool = False) -> ConfirmJob:
     """Start one finalize batch's confirm phase.  Inline (an inline
     pool, or a batch of one): the whole walk runs NOW on the calling
     thread (the classic serial path).  Pooled: each share is put on its
@@ -838,7 +865,13 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
     waiter thread blocks on the answer, and the call returns
     immediately — the batcher's loop overlaps the in-flight
     confirm with the next cycle's scan dispatch, the same software-
-    pipelining move PR 7 made for host→device transfer."""
+    pipelining move PR 7 made for host→device transfer.
+    ``lone_to_walker`` (:meth:`ConfirmPool.deal`): a batch of one goes
+    to a walker too — the side lane's finish, whose one body's walk
+    must not hold the interpreter lock its scan thread needs.  Its spans
+    carry the caller's ambient cycle, which on that thread is never a
+    batched cycle's, so ``confirm_walk`` / ``confirm_ipc`` stay the
+    batched dispatches' alone."""
     job = ConfirmJob(requests, rule_hits)
     pool = pl.confirm_pool
     n = len(requests)
@@ -856,15 +889,15 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray) -> ConfirmJob:
     # scan still stitches to the cycle whose verdicts it computes
     trace_cycle = job.cycle = flight.cycle()
     trace_lane = flight.lane()
-    workers = pool.deal(pl, n)
+    workers = pool.deal(pl, n, lone_to_walker)
     k = len(workers)
     job.share_workers = [w.worker_index for w in workers]
     if k == 0:
-        pool.requests_inline += n  # concheck: ok telemetry-grade counter race
+        pool.count(n, process=False)
         _walk_inline(pl, job, tt, trace_cycle)
         job.launch_us = int((time.perf_counter() - t0) * 1e6)
         return job
-    pool.requests_process += n  # concheck: ok telemetry-grade counter race
+    pool.count(n, process=True)
     memo_cap = getattr(pl, "confirm_memo_entries", 0)
     cache = getattr(pl, "confirm_cache", None)
     cache_cap = cache.cap if cache is not None else 0
@@ -932,14 +965,19 @@ def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
     A share whose walker died under it: the same, less the hang count.
     A share that RAISED re-raises after every other share is folded —
     the batch-level error contract of the serial path, with the healthy
-    shares' work not discarded by ordering."""
+    shares' work not discarded by ordering.  The shares are taken once:
+    a caller that must wait outside a lock and fold inside it
+    (``StreamEngine.finish``) joins first, and the fold's own join
+    finds nothing left; the wait is kept in ``job.join_us``."""
     if not job.pending:
         return job.results
+    t0 = time.perf_counter()
     pool = pl.confirm_pool
     deadline = job.t0 + pool.hang_budget_s
     err: Optional[BaseException] = None
     confirms = pl.confirms
-    for worker, idxs, t_out, pending in job.pending:
+    pending_shares, job.pending = job.pending, []
+    for worker, idxs, t_out, pending in pending_shares:
         try:
             if pending is None:
                 raise WalkerDied("confirm walker %d was gone at the send"
@@ -973,6 +1011,7 @@ def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
             cr = confirms[r]
             cr.qr_skips += skips
             cr.qr_evals += evals
+    job.join_us += int((time.perf_counter() - t0) * 1e6)
     if err is not None:
         raise err
     return job.results

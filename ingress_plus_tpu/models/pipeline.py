@@ -1237,14 +1237,17 @@ class DetectionPipeline:
         return rule_hits & self.paranoia_mask[None, :]
 
     def finalize_launch(self, requests: List[Request],
-                        rule_hits: np.ndarray):
+                        rule_hits: np.ndarray,
+                        lone_to_walker: bool = False):
         """Start the confirm phase for one batch of already-masked
         prefilter hits (docs/CONFIRM_PLANE.md): the per-request
         candidate walks run on the confirm pool — inline (the classic
         serial path) at ``--confirm-workers 1`` and for a batch of one,
         as request shares in the workers' walker processes otherwise.
+        ``lone_to_walker``: a batch of one goes to a walker too (the
+        oversized side lane's finish, ``StreamEngine.finish``).
         Returns the job for :meth:`finalize_join`."""
-        return launch_confirm(self, requests, rule_hits)
+        return launch_confirm(self, requests, rule_hits, lone_to_walker)
 
     def finalize(self, requests: List[Request], rule_hits: np.ndarray,
                  t0: float, observe_rules: bool = True) -> List[Verdict]:
@@ -1264,18 +1267,21 @@ class DetectionPipeline:
         everything else is the pre-pool serial finalize, verdict for
         verdict."""
         stats = self.stats
+        # a join the caller made ahead of this fold (StreamEngine.finish
+        # waits for its walker outside the swap lock)
+        joined_us = cjob.join_us
         tc0 = time.perf_counter()
         with flight.span(EV_FINALIZE, arg=len(cjob.requests)):
             results = join_confirm(self, cjob)
             with flight.span(EV_CONFIRM_FOLD, arg=len(cjob.requests)):
                 verdicts = self._fold_verdicts(cjob, results,
                                                observe_rules)
-        # confirm stage wall = launch window + this join (share waits +
-        # fold).  On the overlapped mesh path the wall BETWEEN launch
+        # confirm stage wall = launch window + the join (share waits) +
+        # fold.  On the overlapped mesh path the wall BETWEEN launch
         # and join is the double buffer's window, not confirm cost —
         # excluded by construction; the per-rule confirm_ns telemetry
         # (RuleStats) carries the true CPU cost either way.
-        stats.confirm_us += cjob.launch_us + int(
+        stats.confirm_us += cjob.launch_us + joined_us + int(
             (time.perf_counter() - tc0) * 1e6)
 
         elapsed = int((time.perf_counter() - t0) * 1e6)

@@ -970,13 +970,19 @@ class Batcher:
                           fut: "Future[Verdict]", h: StreamState,
                           held: List[int]) -> None:
         """Confirm one scanned request and resolve it (the side lane's
-        finish thread): a batch of one, so the walk runs inline on this
-        thread, outside the swap lock, while the scan thread scans the
-        next request."""
+        finish thread), outside the swap lock, while the scan thread
+        scans the next request.  The walk of this batch of one goes to
+        a walker process (``lone_to_walker``) and this thread blocks on
+        the pipe with the interpreter lock released: a body past 16 KiB
+        unpacked walks for tens of ms against a hop of one or two, and
+        walked here that Python would hold the lock the scan thread's
+        every call into JAX waits for.  Inline at ``--confirm-workers
+        1`` or while no walker holds the generation."""
         try:
             with flight.span(EV_SIDE_CONFIRM) as sp_confirm:
                 v = self.stream_engine.finish(
-                    h, hold=functools.partial(self._side_hold, held))
+                    h, hold=functools.partial(self._side_hold, held),
+                    lone_to_walker=True)
             self.sidehist["side_confirm"].observe(sp_confirm.us)
             self.sidehist["side_lock"].observe(held[0])
         except Exception:

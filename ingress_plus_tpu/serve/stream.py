@@ -53,6 +53,7 @@ from ingress_plus_tpu.compiler.bitap import (
 )
 from ingress_plus_tpu.compiler.seclang import STREAM_INDEX
 from ingress_plus_tpu.compiler.ruleset import VARIANTS
+from ingress_plus_tpu.models.confirm_plane import join_confirm
 from ingress_plus_tpu.models.pipeline import DetectionPipeline, Verdict
 from ingress_plus_tpu.ops.scan import pad_rows, scan_bytes_jit
 from ingress_plus_tpu.serve.normalize import (
@@ -511,11 +512,19 @@ class StreamEngine:
                        fail_open=True, elapsed_us=int(
                            (time.perf_counter() - st.t0) * 1e6))
 
-    def finish(self, st: StreamState, hold=None) -> Verdict:
+    def finish(self, st: StreamState, hold=None,
+               lone_to_walker: bool = False) -> Verdict:
         """Fold the stream's match words into rule hits and confirm.
-        ``hold`` as in :meth:`scan`: given, the confirm walk (a batch of
-        one: inline, on the calling thread) runs OUTSIDE the lock and
-        only the mask and the single-threaded fold take it."""
+        ``hold`` as in :meth:`scan`: given, the confirm walk and the
+        wait for it run OUTSIDE the lock and only the mask and the
+        single-threaded fold take it; a ruleset swapped in meanwhile
+        fails the stream open at the fold.  ``lone_to_walker``: the
+        walk of this batch of one goes to a walker process
+        (``ConfirmPool.deal``) and this thread blocks on its pipe, the
+        interpreter lock released — the oversized side lane asks it,
+        so that its body's walk of tens of ms leaves the lock to its
+        scan thread.  Otherwise (a wire stream), and where no walker
+        holds the generation, the walk is inline on this thread."""
         hold = hold or nullcontext
         p = self.pipeline
         req = st.request
@@ -552,10 +561,14 @@ class StreamEngine:
             if st.version != p.ruleset.version:
                 return self._failed_open(st)
             hits = p.mask_hits([req], hits[None])
-        # the walk of a body of tens of KB lasts tens of ms: outside
-        # the hold, so a batched cycle never waits it out
-        cjob = p.finalize_launch([confirm_req], hits)
+        # the walk of a body of tens of KB lasts tens of ms: it, and a
+        # walker's answer, are waited for outside the hold, so a
+        # batched cycle never waits them out
+        cjob = p.finalize_launch([confirm_req], hits, lone_to_walker)
+        join_confirm(p, cjob)
         with hold():
+            if st.version != self.pipeline.ruleset.version:
+                return self._failed_open(st)
             v = p.finalize_join(cjob, st.t0)[0]
             p.stats.requests += 1
         # scan/confirm caps were hit: the verdict is based on a prefix —

@@ -290,6 +290,85 @@ def test_shares_are_dealt_no_wider_than_they_must(n, workers, shares):
         assert max(len(range(s, n, shares)) for s in range(shares)) == per
 
 
+@pytest.mark.parametrize("n,workers,holding,dealt", [
+    (1, 8, 8, [7]), (1, 8, 1, [0]), (1, 8, 0, []), (1, 2, 2, [1]),
+    (1, 1, 0, []), (0, 8, 8, []), (5, 8, 8, [0, 1, 2, 3, 4]),
+    (2, 8, 1, [])])
+def test_the_side_lanes_lone_walk_needs_one_walker_holding_the_pack(
+        n, workers, holding, dealt):
+    """``lone_to_walker``: a batch of one goes to the last walker that
+    holds the generation (none: inline, [] here; an inline pool: the
+    same); a larger batch is dealt as ever."""
+    pool = ConfirmPool(n_workers=1)
+
+    class _W:
+        def __init__(self, i):
+            self.worker_index, self.failed_at = i, None
+            self.held = {7: 0} if i < holding else {}
+            self.installing = set()
+
+    class _P:
+        confirm_gen = 7
+
+    if workers > 1:
+        pool._workers = [_W(i) for i in range(workers)]
+    # the installs a stub cannot take are left out: ready = holding
+    pool._post_installs = lambda pl: (
+        [w for w in pool._workers if 7 in w.held], [])
+    got = pool.deal(_P, n, lone_to_walker=True)
+    assert [w.worker_index for w in got] == dealt
+    if n == 1 and holding:
+        assert pool.deal(_P, 1) == []      # what every other caller gets
+
+
+def test_lone_walks_and_batched_shares_deal_from_two_threads_at_once(pair):
+    """As in the server, where the dispatch thread deals batched shares
+    while the side lane's finish thread deals its lone walks to the same
+    walkers: three threads of each, a 10 us switch interval.  Every
+    result is the inline walk's and every request is counted once,
+    under ``process``."""
+    inline, pooled = pair
+    pool = pooled.confirm_pool
+    batch, lones = _mixed(6, tag="s"), _mixed(8, tag="l")
+    rh_b = pooled.mask_hits(batch, pooled.prefilter(batch))
+    rh_l = pooled.mask_hits(lones, pooled.prefilter(lones))
+    want_b = [_rt(r) for r in join_confirm(
+        inline, launch_confirm(inline, batch, rh_b))]
+    want_l = [_rt(r) for r in join_confirm(
+        inline, launch_confirm(inline, lones, rh_l))]
+    rounds, errors = 20, []
+
+    def _batched():
+        for _ in range(rounds):
+            got = join_confirm(pooled, launch_confirm(pooled, batch, rh_b))
+            if [_rt(r) for r in got] != want_b:
+                errors.append("batched")
+
+    def _lone():
+        for k in range(rounds):
+            i = k % len(lones)
+            (got,) = join_confirm(pooled, launch_confirm(
+                pooled, lones[i:i + 1], rh_l[i:i + 1], lone_to_walker=True))
+            if _rt(got) != want_l[i]:
+                errors.append("lone %d" % i)
+
+    before = (pool.requests_inline, pool.requests_process)
+    threads = [threading.Thread(target=f) for f in (_batched, _lone) * 3]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert (pool.requests_inline, pool.requests_process) == (
+        before[0], before[1] + 3 * rounds * (len(batch) + 1))
+
+
 def test_until_two_walkers_hold_a_generation_the_walk_is_inline(small):
     """A generation nobody installed ahead of its traffic: the batch
     that finds it missing is walked inline (and queues the install),

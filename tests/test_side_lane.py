@@ -10,8 +10,12 @@ JSON and urlencoded bodies, the payload mid-body, at the very tail,
 across a wave boundary of every kind (narrow/narrow, wide/wide,
 wide/narrow) and across a 64 KiB chunk boundary, and stretched over each
 by thousands of bytes that a rule's chain deletes.  The reference shares
-no code with the program.  The wave plan itself is held, on the engine
-alone, to an all-narrow plan and to one unbroken scan.
+no code with the program.  Every case runs twice: with the inline
+confirm (``--confirm-workers 1``) and with walker processes, to which
+the side lane hands its lone walk.  Where that walk runs and how it
+fails (a walker killed or wedged under it, a hot swap during it) is held
+on a small pack.  The wave plan itself is held, on the engine alone, to
+an all-narrow plan and to one unbroken scan.
 
 ROADMAP F1 is the named regression: the side lane used to confirm on the
 unpacked copy of the body, whose url-decoded segment the confirm twin
@@ -19,8 +23,12 @@ decoded once more (two rule ids too many on a double-encoded form
 payload).
 """
 
+import os
 import random
+import signal
 import sys
+import threading
+import time
 from pathlib import Path
 
 import pytest
@@ -46,22 +54,54 @@ CHUNK = Batcher.OVERSIZE_CHUNK
 SQLI = "1' UNION SELECT card_no FROM payments--"
 
 
+def _wait_for(cond, timeout=120.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if cond():
+            return True
+        time.sleep(0.05)
+    return cond()
+
+
+def _held_by_all(pl) -> bool:
+    return all(pl.confirm_gen in w.held and not w.installing
+               for w in pl.confirm_pool._workers)
+
+
 @pytest.fixture(scope="module")
-def lane():
-    """The served pack on a CPU batcher (inline confirm), warmed as the
-    server's start-up warms it, and the reference's deployment."""
+def served_pack():
     from ingress_plus_tpu.compiler import compile_ruleset
     from ingress_plus_tpu.compiler.sigpack import RULES_DIR, load_bundled_rules
+
+    return compile_ruleset(load_bundled_rules(), base_path=RULES_DIR / "crs")
+
+
+@pytest.fixture(scope="module")
+def deployment():
+    return plainwaf.Deployment(BENCH / "rules" / "crs",
+                               BENCH / "rules" / "sigpack.json")
+
+
+#: confirm workers of each lane: 1 walks inline, 2 are walker processes
+POOLS = {"inline": 1, "walkers": 2}
+
+
+@pytest.fixture(scope="module", params=sorted(POOLS))
+def lane(request, served_pack, deployment):
+    """The served pack on a CPU batcher, warmed as the server's start-up
+    warms it, and the reference's deployment; the confirm inline, or on
+    walker processes that hold the pack before the first request."""
     from ingress_plus_tpu.models.pipeline import DetectionPipeline
 
-    cr = compile_ruleset(load_bundled_rules(), base_path=RULES_DIR / "crs")
-    batcher = Batcher(DetectionPipeline(cr, mode="block"), max_batch=8,
-                      hard_deadline_s=60.0)
-    batcher.stream_engine.warm()
-    dep = plainwaf.Deployment(BENCH / "rules" / "crs",
-                              BENCH / "rules" / "sigpack.json")
-    yield batcher, dep
-    batcher.close()
+    batcher = Batcher(DetectionPipeline(
+        served_pack, mode="block", confirm_workers=POOLS[request.param]),
+        max_batch=8, hard_deadline_s=60.0)
+    try:
+        assert _wait_for(lambda: _held_by_all(batcher.pipeline))
+        batcher.stream_engine.warm()
+        yield batcher, deployment
+    finally:
+        batcher.close()
 
 
 def build(size: int, ctype: str, payload: str, where,
@@ -123,15 +163,24 @@ def build_over(size: int, ctype: str, payload: str, target,
     raise AssertionError("payload not laid over %d" % target)
 
 
-def served_and_reference(lane, wire_request: WireRequest, req_id: int = 7):
+def served_and_reference(lane, wire_request: WireRequest, req_id: int = 7,
+                         held: bool = True):
+    """(served, reference) verdicts of one frame; a rerouted request's
+    walk went to a walker process wherever the lane has them and
+    (``held``) they hold the pack."""
     from ingress_plus_tpu.serve.protocol import decode_request
 
     batcher, dep = lane
+    pool = batcher.pipeline.confirm_pool
     frame = encode_request(wire_request, req_id=req_id)
     _id, mode, req = decode_request(frame[8:])
     req.mode = mode
+    rerouted, walked = batcher.stats.oversized_rerouted, pool.requests_process
     v = batcher.submit(req).result(timeout=300)
     assert not v.fail_open
+    rerouted = batcher.stats.oversized_rerouted - rerouted
+    assert pool.requests_process - walked == (
+        rerouted if held and not pool.inline else 0)
     _idx, request = plainwaf.decode_frame(frame)
     want = plainwaf.verdict(dep, request)
     return ((bool(v.attack), bool(v.blocked),
@@ -366,6 +415,200 @@ def test_wave_counters_count_live_rows_and_their_bytes(lane):
     assert (eng.waves - w0 <= eng.wave_rows - r0
             <= len(VARIANTS) * (eng.waves - w0))
     assert len(scanned) <= eng.wave_bytes - b0 <= len(VARIANTS) * len(scanned)
+
+
+# ------------------------------------ where the lone walk runs, and faults
+
+def test_the_side_lane_walks_in_a_walker_and_a_batched_one_inline(lane):
+    """A rerouted request is walked in a walker process (the last one:
+    batched shares are dealt from the first) and counted under
+    ``process``; a batched dispatch of one is still walked inline.  An
+    inline pool walks both inline."""
+    batcher, _dep = lane
+    pool = batcher.pipeline.confirm_pool
+    inline, process = pool.requests_inline, pool.requests_process
+    rerouted = batcher.stats.oversized_rerouted
+    have, want = served_and_reference(lane, build(20_000, "form", SQLI, "mid"))
+    assert batcher.stats.oversized_rerouted == rerouted + 1
+    assert want[0] and have == want
+    assert (pool.requests_inline - inline, pool.requests_process - process) \
+        == ((1, 0) if pool.inline else (0, 1))
+    have, want = served_and_reference(lane, build(3_000, "form", SQLI, "mid"))
+    assert batcher.stats.oversized_rerouted == rerouted + 1
+    assert want[0] and have == want
+    assert (pool.requests_inline - inline, pool.requests_process - process) \
+        == ((2, 0) if pool.inline else (1, 1))
+
+
+def test_a_generation_no_walker_holds_yet_walks_the_side_lane_inline(lane):
+    """The walkers forget the pack (as after a swap whose install has
+    not been answered): the rerouted request is walked inline and the
+    install goes out; once it is answered the next one goes to a
+    walker."""
+    batcher, _dep = lane
+    pl = batcher.pipeline
+    pool = pl.confirm_pool
+    for w in pool._workers:
+        w.held.pop(pl.confirm_gen, None)
+    inline, process = pool.requests_inline, pool.requests_process
+    have, want = served_and_reference(
+        lane, build(20_000, "json", SQLI, "mid"), held=False)
+    assert want[0] and have == want
+    assert (pool.requests_inline, pool.requests_process) \
+        == (inline + 1, process)
+    assert _wait_for(lambda: _held_by_all(pl))
+    have, want = served_and_reference(lane, build(21_000, "json", SQLI, "mid"))
+    assert want[0] and have == want
+    assert pool.requests_process == process + (0 if pool.inline else 1)
+
+
+#: a small pack for the fault cases; the last rule backtracks without
+#: bound on ``a`` x 40 then ``!``, so the walk of such a body never ends
+SQLI_RULE = """
+SecRule REQUEST_BODY "@rx (?i)union\\s+select" \
+    "id:942100,phase:2,block,t:urlDecodeUni,severity:CRITICAL,tag:'attack-sqli'"
+"""
+SMALL_RULES = SQLI_RULE + """
+SecRule REQUEST_BODY "@rx /etc/passwd" \
+    "id:930120,phase:2,block,severity:CRITICAL,tag:'attack-lfi'"
+"""
+BACKTRACK = ('SecRule REQUEST_BODY "@rx ^(a+)+$" "id:900001,phase:2,block,'
+             'severity:CRITICAL,tag:\'attack-generic\'"\n')
+
+
+def small_batcher(rules: str = SMALL_RULES, workers: int = 2,
+                  hang_budget_s: float = 30.0) -> Batcher:
+    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
+    from ingress_plus_tpu.compiler.seclang import parse_seclang
+    from ingress_plus_tpu.models.pipeline import DetectionPipeline
+
+    b = Batcher(DetectionPipeline(
+        compile_ruleset(parse_seclang(rules)), mode="block",
+        confirm_workers=workers, confirm_hang_budget_s=hang_budget_s),
+        max_batch=8, hard_deadline_s=60.0)
+    assert _wait_for(lambda: _held_by_all(b.pipeline))
+    return b
+
+
+def oversized(body: bytes, request_id: str):
+    """A raw reroute: a plain-text body past the 16 KiB tier."""
+    from ingress_plus_tpu.serve.normalize import Request
+
+    assert len(body) > THRESHOLD
+    return Request(method="POST", uri="/upload", request_id=request_id,
+                   headers={"content-type": "text/plain"}, body=body)
+
+
+BENIGN = b"lorem ipsum " * 1_600
+ATTACK = BENIGN[:9_000] + b" 1 union select pw from users " + BENIGN[9_000:]
+FOREVER = b"a" * 40 + b"!" + BENIGN
+
+
+def test_the_lone_walk_books_no_batched_sub_stage():
+    """The side lane's walk in a walker is a ``side_confirm`` of its
+    own: ``confirm_walk`` and ``confirm_ipc`` read the batched
+    dispatches alone, and the verdict names the walker."""
+    from ingress_plus_tpu.utils.trace import flight
+
+    flight.configure(enabled=True)
+    b = small_batcher()
+    try:
+        pool = b.pipeline.confirm_pool
+        v = b.submit(oversized(ATTACK, "a")).result(timeout=120)
+        assert v.attack and not v.fail_open
+        assert v.confirm_worker == pool._workers[-1].worker_index
+        assert pool.requests_process == 1 and pool.requests_inline == 0
+        assert b.sidehist["side_confirm"].total == 1
+        assert b.subhist["confirm_walk"].sum_us == 0
+        assert b.subhist["confirm_ipc"].sum_us == 0
+    finally:
+        b.close()
+
+
+def test_a_walker_killed_mid_walk_fails_the_request_open():
+    b = small_batcher(SMALL_RULES + BACKTRACK)
+    try:
+        pool = b.pipeline.confirm_pool
+        victim = pool._workers[-1]
+        fut = b.submit(oversized(FOREVER, "forever"))
+        assert _wait_for(lambda: pool.requests_process == 1)
+        time.sleep(0.3)
+        assert not fut.done()            # walked without end
+        os.kill(victim.proc.pid, signal.SIGKILL)
+        v = fut.result(timeout=60)
+        assert v.fail_open and not v.attack
+        assert pool.workers_replaced == 1
+        assert b.pipeline.stats.confirm_hangs == 0     # died, not hung
+        assert pool._workers[-1] is not victim
+        # the fresh walker takes the side lane's walks once it holds
+        # the pack
+        assert _wait_for(lambda: _held_by_all(b.pipeline))
+        v = b.submit(oversized(ATTACK, "after")).result(timeout=120)
+        assert v.attack and not v.fail_open
+        assert pool.requests_process == 2
+    finally:
+        b.close()
+
+
+def test_a_walker_slowed_past_the_hang_budget_fails_the_request_open():
+    from ingress_plus_tpu.utils import faults
+    from ingress_plus_tpu.utils.faults import FaultPlan
+
+    b = small_batcher(hang_budget_s=1.0)
+    try:
+        pool = b.pipeline.confirm_pool
+        stuck = pool._workers[-1]
+        faults.install(FaultPlan.from_spec(
+            "slow_confirm:worker=%d,times=1,delay_s=8.0"
+            % stuck.worker_index))
+        try:
+            t0 = time.perf_counter()
+            v = b.submit(oversized(ATTACK, "slow")).result(timeout=60)
+            assert time.perf_counter() - t0 < 6.0   # bounded by the budget
+        finally:
+            faults.install(None)
+        assert v.fail_open and not v.attack
+        assert b.pipeline.stats.confirm_hangs == 1
+        assert pool.workers_replaced == 1
+        assert pool._workers[-1] is not stuck
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_a_hot_swap_during_the_walk_fails_the_request_open(monkeypatch,
+                                                           pool):
+    """A ruleset installed while the walk is out (here: just before its
+    answer is taken) fails the request open at the fold, inline or on a
+    walker; the next request is served by the new pack."""
+    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
+    from ingress_plus_tpu.compiler.seclang import parse_seclang
+    from ingress_plus_tpu.serve import stream
+
+    b = small_batcher(workers=POOLS[pool])
+    try:
+        old = b.pipeline
+        real_join = stream.join_confirm
+
+        def _swap_then_join(pl, job):
+            t = threading.Thread(target=b.swap_ruleset, args=(
+                compile_ruleset(parse_seclang(SQLI_RULE)),))
+            t.start()
+            t.join(120)
+            return real_join(pl, job)
+
+        monkeypatch.setattr(stream, "join_confirm", _swap_then_join)
+        v = b.submit(oversized(ATTACK, "swapped")).result(timeout=180)
+        monkeypatch.setattr(stream, "join_confirm", real_join)
+        assert b.pipeline is not old
+        assert v.fail_open and not v.attack
+        assert old.confirm_pool.requests_process == (
+            0 if old.confirm_pool.inline else 1)
+        v = b.submit(oversized(ATTACK, "after")).result(timeout=180)
+        assert v.attack and not v.fail_open
+        assert v.generation == b.pipeline.generation_tag
+    finally:
+        b.close()
 
 
 # ------------------------------------------------ the plan, engine alone
