@@ -606,7 +606,7 @@ class ConfirmJob:
 
     __slots__ = ("requests", "rule_hits", "results", "pending", "memo",
                  "launch_us", "join_us", "share_workers", "memo_hits",
-                 "memo_misses", "cycle", "t0")
+                 "memo_misses", "cycle", "t0", "walk_ns")
 
     def __init__(self, requests, rule_hits) -> None:
         self.requests = requests
@@ -631,6 +631,9 @@ class ConfirmJob:
         self.memo_hits = 0
         self.memo_misses = 0
         self.cycle = 0
+        #: the walk's own time, summed over the shares: each walker's
+        #: as it reports it, or the inline walk's ``confirm_walk`` span
+        self.walk_ns = 0
 
 
 class ConfirmPool:
@@ -839,7 +842,7 @@ def _walk_inline(pl, job: ConfirmJob, tt: bool, trace_cycle: int) -> None:
     faults.set_current_confirm_worker(0)
     try:
         with flight.span(EV_CONFIRM, cycle=trace_cycle, tag=0,
-                         arg=len(requests)):
+                         arg=len(requests)) as sp:
             faults.sleep_if("slow_confirm")
             for qi, req in enumerate(requests):
                 if tt:
@@ -851,6 +854,7 @@ def _walk_inline(pl, job: ConfirmJob, tt: bool, trace_cycle: int) -> None:
         if tt:
             faults.set_current_tenant(None)
         faults.set_current_confirm_worker(None)
+    job.walk_ns = sp.us * 1000
     # no hop: the dispatch's confirm_ipc reads 0, beside its walk
     now = time.monotonic_ns()
     flight.span_at(EV_CONFIRM_IPC, now, now, cycle=trace_cycle)
@@ -999,6 +1003,7 @@ def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
             job.results[i] = res
         job.memo_hits += hits
         job.memo_misses += misses
+        job.walk_ns += walk_ns
         # the hop: from the share's send to its answer in the waiter's
         # hands, less the walker's own walk time (the pipe both ways,
         # the walker's unpickling and pickling, the wake-ups)

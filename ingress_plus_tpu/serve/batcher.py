@@ -60,7 +60,7 @@ from collections import deque
 from concurrent.futures import Future
 from contextlib import contextmanager
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ingress_plus_tpu.models.pipeline import DetectionPipeline, Verdict
 from ingress_plus_tpu.models.tenant_guard import (
@@ -90,6 +90,8 @@ from ingress_plus_tpu.utils.trace import (
     EV_SCAN_WALL,
     EV_SIDE_CONFIRM,
     EV_SIDE_LOCK,
+    EV_SIDE_LOCK_WAIT,
+    EV_SIDE_PREFILTER,
     EV_SIDE_SCAN,
     EV_SIDE_WAIT,
     EV_STREAM,
@@ -115,6 +117,11 @@ from ingress_plus_tpu.utils.trace import (
 #: the stream engine's own unpack stages (serve/stream.py StreamState):
 #: off for a side-lane body, which arrives unpacked
 _STREAM_UNPACKERS = frozenset({"gzip", "base64", "json"})
+
+#: the side stages a rerouted request sums over its spans (each of the
+#: other three is one span a request)
+SUMMED_SIDE_STAGES = ("side_lock", "side_prefilter", "side_launch",
+                      "side_fetch", "side_lock_wait", "side_walk")
 
 #: backward-compat alias — the single-device worker grew into
 #: serve/lanes.LaneWorker when the lane plane went per-chip
@@ -879,15 +886,23 @@ class Batcher:
                 self._release_oversized_slot(item[1].tenant)
 
     @contextmanager
-    def _side_hold(self, held: List[int]):
-        """The swap lock as the side lane takes it: one hold, its
-        ``side_lock`` span, and its µs added to ``held[0]`` (the
-        request's sum, observed once in ``_finish_oversized``)."""
+    def _side_hold(self, spent: Dict[str, List[int]]):
+        """The swap lock as the side lane takes it: the wait for it
+        (``side_lock_wait``) and one hold (``side_lock``), both spans
+        added to ``spent``, the request's sums (observed once in
+        ``_observe_side``)."""
+        wait = flight.span(EV_SIDE_LOCK_WAIT, into=spent).begin()
         with self._swap_lock:
-            t0 = time.perf_counter()
-            with flight.span(EV_SIDE_LOCK):
+            wait.end()
+            with flight.span(EV_SIDE_LOCK, into=spent):
                 yield
-            held[0] += int((time.perf_counter() - t0) * 1e6)
+
+    def _observe_side(self, spent: Dict[str, List[int]]) -> None:
+        """A rerouted request's summed side-lane spans, one observation
+        each with its CPU µs (0 for a part it had none of)."""
+        for name in SUMMED_SIDE_STAGES:
+            ns, cpu_ns = spent.get(name, (0, 0))
+            self.sidehist[name].observe(ns // 1000, cpu_ns // 1000)
 
     def _detect_oversized(self, ts: float, request: Request, plan,
                         fut: "Future[Verdict]") -> bool:
@@ -939,8 +954,10 @@ class Batcher:
             # default device).
             self._resolve_oversized(ts, request, fut, None)
             return False
-        held = [0]
-        hold = functools.partial(self._side_hold, held)
+        # the request's summed spans, {name: [ns, CPU ns]}, filled on
+        # both side threads, one after the other
+        spent: Dict[str, List[int]] = {}
+        hold = functools.partial(self._side_hold, spent)
         engine = self.stream_engine
         try:
             with flight.span(EV_SIDE_SCAN, arg=len(body)) as sp_scan:
@@ -954,21 +971,22 @@ class Batcher:
                                | _STREAM_UNPACKERS)
                 with hold():
                     h = engine.begin(meta, confirm_request=request)
-                    h.base_hits = self.pipeline.prefilter([meta])[0]
+                    with flight.span(EV_SIDE_PREFILTER, into=spent):
+                        h.base_hits = self.pipeline.prefilter([meta])[0]
                 for i in range(0, len(scanned), self.OVERSIZE_CHUNK):
                     engine.scan(h.feed(scanned[i:i + self.OVERSIZE_CHUNK]),
-                                hold=hold)
-                engine.scan(h.flush(), hold=hold)
-            self.sidehist["side_scan"].observe(sp_scan.us)
+                                hold=hold, spent=spent)
+                engine.scan(h.flush(), hold=hold, spent=spent)
+            self.sidehist["side_scan"].observe(sp_scan.us, sp_scan.cpu_us)
         except Exception:
             self._resolve_oversized(ts, request, fut, None)
             return False
-        self._oversized_fin_q.put((ts, request, fut, h, held))
+        self._oversized_fin_q.put((ts, request, fut, h, spent))
         return True
 
     def _finish_oversized(self, ts: float, request: Request,
                           fut: "Future[Verdict]", h: StreamState,
-                          held: List[int]) -> None:
+                          spent: Dict[str, List[int]]) -> None:
         """Confirm one scanned request and resolve it (the side lane's
         finish thread), outside the swap lock, while the scan thread
         scans the next request.  The walk of this batch of one goes to
@@ -981,10 +999,11 @@ class Batcher:
         try:
             with flight.span(EV_SIDE_CONFIRM) as sp_confirm:
                 v = self.stream_engine.finish(
-                    h, hold=functools.partial(self._side_hold, held),
-                    lone_to_walker=True)
-            self.sidehist["side_confirm"].observe(sp_confirm.us)
-            self.sidehist["side_lock"].observe(held[0])
+                    h, hold=functools.partial(self._side_hold, spent),
+                    lone_to_walker=True, spent=spent)
+            self.sidehist["side_confirm"].observe(sp_confirm.us,
+                                                  sp_confirm.cpu_us)
+            self._observe_side(spent)
         except Exception:
             v = None
         self._resolve_oversized(ts, request, fut, v)
@@ -1328,11 +1347,12 @@ class Batcher:
         self._drain_idle_us += sp.us
         return batch
 
-    def _sub_spans(self, cid: int) -> Dict[str, int]:
+    def _sub_spans(self, cid: int) -> Tuple[Dict[str, int], Dict[str, int]]:
         """Cycle ``cid``'s sub-spans for its :class:`BatchTrace`: what
         the flight recorder's spans accumulated under the cycle id, and
-        the loop's idle time before it.  Empty with the recorder off."""
-        sub, lanes = flight.take_with_lanes(cid)
+        the loop's idle time before it; beside them the spans' CPU µs.
+        Empty with the recorder off."""
+        sub, lanes, cpu = flight.take_all(cid)
         if flight.enabled:
             sub["drain_idle"] = self._drain_idle_us
         self._drain_idle_us = 0
@@ -1341,7 +1361,7 @@ class Batcher:
             # a cycle each (ipt_lane_stage_us{device=,stage=})
             for key, us in lanes.items():
                 self._count_us(self.lane_stage_us, key, us)
-        return sub
+        return sub, cpu
 
     @staticmethod
     def _count_us(table: dict, key, us: int) -> None:
@@ -2029,6 +2049,7 @@ class Batcher:
         if took > self.hard_deadline_s:
             self.stats.deadline_overruns += c.n_reqs + c.n_finishes
         self.stats.completed += c.n_reqs + c.n_finishes
+        sub, sub_cpu = self._sub_spans(c.cid)
         trace = BatchTrace(
             ts=time.time(),
             n_requests=c.n_reqs,
@@ -2038,7 +2059,8 @@ class Batcher:
             engine_us=d_engine,
             confirm_us=d_confirm,
             prep_us=d_prep,
-            sub_us=self._sub_spans(c.cid),
+            sub_us=sub,
+            sub_cpu_us=sub_cpu,
             gc_us=gc_watch.pause_us() - c.gc_us0,
             request_ids=[r.request_id for _ts, r, _v, _l in done]
             + [h.request.request_id for h, _ in c.finish_verdicts])
@@ -2172,9 +2194,11 @@ class Batcher:
         h["confirm"].observe(trace.confirm_us)
         if flight.enabled:
             # the cycle's sub-spans, once per dispatch like the stages
-            # they lie in (a dispatch without one observes 0)
+            # they lie in (a dispatch without one observes 0), each
+            # with its spans' CPU µs
             for name in PER_DISPATCH:
-                self.subhist[name].observe(trace.sub_us.get(name, 0))
+                self.subhist[name].observe(trace.sub_us.get(name, 0),
+                                           trace.sub_cpu_us.get(name, 0))
         if trace.n_requests:
             self.batch_size_hist.observe(trace.n_requests)
         stages = None                 # built only if something IS slow

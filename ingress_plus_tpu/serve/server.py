@@ -55,6 +55,7 @@ from ingress_plus_tpu.utils.platform import (
     device_memory_peak_bytes,
 )
 from ingress_plus_tpu.utils.trace import (
+    CPU_STAGES,
     EV_REPLY,
     ProfilerBusy,
     ProfilerSwitch,
@@ -97,6 +98,13 @@ METRIC_HELP = {
         "batches served on the CPU confirm-only fallback",
     "ipt_stage_us":
         "per-stage and per-sub-stage latency histogram (log2 us buckets)",
+    "ipt_stage_cpu_us_total":
+        "thread CPU time inside the spans of a stage, bumped with each "
+        "ipt_stage_us observation of it (us): sum less this is the "
+        "time off the CPU",
+    "ipt_thread_cpu_seconds_total":
+        "CPU time of the server's Python threads by root name, read at "
+        "scrape time (s)",
     "ipt_device_launches_total":
         "device programs enqueued by the scan dispatch",
     "ipt_oversized_rerouted_total":
@@ -797,6 +805,18 @@ class ServeLoop:
             for stage, hist in (*self.batcher.subhist.items(),
                                 *self.batcher.sidehist.items()):
                 lines += hist.prometheus("ipt_stage_us", {"stage": stage})
+            # the same spans' thread CPU: sum less CPU is time off the
+            # CPU (the interpreter lock, a blocking call, preemption)
+            lines.append("# TYPE ipt_stage_cpu_us_total counter")
+            hists = {**self.batcher.subhist, **self.batcher.sidehist}
+            lines += ['ipt_stage_cpu_us_total{stage="%s"} %d'
+                      % (stage, hists[stage].cpu_us) for stage in CPU_STAGES]
+            threads = flight.thread_cpu_seconds()
+            if threads is not None:
+                lines.append("# TYPE ipt_thread_cpu_seconds_total counter")
+                lines += bounded_counter_series(
+                    "ipt_thread_cpu_seconds_total", "thread", threads,
+                    fmt="%.6f")
         lines += [
             "# TYPE ipt_device_launches_total counter",
             "ipt_device_launches_total %d"

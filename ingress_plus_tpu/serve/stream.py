@@ -72,6 +72,12 @@ from ingress_plus_tpu.serve.unpack import (
     grpc_content_kind,
     header_lookup,
 )
+from ingress_plus_tpu.utils.trace import (
+    EV_SIDE_FETCH,
+    EV_SIDE_LAUNCH,
+    EV_SIDE_WALK,
+    flight,
+)
 
 # longest suffix that might be an incomplete %-escape: %, %X, %u, %uX..%uXXX
 _URL_TAIL = re.compile(rb"%(?:u[0-9a-fA-F]{0,3}|[0-9a-fA-F])?$")
@@ -315,6 +321,12 @@ def wave_width(pending: int) -> int:
     return WIDE_L if pending >= WIDE_L else CHUNK_L
 
 
+def _side_span(code: int, spent: Optional[Dict[str, List[int]]]):
+    """A part of a side-lane request's scan as a span summed into its
+    ``spent``; nothing for a caller without one."""
+    return nullcontext() if spent is None else flight.span(code, into=spent)
+
+
 class StreamEngine:
     """Chunk-batch scanner + stream finisher.  Two callers, both under
     the batcher's swap lock: its dispatch thread (wire streams: holds
@@ -406,7 +418,7 @@ class StreamEngine:
     # ------------------------------------------------------------ scan
 
     def scan(self, items: List[Tuple[StreamState, int, bytes]],
-             hold=None) -> int:
+             hold=None, spent: Optional[Dict[str, List[int]]] = None) -> int:
         """Scan increments for many (stream, variant) rows, batched into
         waves whose width ``wave_width`` chooses from the bytes pending
         in the call's longest row (WIDE_L while that much is pending,
@@ -417,8 +429,11 @@ class StreamEngine:
         side worker's hold of the swap lock: the version check, the
         tables of that generation and the counters; the launch follows
         outside it, and nothing waits for the device before the last
-        wave); None where the caller holds the lock already.  Returns
-        the waves launched."""
+        wave); None where the caller holds the lock already.  ``spent``:
+        the side lane's sums for the request (``flight.span(into=)``),
+        to which each wave's call into JAX adds a ``side_launch`` span
+        and the copies back at the end a ``side_fetch``; None (a wire
+        stream) opens neither.  Returns the waves launched."""
         hold = hold or nullcontext
         merged: Dict[Tuple[int, int], List] = {}
         for st, vi, data in items:
@@ -490,12 +505,14 @@ class StreamEngine:
             # it does, and on this machine the call into JAX returns
             # only after milliseconds, which a batched cycle must not
             # wait out
-            match, state = scan_bytes_jit(tables, tokens, lengths, state,
-                                          match)
+            with _side_span(EV_SIDE_LAUNCH, spent):
+                match, state = scan_bytes_jit(tables, tokens, lengths,
+                                              state, match)
             waves += 1
             off += L
-        m_out = np.asarray(match)
-        s_out = np.asarray(state)
+        with _side_span(EV_SIDE_FETCH, spent):
+            m_out = np.asarray(match)
+            s_out = np.asarray(state)
         for j, r in enumerate(rows):
             for st, vi, _ in (r, *followers[id(r)]):
                 st.state[vi] = s_out[j]
@@ -513,7 +530,8 @@ class StreamEngine:
                            (time.perf_counter() - st.t0) * 1e6))
 
     def finish(self, st: StreamState, hold=None,
-               lone_to_walker: bool = False) -> Verdict:
+               lone_to_walker: bool = False,
+               spent: Optional[Dict[str, List[int]]] = None) -> Verdict:
         """Fold the stream's match words into rule hits and confirm.
         ``hold`` as in :meth:`scan`: given, the confirm walk and the
         wait for it run OUTSIDE the lock and only the mask and the
@@ -524,7 +542,10 @@ class StreamEngine:
         interpreter lock released — the oversized side lane asks it,
         so that its body's walk of tens of ms leaves the lock to its
         scan thread.  Otherwise (a wire stream), and where no walker
-        holds the generation, the walk is inline on this thread."""
+        holds the generation, the walk is inline on this thread.
+        ``spent`` as in :meth:`scan`: the walk's own time is added to it
+        as ``side_walk`` (a walker's, as it reports it; inline, this
+        thread's ``confirm_walk`` span)."""
         hold = hold or nullcontext
         p = self.pipeline
         req = st.request
@@ -566,6 +587,11 @@ class StreamEngine:
         # batched cycle never waits them out
         cjob = p.finalize_launch([confirm_req], hits, lone_to_walker)
         join_confirm(p, cjob)
+        if spent is not None:
+            # ring only, back-dated from the answer: it ran elsewhere
+            now = time.monotonic_ns()
+            flight.span_at(EV_SIDE_WALK, now - cjob.walk_ns, now)
+            spent.setdefault("side_walk", [0, 0])[0] += cjob.walk_ns
         with hold():
             if st.version != self.pipeline.ruleset.version:
                 return self._failed_open(st)

@@ -28,7 +28,11 @@ profiles the Go side with pprof.  The TPU-native equivalents:
     recorder: ring begin/end events, a per-cycle µs accumulator the
     batcher folds into ``ipt_stage_us{stage=<sub-stage>}``, and a
     ``jax.profiler.TraceAnnotation("ipt:<name>")`` so the same span
-    lies in a profiler trace beside the device operations.
+    lies in a profiler trace beside the device operations.  A span of a
+    stage also reads the thread's CPU clock, so wall less CPU is its
+    time off the CPU (``ipt_stage_cpu_us_total{stage=}``), and
+    ``FlightRecorder.thread_cpu_seconds`` reads every registered
+    thread's CPU at scrape time (``ipt_thread_cpu_seconds_total``).
   * ``ProfilerSwitch`` — the program's own profiler switch
     (``POST /debug/profile?seconds=``, traces into ``--trace-dir``):
     one bounded session at a time, Python tracer off.
@@ -316,24 +320,28 @@ def _percentile_from_buckets(bounds: Sequence[int], counts: Sequence[int],
 class Histogram:
     """Fixed log-bucket µs histogram: observe is O(log n_buckets) with
     zero allocation (list index increments under a short lock — many
-    producer threads, consistent snapshots for the scraper)."""
+    producer threads, consistent snapshots for the scraper).  A stage's
+    histogram also sums the thread CPU µs its observations carry
+    (``cpu_us``: ``ipt_stage_cpu_us_total``)."""
 
-    __slots__ = ("bounds", "counts", "total", "sum_us", "_lock")
+    __slots__ = ("bounds", "counts", "total", "sum_us", "cpu_us", "_lock")
 
     def __init__(self, bounds: Sequence[int] = DEFAULT_BUCKETS_US):
         self.bounds = tuple(bounds)
         self.counts = [0] * (len(self.bounds) + 1)  # last = +Inf overflow
         self.total = 0
         self.sum_us = 0
+        self.cpu_us = 0
         self._lock = named_lock("Histogram._lock")
 
-    def observe(self, us: float) -> None:
+    def observe(self, us: float, cpu_us: int = 0) -> None:
         us_i = int(us)
         i = bisect_left(self.bounds, us_i)
         with self._lock:
             self.counts[i] += 1
             self.total += 1
             self.sum_us += us_i
+            self.cpu_us += cpu_us
 
     def reset(self) -> None:
         """Zero the distribution (bench legs reset after warmup so the
@@ -342,6 +350,7 @@ class Histogram:
             self.counts = [0] * (len(self.bounds) + 1)
             self.total = 0
             self.sum_us = 0
+            self.cpu_us = 0
 
     def snapshot(self) -> Tuple[List[int], int, int]:
         with self._lock:
@@ -451,6 +460,9 @@ class BatchTrace:
     #: with the flight recorder off)
     sub_us: Dict[str, int] = field(default_factory=dict)
     gc_us: int = 0            # interpreter collection pauses in the cycle
+    #: the thread CPU µs of the same sub-spans, where they read it
+    #: (``CPU_STAGES``)
+    sub_cpu_us: Dict[str, int] = field(default_factory=dict)
 
     def stages(self) -> Dict[str, int]:
         """Per-stage µs breakdown; ``other_us`` is the unattributed
@@ -637,9 +649,9 @@ class RecentMedian:
 
 
 def bounded_counter_series(name: str, label: str,
-                           counts: Dict[str, int], cap: int = 30,
+                           counts: Dict[str, float], cap: int = 30,
                            extra: Optional[Dict[str, str]] = None,
-                           ) -> List[str]:
+                           fmt: str = "%d") -> List[str]:
     """Prometheus counter lines for one labeled series with a HARD
     cardinality budget (the detection-plane telemetry policy: per-rule
     detail is JSON-only, Prometheus gets bounded label sets).
@@ -653,20 +665,22 @@ def bounded_counter_series(name: str, label: str,
     change reads as a process reset to PromQL rate()).  With a fixed
     label universe per series generation (rule families are fixed per
     ruleset version, L tiers are static) every series is monotonic.
-    ``extra`` labels (e.g. the ruleset version) ride every line.  No
-    # TYPE header — the caller groups series under one."""
+    ``extra`` labels (e.g. the ruleset version) ride every line; ``fmt``
+    formats a value (``"%d"``: counts).  No # TYPE header — the caller
+    groups series under one."""
     base = "".join('%s="%s",' % (k, v)
                    for k, v in (extra or {}).items())
     ordered = sorted(counts.items())
     lines = []
     other = 0
+    line = '%s{%s%s="%s"} ' + fmt
     for i, (val, n) in enumerate(ordered):
         if i < cap and val != "other":
-            lines.append('%s{%s%s="%s"} %d' % (name, base, label, val, n))
+            lines.append(line % (name, base, label, val, n))
         else:
             other += n
     if other or len(ordered) > cap:
-        lines.append('%s{%s%s="other"} %d' % (name, base, label, other))
+        lines.append(line % (name, base, label, "other", other))
     return lines
 
 
@@ -779,6 +793,11 @@ EV_SIDE_WAIT = 29  # side lane: submit → the worker takes the request (back-da
 EV_SIDE_SCAN = 30  # side lane: begin → last wave and flush, the head's prefilter inside, arg=body bytes
 EV_SIDE_CONFIRM = 31  # side lane: finish's confirm walk and fold
 EV_SIDE_LOCK = 32  # side lane: one hold of the batcher's swap lock
+EV_SIDE_PREFILTER = 33  # side lane: the head's prefilter (inside a hold)
+EV_SIDE_LAUNCH = 34  # side lane: one wave's call into JAX (scan_bytes_jit)
+EV_SIDE_FETCH = 35  # side lane: a scan call's copies of match and state back
+EV_SIDE_LOCK_WAIT = 36  # side lane: asking for the swap lock -> holding it
+EV_SIDE_WALK = 37  # side lane: the walker's own walk (back-dated, ring only)
 
 EVENT_NAMES: Dict[int, str] = {
     EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
@@ -795,6 +814,9 @@ EVENT_NAMES: Dict[int, str] = {
     EV_SCAN_WALL: "scan_wall", EV_CONFIRM_IPC: "confirm_ipc",
     EV_SIDE_WAIT: "side_wait", EV_SIDE_SCAN: "side_scan",
     EV_SIDE_CONFIRM: "side_confirm", EV_SIDE_LOCK: "side_lock",
+    EV_SIDE_PREFILTER: "side_prefilter", EV_SIDE_LAUNCH: "side_launch",
+    EV_SIDE_FETCH: "side_fetch", EV_SIDE_LOCK_WAIT: "side_lock_wait",
+    EV_SIDE_WALK: "side_walk",
 }
 
 #: span codes whose elapsed µs accumulate per cycle id for the batcher's
@@ -836,10 +858,33 @@ SUBSTAGES: Tuple[str, ...] = PER_DISPATCH + ("reply",)
 #: batched dispatch observes them: side_wait = submit → the worker takes
 #: the request; side_scan = stream begin → last wave and flush, the
 #: head's prefilter inside; side_confirm = finish's confirm walk and
-#: fold; side_lock = the request's holds of the swap lock, summed.
+#: fold; side_lock = the request's holds of the swap lock, summed.  The
+#: parts, each summed over the request: side_prefilter = the head's
+#: prefilter, side_launch = the waves' calls into JAX, side_fetch = the
+#: scan calls' waits for their results (all three inside side_scan);
+#: side_lock_wait = asking for the swap lock -> holding it, on both side
+#: threads; side_walk = the walker's own walk (inside side_confirm).
 SIDE_STAGES: Tuple[str, ...] = tuple(
     EVENT_NAMES[c] for c in (EV_SIDE_WAIT, EV_SIDE_SCAN, EV_SIDE_CONFIRM,
-                             EV_SIDE_LOCK))
+                             EV_SIDE_LOCK, EV_SIDE_PREFILTER, EV_SIDE_LAUNCH,
+                             EV_SIDE_FETCH, EV_SIDE_LOCK_WAIT, EV_SIDE_WALK))
+
+#: the stages whose spans read the thread's CPU clock too, rendered as
+#: ``ipt_stage_cpu_us_total{stage=}``: the stages folded into
+#: ``ipt_stage_us`` whose sites open a ``flight.span`` on the thread that
+#: does the work (lane_handoff, confirm_ipc and side_wait are back-dated
+#: across threads, side_walk is another process's clock), but for
+#: confirm_walk: eight spans a cycle on waiter threads blocked on a pipe,
+#: whose CPU is ~0 and whose reads would cost the interpreter lock ~0.1 ms
+#: a cycle on a host where one costs ~7 us (gVisor).  Each counter is
+#: bumped where, and as often as, its stage's ``ipt_stage_us`` is
+#: observed, so (sum - CPU) / count is the mean time off the CPU.
+CPU_STAGES: Tuple[str, ...] = tuple(
+    EVENT_NAMES[c] for c in (
+        EV_SCAN_PACK, EV_SCAN_LAUNCH, EV_SCAN_WAIT, EV_CONFIRM_FOLD,
+        EV_SIDE_SCAN, EV_SIDE_CONFIRM, EV_SIDE_LOCK, EV_SIDE_PREFILTER,
+        EV_SIDE_LAUNCH, EV_SIDE_FETCH, EV_SIDE_LOCK_WAIT))
+_CPU_TIMED = frozenset(c for c, n in EVENT_NAMES.items() if n in CPU_STAGES)
 
 #: the profiler-trace names, built once (``ipt:<name>``)
 _ANNOTATION_NAMES: Dict[int, str] = {
@@ -868,6 +913,38 @@ def request_tag(request_id: str) -> int:
     """Stable-within-process int tag for a wire request id (the flow id
     stitching submit → verdict across threads)."""
     return hash(request_id) & 0x7FFFFFFFFFFFFFFF
+
+
+try:
+    _CLK_TCK = os.sysconf("SC_CLK_TCK")
+except (AttributeError, ValueError, OSError):
+    _CLK_TCK = 100
+
+
+def _own_cpu_clock() -> Optional[int]:
+    """The calling thread's CPU clock id, for others to read."""
+    try:
+        return time.pthread_getcpuclockid(threading.get_ident())
+    except (AttributeError, OSError):
+        return None
+
+
+def _thread_cpu_s(clock: Optional[int], native_id: int) -> Optional[float]:
+    """One live thread's CPU seconds: its CPU clock, else the kernel's
+    per-task ``utime + stime`` (clock ticks); None where neither reads."""
+    if clock is not None:
+        try:
+            return time.clock_gettime(clock)
+        except OSError:
+            pass
+    try:
+        with open("/proc/self/task/%d/stat" % native_id, "rb") as f:
+            # the fields after the command name: utime and stime are
+            # the 12th and 13th
+            fields = f.read().rsplit(b")", 1)[1].split()
+        return (int(fields[11]) + int(fields[12])) / _CLK_TCK
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 class _ThreadRing:
@@ -940,7 +1017,7 @@ class _NoSpan:
     """``flight.span`` with the recorder off: nothing, ``us`` 0."""
 
     __slots__ = ()
-    us = 0
+    us = cpu_us = 0
 
     def begin(self):
         return self
@@ -961,13 +1038,15 @@ class _Span:
     """One open span (see :meth:`FlightRecorder.span`)."""
 
     __slots__ = ("_rec", "_code", "_tag", "_arg", "_cycle", "_ring",
-                 "_t0", "_ann", "us")
+                 "_t0", "_c0", "_ann", "_into", "us", "cpu_us")
 
     def __init__(self, rec: "FlightRecorder", code: int,
-                 cycle: Optional[int], tag: int, arg: int):
+                 cycle: Optional[int], tag: int, arg: int,
+                 into: Optional[Dict[str, List[int]]]):
         self._rec, self._code, self._tag, self._arg = rec, code, tag, arg
         self._cycle = cycle
-        self.us = 0
+        self._into = into
+        self.us = self.cpu_us = 0
 
     def begin(self):
         """Open the span (``with`` does; a span that opens in one
@@ -984,19 +1063,37 @@ class _Span:
         ann.__enter__()
         # concheck: ok a span object is opened and closed by one thread
         t0 = self._t0 = time.monotonic_ns()
+        # the CPU clock read inside the wall clock's two reads, so a
+        # span's CPU time never passes its wall time (where the clock
+        # advances in ticks, a span's reading is only a sample)
+        # concheck: ok a span object is opened and closed by one thread
+        self._c0 = time.thread_time_ns() if self._code in _CPU_TIMED else -1
         ring.record(t0, self._code, PH_B, self._cycle, self._tag,
                     self._arg)
         return self
 
     def end(self) -> None:
+        c0 = self._c0
+        cpu_ns = time.thread_time_ns() - c0 if c0 >= 0 else 0
         t1 = time.monotonic_ns()
         self._ring.record(t1, self._code, PH_E, self._cycle, self._tag, 0)
         self._ann.__exit__(None, None, None)
+        ns = t1 - self._t0
         # concheck: ok a span object is opened and closed by one thread
-        self.us = us = (t1 - self._t0) // 1000
+        self.us = us = ns // 1000
+        # concheck: ok a span object is opened and closed by one thread
+        self.cpu_us = cpu_us = cpu_ns // 1000
         if self._code in ACCUMULATED:
             self._rec._accumulate(self._code, self._cycle, us,
-                                  self._ring.lane)
+                                  self._ring.lane,
+                                  cpu_us if c0 >= 0 else None)
+        into = self._into
+        if into is not None:
+            # the caller's per-request sums, in ns: a short span adds
+            # what µs would floor away
+            cell = into.setdefault(EVENT_NAMES[self._code], [0, 0])
+            cell[0] += ns
+            cell[1] += cpu_ns
 
     __enter__ = begin
 
@@ -1028,8 +1125,16 @@ class FlightRecorder:
         #: by spans on any thread, taken by the batcher when the cycle's
         #: verdicts resolve
         self._acc: Dict[int, Tuple[Dict[str, int],
-                                   Dict[Tuple[int, str], int]]] = {}
+                                   Dict[Tuple[int, str], int],
+                                   Dict[str, int]]] = {}
         self._acc_lock = named_lock("FlightRecorder._acc_lock")
+        #: every thread that ever registered a ring (kept across
+        #: ``configure``): thread -> [root, its CPU clock id or None,
+        #: native id, CPU seconds last read]; a dead thread's last
+        #: reading moves to ``_dead_cpu_s`` by root, so a sum by root
+        #: never goes back (``thread_cpu_seconds``)
+        self._threads: Dict[threading.Thread, list] = {}
+        self._dead_cpu_s: Dict[str, float] = {}
 
     # ------------------------------------------------------- lifecycle
 
@@ -1064,7 +1169,8 @@ class FlightRecorder:
         self._register(root)
 
     def _register(self, root: Optional[str]) -> _ThreadRing:
-        name = threading.current_thread().name
+        thread = threading.current_thread()
+        name = thread.name
         if root is None:
             root = _THREAD_SUFFIX_RE.sub("", name) or name
         with self._lock:
@@ -1079,6 +1185,16 @@ class FlightRecorder:
             self._next_tid += 1
             self._rings.append(ring)
             gen = self._gen
+            cell = self._threads.get(thread)
+            if cell is not None:
+                cell[0] = root
+            else:
+                if len(self._threads) >= self.MAX_RINGS:
+                    self._fold_dead_threads()
+                # the clock id is taken here, on the thread itself: a
+                # scrape never asks for a thread that may have exited
+                self._threads[thread] = [root, _own_cpu_clock(),
+                                         threading.get_native_id(), 0.0]
         self._tls.ring = ring
         self._tls.gen = gen
         return ring
@@ -1132,19 +1248,34 @@ class FlightRecorder:
         self.record(code, PH_I, cycle, tag, arg)
 
     def span(self, code: int, cycle: Optional[int] = None,
-             tag: int = 0, arg: int = 0):
+             tag: int = 0, arg: int = 0,
+             into: Optional[Dict[str, List[int]]] = None):
         """The span primitive — ``with flight.span(EV_X, arg=n) as sp:``.
         From this one call site the span (a) writes the ring's
         begin/end events under the thread's ambient cycle id, (b) adds
         its elapsed µs to that cycle's accumulator when its code is in
-        ``ACCUMULATED`` (``take`` hands them to the batcher), and (c)
+        ``ACCUMULATED`` (``take_all`` hands them to the batcher), and (c)
         lies in a profiler trace as ``ipt:<name>`` with ``cycle`` and
         ``n`` (TraceAnnotation).  ``sp.us`` holds the elapsed µs after
         the block.  For per-dispatch spans: a dozen or so a cycle.
+
+        A span of a stage in ``CPU_STAGES`` also reads the thread's CPU
+        clock (``time.thread_time_ns``) at both ends: ``sp.cpu_us``,
+        added to the accumulator beside the wall µs.  The wall time less
+        the CPU time is the time the thread spent off the CPU inside it:
+        waiting for the interpreter lock, blocked in a call that releases
+        it (a device copy, a pipe, a lock, a sleep), or preempted by
+        another process on the host (the walker processes among them).
+        For a span made only of calls into JAX that enqueue work
+        (``scan_launch``, ``side_launch``) that is the wait for the lock;
+        for one that blocks on the device (``scan_wait``, ``side_fetch``)
+        it is mostly the device.  ``into``: a caller's dict, ``{name:
+        [ns, CPU ns]}``, to which the span adds itself when it closes
+        (the side lane sums a request's spans there).
         Recorder off: one attribute read, ``sp.us`` stays 0."""
         if not self.enabled:
             return _NO_SPAN
-        return _Span(self, code, cycle, tag, arg)
+        return _Span(self, code, cycle, tag, arg, into)
 
     def span_at(self, code: int, t0_ns: int, t1_ns: int,
                 cycle: Optional[int] = None, tag: int = 0,
@@ -1166,7 +1297,7 @@ class FlightRecorder:
                              ring.lane)
 
     def _accumulate(self, code: int, cycle: int, us: int,
-                    lane: int = -1) -> None:
+                    lane: int = -1, cpu_us: Optional[int] = None) -> None:
         with self._acc_lock:
             acc = self._acc.get(cycle)
             if acc is None:
@@ -1174,25 +1305,33 @@ class FlightRecorder:
                     # a cycle nobody took (a library caller's, a side
                     # lane's): the oldest goes, the dict stays small
                     del self._acc[next(iter(self._acc))]
-                acc = self._acc[cycle] = ({}, {})
-            sub, lanes = acc
+                acc = self._acc[cycle] = ({}, {}, {})
+            sub, lanes, cpu = acc
             name = ACCUMULATED[code]
             sub[name] = sub.get(name, 0) + us
+            if cpu_us is not None:
+                cpu[name] = cpu.get(name, 0) + cpu_us
             if lane >= 0:
                 lanes[lane, name] = lanes.get((lane, name), 0) + us
 
     def take(self, cycle: int) -> Dict[str, int]:
         """Pop what the spans of ``cycle`` accumulated, ``{sub-stage:
         µs}`` (empty with the recorder off or nothing recorded)."""
-        return self.take_with_lanes(cycle)[0]
+        return self.take_all(cycle)[0]
 
     def take_with_lanes(self, cycle: int) -> Tuple[
             Dict[str, int], Dict[Tuple[int, str], int]]:
         """:meth:`take`, and beside it what the same spans accumulated
         per lane, ``{(lane, sub-stage): µs}``: the spans that closed on
         a thread with an ambient lane (:meth:`set_lane`)."""
+        return self.take_all(cycle)[:2]
+
+    def take_all(self, cycle: int) -> Tuple[
+            Dict[str, int], Dict[Tuple[int, str], int], Dict[str, int]]:
+        """:meth:`take_with_lanes`, and third the CPU µs of the same
+        spans, ``{sub-stage: µs}`` (back-dated spans add none)."""
         with self._acc_lock:
-            return self._acc.pop(cycle, None) or ({}, {})
+            return self._acc.pop(cycle, None) or ({}, {}, {})
 
     def set_cycle(self, cycle: int) -> None:
         """Ambient cycle id for subsequent events on THIS thread (the
@@ -1228,6 +1367,38 @@ class FlightRecorder:
         return fn(*args)
 
     # ---------------------------------------------------------- export
+
+    def _fold_dead_threads(self) -> None:
+        """Move exited threads' last readings to their root (under
+        ``_lock``)."""
+        for thread, (root, _clock, _tid, last_s) in list(
+                self._threads.items()):
+            if not thread.is_alive():
+                self._dead_cpu_s[root] = self._dead_cpu_s.get(root, 0.0) \
+                    + last_s
+                del self._threads[thread]
+
+    def thread_cpu_seconds(self) -> Optional[Dict[str, float]]:
+        """CPU seconds of every thread that has registered a ring, summed
+        by root name (``ipt_thread_cpu_seconds_total{thread=}``), read now:
+        a live thread's CPU clock (``time.clock_gettime`` of its
+        ``pthread_getcpuclockid``), else its ``/proc/self/task/<tid>/stat``
+        utime + stime; an exited thread's last reading.  Never less than
+        the call before.  None where no live thread's time could be
+        read."""
+        read = False
+        with self._lock:
+            for thread, cell in self._threads.items():
+                if thread.is_alive():
+                    s = _thread_cpu_s(cell[1], cell[2])
+                    if s is not None:
+                        read = True
+                        cell[3] = max(cell[3], s)
+            self._fold_dead_threads()
+            out = dict(self._dead_cpu_s)
+            for root, _clock, _tid, last_s in self._threads.values():
+                out[root] = out.get(root, 0.0) + last_s
+        return out if read else None
 
     def dropped(self) -> int:
         with self._lock:

@@ -866,3 +866,129 @@ def test_gc_hook_takes_no_lock_and_registers_nothing():
         watch.uninstall()
     assert watch.collections_by_gen[2] == before + 1
     assert done == [0]                     # counted, nothing registered
+
+
+# ------------------------------------------- CPU time inside a stage span
+
+def _spin(seconds: float) -> int:
+    """Pure Python for ``seconds`` of wall time: holds the interpreter
+    lock but at the switch interval."""
+    n, end = 0, time.monotonic() + seconds
+    while time.monotonic() < end:
+        n += 1
+    return n
+
+
+def test_a_busy_span_reads_its_cpu_time_and_a_sleep_none(fake_annotation):
+    rec = FlightRecorder()
+    with rec.span(trace_mod.EV_SCAN_LAUNCH) as busy:
+        _spin(0.1)
+    with rec.span(trace_mod.EV_SCAN_LAUNCH) as asleep:
+        time.sleep(0.1)
+    assert 0.4 * busy.us <= busy.cpu_us <= busy.us
+    assert asleep.us >= 100_000 and asleep.cpu_us <= 0.1 * asleep.us
+    # the cycle's accumulator carries the CPU beside the wall
+    sub, _lanes, cpu = rec.take_all(0)
+    assert sub == {"scan_launch": busy.us + asleep.us}
+    assert cpu == {"scan_launch": busy.cpu_us + asleep.cpu_us}
+
+
+def test_a_span_that_waits_for_the_interpreter_lock_reads_it_off_cpu(
+        fake_annotation):
+    """A thread spinning in pure Python takes the interpreter lock for a
+    switch interval at a time: a span around the same work on another
+    thread lasts longer than its CPU time by about the other's share."""
+    import threading
+
+    rec = FlightRecorder()
+    stop = threading.Event()
+
+    def hog():
+        while not stop.is_set():
+            _spin(0.01)
+
+    other = threading.Thread(target=hog, daemon=True)
+    other.start()
+    try:
+        time.sleep(0.02)
+        with rec.span(trace_mod.EV_SIDE_LAUNCH) as sp:
+            n = 0
+            for _ in range(1_000_000):      # bytecode: yields the lock
+                n += 1
+    finally:
+        stop.set()
+        other.join(timeout=10)
+    assert sp.us >= 10_000
+    assert sp.us - sp.cpu_us >= 0.25 * sp.us, (sp.us, sp.cpu_us)
+
+
+def test_only_stage_spans_read_the_cpu_clock_and_back_dated_ones_none(
+        fake_annotation):
+    rec = FlightRecorder()
+    with rec.span(trace_mod.EV_CYCLE) as cycle:
+        _spin(0.02)
+    assert cycle.us >= 20_000 and cycle.cpu_us == 0
+    rec.span_at(trace_mod.EV_SCAN_PACK, 0, 5_000, cycle=3)
+    assert rec.take_all(3) == ({"scan_pack": 5}, {}, {})
+    assert set(trace_mod.CPU_STAGES) <= (
+        set(trace_mod.ACCUMULATED.values()) | set(trace_mod.SIDE_STAGES))
+    for back_dated in ("lane_handoff", "confirm_ipc", "side_wait",
+                       "side_walk"):
+        assert back_dated not in trace_mod.CPU_STAGES
+    # a confirm share's span is a waiter blocked on its walker's pipe
+    with rec.span(trace_mod.EV_CONFIRM) as walk:
+        _spin(0.02)
+    assert walk.us >= 20_000 and walk.cpu_us == 0
+
+
+def test_a_span_adds_itself_to_its_callers_sums_in_ns(fake_annotation):
+    rec = FlightRecorder()
+    spent = {}
+    for _ in range(3):
+        with rec.span(trace_mod.EV_SIDE_FETCH, into=spent) as sp:
+            _spin(0.005)
+    ns, cpu_ns = spent["side_fetch"]
+    assert list(spent) == ["side_fetch"]
+    assert 3 * 5_000_000 <= ns and 0 <= cpu_ns <= ns
+    assert ns // 1000 >= sp.us
+    with trace_mod._NO_SPAN as off:
+        pass
+    assert off.us == off.cpu_us == 0
+
+
+def test_stage_histogram_sums_the_cpu_its_observations_carry():
+    h = trace_mod.Histogram()
+    h.observe(900, 300)
+    h.observe(100)
+    assert (h.total, h.sum_us, h.cpu_us) == (2, 1000, 300)
+    h.reset()
+    assert (h.total, h.sum_us, h.cpu_us) == (0, 0, 0)
+
+
+def test_thread_cpu_is_read_by_root_and_never_goes_back(fake_annotation):
+    import threading
+
+    rec = FlightRecorder()
+    assert rec.thread_cpu_seconds() is None          # no thread yet
+    go, done = threading.Event(), threading.Event()
+
+    def worker():
+        rec.register_thread("spinner")
+        _spin(0.1)
+        go.set()
+        done.wait(10)
+
+    t = threading.Thread(target=worker)
+    t.start()
+    try:
+        assert go.wait(10)
+        live = rec.thread_cpu_seconds()
+        assert live["spinner"] >= 0.03
+    finally:
+        done.set()
+        t.join(10)
+    rec.register_thread("main")      # a live thread to read
+    rec.reset()                      # rings dropped, readings kept
+    after = rec.thread_cpu_seconds()
+    assert after["spinner"] >= live["spinner"]
+    assert "spinner" in rec._dead_cpu_s and t not in rec._threads

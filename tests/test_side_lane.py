@@ -525,6 +525,56 @@ def test_the_lone_walk_books_no_batched_sub_stage():
         b.close()
 
 
+#: the side stages that open up side_scan and side_confirm
+SIDE_PARTS = ("side_prefilter", "side_launch", "side_fetch",
+              "side_lock_wait", "side_walk")
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_one_rerouted_request_books_each_part_of_its_stages_once(workers):
+    """After one rerouted request the head's prefilter, the waves'
+    launches, the scan calls' waits for the device, the waits for the
+    swap lock (both side threads) and the walk are each observed once;
+    the first three lie inside ``side_scan``, the walk inside
+    ``side_confirm``; no stage's CPU time passes its wall time, and
+    ``/metrics`` renders one CPU counter a stage that reads it, and the
+    side threads' CPU."""
+    from ingress_plus_tpu.analysis.promlint import check_exposition
+    from ingress_plus_tpu.serve.server import ServeLoop
+    from ingress_plus_tpu.utils.trace import CPU_STAGES, flight
+
+    flight.configure(enabled=True)
+    b = small_batcher(workers=workers)
+    try:
+        v = b.submit(oversized(ATTACK, "a")).result(timeout=120)
+        assert v.attack and not v.fail_open
+        h = b.sidehist
+        assert {n: h[n].total for n in SIDE_STAGES} == dict.fromkeys(
+            SIDE_STAGES, 1)
+        assert (h["side_prefilter"].sum_us + h["side_launch"].sum_us
+                + h["side_fetch"].sum_us <= h["side_scan"].sum_us)
+        assert 0 < h["side_walk"].sum_us <= h["side_confirm"].sum_us
+        assert h["side_launch"].sum_us > 0 and h["side_launch"].cpu_us > 0
+        hists = {**b.subhist, **h}
+        for name in CPU_STAGES:
+            assert 0 <= hists[name].cpu_us <= hists[name].sum_us, name
+        text = ServeLoop(b, socket_path="/tmp/ipt-side-parts.sock"
+                         )._metrics_text()
+    finally:
+        b.close()
+    assert check_exposition(text) == []
+    values = {ln.rsplit(" ", 1)[0]: float(ln.rsplit(" ", 1)[1])
+              for ln in text.splitlines() if ln and not ln.startswith("#")}
+    assert [k for k in values if k.startswith("ipt_stage_cpu_us_total")] \
+        == ['ipt_stage_cpu_us_total{stage="%s"}' % s for s in CPU_STAGES]
+    for stage in CPU_STAGES:
+        assert (values['ipt_stage_cpu_us_total{stage="%s"}' % stage]
+                <= values['ipt_stage_us_sum{stage="%s"}' % stage])
+    assert values['ipt_thread_cpu_seconds_total{thread="oversized"}'] > 0
+    assert 'ipt_thread_cpu_seconds_total{thread="oversized_finish"}' \
+        in values
+
+
 def test_a_walker_killed_mid_walk_fails_the_request_open():
     b = small_batcher(SMALL_RULES + BACKTRACK)
     try:
