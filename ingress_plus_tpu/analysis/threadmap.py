@@ -142,11 +142,11 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
         name="oversized_finish",
         entries=("serve/batcher.py::Batcher._run_oversized_finish",),
         concurrent=False,
-        description="ipt-oversized-finish: confirms a scanned oversized "
-                    "body (a batch of one, walked in a walker process "
-                    "where one holds the generation, else inline) and "
-                    "resolves its verdict while the side worker scans "
-                    "the next one"),
+        description="ipt-oversized-finish: confirms a scanned batch of "
+                    "oversized bodies (one a share on the last walker "
+                    "processes that hold the generation, else inline) "
+                    "and resolves their verdicts while the side worker "
+                    "scans the next batch"),
     ThreadRoot(
         name="shadow",
         entries=("control/rollout.py::RolloutController._shadow_run",),

@@ -606,7 +606,7 @@ class ConfirmJob:
 
     __slots__ = ("requests", "rule_hits", "results", "pending", "memo",
                  "launch_us", "join_us", "share_workers", "memo_hits",
-                 "memo_misses", "cycle", "t0", "walk_ns")
+                 "memo_misses", "cycle", "t0", "walk_ns", "share_walk_ns")
 
     def __init__(self, requests, rule_hits) -> None:
         self.requests = requests
@@ -634,6 +634,8 @@ class ConfirmJob:
         #: the walk's own time, summed over the shares: each walker's
         #: as it reports it, or the inline walk's ``confirm_walk`` span
         self.walk_ns = 0
+        #: the same by request: the walk of the share it went out in
+        self.share_walk_ns: List[int] = [0] * len(requests)
 
 
 class ConfirmPool:
@@ -649,12 +651,14 @@ class ConfirmPool:
     is, and so is a batch that finds fewer than two workers holding its
     generation (walkers still starting, a generation nobody installed
     ahead of its traffic: the install goes out and a later batch finds
-    it).  The one caller that asks otherwise (``lone_to_walker``) is
-    the oversized side lane's finish thread: its lone request is a body
-    past 16 KiB unpacked, whose walk of tens of ms would hold the
-    interpreter lock beside the side lane's scan thread, so it goes to
-    one walker holding the generation and the caller blocks on the
-    pipe; with none such it is walked inline as well.  So a share's
+    it).  The one caller dealt otherwise (``side_lane``, the side
+    lane's deal) is the oversized side lane's finish thread: each of its
+    requests is a body past 16 KiB unpacked, whose walk of tens of ms
+    would hold the interpreter lock beside the side lane's scan thread,
+    so its batch, of one or more, goes out one request a share to the
+    walkers holding the generation, the LAST of them first, and the
+    caller blocks on the pipes; with none such it is walked inline as
+    well.  So a share's
     hang budget covers a walk and nothing else.  The pool is
     ruleset-free — the batcher carries ONE pool across hot swaps like
     the stats object, installing each new generation in the walkers
@@ -730,23 +734,26 @@ class ConfirmPool:
         return ready, posted
 
     def deal(self, pl, n: int,
-             lone_to_walker: bool = False) -> List[_ConfirmWorker]:
+             side_lane: bool = False) -> List[_ConfirmWorker]:
         """The workers a batch of ``n`` requests of ``pl`` goes out to,
         a share each (request ``i`` to share ``i % len``): no worker
         gets more than ``ceil(n / N)`` and no more workers are used
         than that takes.  Empty: the caller walks inline — an inline
         pool, a batch of one (nothing to spread, and the hop would cost
         as much as the walk of a batched request), or fewer than two
-        walkers holding the generation yet.  ``lone_to_walker``: a
-        batch of one goes to one walker that holds the generation, the
-        last of them (batched shares are dealt from the first), so that
-        a long walk leaves the caller's interpreter lock alone; the
+        walkers holding the generation yet.  ``side_lane``, the side
+        lane's deal: a batch of any ``n`` >= 1 goes out one request a
+        share to the last ``n`` walkers that hold the generation (all
+        of them where there are fewer), one such being enough, so that
+        its long walks run side by side, leave the caller's interpreter
+        lock alone, and never queue in front of a batched share on a
+        walker's pipe (batched shares are dealt from the first); the
         side lane's finish asks it, and nothing else does."""
-        if not self._workers or n < (1 if lone_to_walker else 2):
+        if not self._workers or n < (1 if side_lane else 2):
             return []
         ready, _posted = self._post_installs(pl)
-        if n == 1:
-            dealt = ready[-1:]
+        if side_lane:
+            dealt = ready[-n:]
         elif len(ready) < 2:
             return []
         else:
@@ -855,13 +862,14 @@ def _walk_inline(pl, job: ConfirmJob, tt: bool, trace_cycle: int) -> None:
             faults.set_current_tenant(None)
         faults.set_current_confirm_worker(None)
     job.walk_ns = sp.us * 1000
+    job.share_walk_ns = [job.walk_ns] * len(requests)
     # no hop: the dispatch's confirm_ipc reads 0, beside its walk
     now = time.monotonic_ns()
     flight.span_at(EV_CONFIRM_IPC, now, now, cycle=trace_cycle)
 
 
 def launch_confirm(pl, requests, rule_hits: np.ndarray,
-                   lone_to_walker: bool = False) -> ConfirmJob:
+                   side_lane: bool = False) -> ConfirmJob:
     """Start one finalize batch's confirm phase.  Inline (an inline
     pool, or a batch of one): the whole walk runs NOW on the calling
     thread (the classic serial path).  Pooled: each share is put on its
@@ -870,9 +878,10 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray,
     immediately — the batcher's loop overlaps the in-flight
     confirm with the next cycle's scan dispatch, the same software-
     pipelining move PR 7 made for host→device transfer.
-    ``lone_to_walker`` (:meth:`ConfirmPool.deal`): a batch of one goes
-    to a walker too — the side lane's finish, whose one body's walk
-    must not hold the interpreter lock its scan thread needs.  Its spans
+    ``side_lane`` (:meth:`ConfirmPool.deal`): the side lane's deal, a
+    batch of one or more from the last walkers, one request a share —
+    the side lane's finish, whose bodies' walks must not hold the
+    interpreter lock its scan thread needs.  Its spans
     carry the caller's ambient cycle, which on that thread is never a
     batched cycle's, so ``confirm_walk`` / ``confirm_ipc`` stay the
     batched dispatches' alone."""
@@ -893,7 +902,7 @@ def launch_confirm(pl, requests, rule_hits: np.ndarray,
     # scan still stitches to the cycle whose verdicts it computes
     trace_cycle = job.cycle = flight.cycle()
     trace_lane = flight.lane()
-    workers = pool.deal(pl, n, lone_to_walker)
+    workers = pool.deal(pl, n, side_lane)
     k = len(workers)
     job.share_workers = [w.worker_index for w in workers]
     if k == 0:
@@ -1001,6 +1010,7 @@ def join_confirm(pl, job: ConfirmJob) -> List[Optional[ConfirmResult]]:
             continue
         for i, res in zip(idxs, results):
             job.results[i] = res
+            job.share_walk_ns[i] = walk_ns
         job.memo_hits += hits
         job.memo_misses += misses
         job.walk_ns += walk_ns

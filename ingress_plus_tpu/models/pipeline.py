@@ -1238,16 +1238,17 @@ class DetectionPipeline:
 
     def finalize_launch(self, requests: List[Request],
                         rule_hits: np.ndarray,
-                        lone_to_walker: bool = False):
+                        side_lane: bool = False):
         """Start the confirm phase for one batch of already-masked
         prefilter hits (docs/CONFIRM_PLANE.md): the per-request
         candidate walks run on the confirm pool — inline (the classic
         serial path) at ``--confirm-workers 1`` and for a batch of one,
         as request shares in the workers' walker processes otherwise.
-        ``lone_to_walker``: a batch of one goes to a walker too (the
-        oversized side lane's finish, ``StreamEngine.finish``).
+        ``side_lane``: the side lane's deal (``ConfirmPool.deal``), a
+        batch of one or more from the last walkers, one request a share
+        (the oversized side lane's finish, ``StreamEngine.finish_all``).
         Returns the job for :meth:`finalize_join`."""
-        return launch_confirm(self, requests, rule_hits, lone_to_walker)
+        return launch_confirm(self, requests, rule_hits, side_lane)
 
     def finalize(self, requests: List[Request], rule_hits: np.ndarray,
                  t0: float, observe_rules: bool = True) -> List[Verdict]:

@@ -432,6 +432,9 @@ class BatcherStats:
         default_factory=lambda: {"raw": 0, "unpack": 0})
     oversized_bytes: Dict[str, int] = field(
         default_factory=lambda: {"raw": 0, "unpack": 0})
+    # the side lane's scan batches (ipt_oversized_batches_total): the
+    # rerouted requests taken together, a lone one a batch of one
+    oversized_batches: int = 0
     # fail-safe plane (docs/ROBUSTNESS.md)
     hangs: int = 0                 # device-lane hang-budget overruns
     cpu_fallback_batches: int = 0  # batches served breaker-open (CPU)
@@ -851,75 +854,90 @@ class Batcher:
                 self._oversized_by_tenant.pop(tenant, None)
 
     def _run_oversized(self) -> None:
-        """The side lane's scan stage: one request at a time, handed
-        on to the finish stage with its stream scanned."""
+        """The side lane's scan stage: the batch of rerouted requests
+        waiting when it is free (the first taken, then every other one
+        already queued, without a wait: one alone is a batch of one),
+        handed on to the finish stage with their streams scanned."""
         flight.register_thread("oversized")
         while not self._stop.is_set():
             try:
-                ts, request, plan, fut = self._oversized_q.get(timeout=0.1)
+                batch = [self._oversized_q.get(timeout=0.1)]
             except queue.Empty:
                 continue
-            handed_on = False
-            try:
-                flight.begin(EV_OVERSIZED, cycle=0, tag=request.tenant,
-                             arg=len(request.body))
+            while len(batch) < self._oversized_q.maxsize:
                 try:
-                    handed_on = self._detect_oversized(ts, request, plan, fut)
+                    batch.append(self._oversized_q.get_nowait())
+                except queue.Empty:
+                    break
+            handed_on: List[Tuple] = []
+            try:
+                flight.begin(EV_OVERSIZED, cycle=0, tag=len(batch),
+                             arg=sum(len(item[1].body) for item in batch))
+                try:
+                    handed_on = self._detect_oversized(batch)
                 finally:
-                    flight.end(EV_OVERSIZED, cycle=0,
-                               tag=request.tenant)
+                    flight.end(EV_OVERSIZED, cycle=0, tag=len(batch))
             finally:
-                if not handed_on:
-                    self._release_oversized_slot(request.tenant)
+                for item in batch:
+                    if not any(item is e for e in handed_on):
+                        self._release_oversized_slot(item[1].tenant)
 
     def _run_oversized_finish(self) -> None:
-        """The side lane's finish stage: confirm, verdict, slot."""
+        """The side lane's finish stage: confirm, verdicts, slots."""
         flight.register_thread("oversized_finish")
         while not self._stop.is_set():
             try:
-                item = self._oversized_fin_q.get(timeout=0.1)
+                entries, spent = self._oversized_fin_q.get(timeout=0.1)
             except queue.Empty:
                 continue
             try:
-                self._finish_oversized(*item)
+                self._finish_oversized(entries, spent)
             finally:
-                self._release_oversized_slot(item[1].tenant)
+                for entry in entries:
+                    self._release_oversized_slot(entry[1].tenant)
 
     @contextmanager
     def _side_hold(self, spent: Dict[str, List[int]]):
         """The swap lock as the side lane takes it: the wait for it
         (``side_lock_wait``) and one hold (``side_lock``), both spans
-        added to ``spent``, the request's sums (observed once in
-        ``_observe_side``)."""
+        added to ``spent``, the batch's sums (observed once a request
+        in ``_observe_side``)."""
         wait = flight.span(EV_SIDE_LOCK_WAIT, into=spent).begin()
         with self._swap_lock:
             wait.end()
             with flight.span(EV_SIDE_LOCK, into=spent):
                 yield
 
-    def _observe_side(self, spent: Dict[str, List[int]]) -> None:
-        """A rerouted request's summed side-lane spans, one observation
-        each with its CPU µs (0 for a part it had none of)."""
+    def _observe_side(self, spent: Dict[str, List[int]],
+                      walk_ns: int) -> None:
+        """A rerouted request's summed side-lane spans — its batch's —
+        and the walk of its own confirm share, one observation each with
+        its CPU µs (0 for a part it had none of)."""
         for name in SUMMED_SIDE_STAGES:
-            ns, cpu_ns = spent.get(name, (0, 0))
+            ns, cpu_ns = ((walk_ns, 0) if name == "side_walk"
+                          else spent.get(name, (0, 0)))
             self.sidehist[name].observe(ns // 1000, cpu_ns // 1000)
 
-    def _detect_oversized(self, ts: float, request: Request, plan,
-                        fut: "Future[Verdict]") -> bool:
-        """Scan one oversized request through the stream engine (the
-        side lane's scan thread) and hand it to the finish stage; False
-        where its verdict is resolved here, fail-open.
+    def _detect_oversized(self, batch: List[Tuple]) -> List[Tuple]:
+        """Scan a batch of oversized requests, ``(ts, request, plan,
+        fut)`` each, through the stream engine together (the side
+        lane's scan thread) and hand them to the finish stage as one
+        item; returns the entries handed on, the others resolved here,
+        fail-open.
 
-        What is scanned: the batched path's own scan stream of the
+        What is scanned: the batched path's own scan stream of each
         body, ``unpack_body(body, headers, parsers_off)`` with its
         scan-only segments (inflated base, url-decoded form copy,
         extracted JSON / XML strings, base64 decode), for BOTH reroute
         kinds, fed as ONE sequence through the stream's incremental
-        variant chain in waves of 16,384 steps while that much of a
-        chunk is pending and of 2,048 for its rest (``stream.py
-        wave_width``), with the automaton state carried from each wave
-        to the next (on the device inside a chunk of
-        ``OVERSIZE_CHUNK`` bytes, through the host between chunks): the
+        variant chain ``OVERSIZE_CHUNK`` bytes at a time.  One
+        ``StreamEngine.scan`` call a chunk index takes the i-th chunk
+        of every body that still has one (the last with its stream's
+        flush), so the batch's rows share each wave: waves of 16,384
+        steps while that much of the longest row's chunk is pending and
+        of 2,048 for its rest (``stream.py wave_width``), with each
+        row's automaton state carried from each wave to the next (on
+        the device inside a call, through the host between calls): the
         candidates are the ones the batched prefilter would have found
         had its rows no 16 KiB bound, however many bytes a variant
         deletes between two halves of a factor.  What is confirmed
@@ -928,22 +946,25 @@ class Batcher:
         itself — never the unpacked copy, whose url-decoded segment it
         would decode a second time (ROADMAP F1).
 
-        The swap lock is taken once a wave for the generation check
-        (and for the head's prefilter and the verdict's fold), never for
-        a chunk, a wave's launch or device time, or the confirm walk: a
-        batched cycle never waits out a wave.  The unpack runs off-lock.
-        A ruleset hot-swap mid-body is detected by the stream engine's
-        version check and fails open, same as in-flight wire streams."""
-        kind, body, headers = plan
+        The swap lock is taken once for the heads' prefilter, once a
+        wave for the generation check (and twice for the verdicts'
+        mask and fold), never for a chunk, a wave's launch or device
+        time, or the confirm walks: a batched cycle never waits out a
+        wave.  The unpack runs off-lock, a body at a time; one whose
+        unpack raises fails open alone.  A ruleset hot-swap mid-scan is
+        detected by the stream engine's version check and fails the
+        batch open, as it fails in-flight wire streams."""
         t_taken = time.perf_counter()
-        st = self.stats
-        st.oversized_requests[kind] += 1
-        st.oversized_bytes[kind] += len(body)
         now_ns = time.monotonic_ns()
-        wait_us = int((t_taken - ts) * 1e6)
-        flight.span_at(EV_SIDE_WAIT, now_ns - wait_us * 1000, now_ns,
-                       tag=request.tenant)
-        self.sidehist["side_wait"].observe(wait_us)
+        st = self.stats
+        st.oversized_batches += 1
+        for ts, request, (kind, body, _headers), _fut in batch:
+            st.oversized_requests[kind] += 1
+            st.oversized_bytes[kind] += len(body)
+            wait_us = int((t_taken - ts) * 1e6)
+            flight.span_at(EV_SIDE_WAIT, now_ns - wait_us * 1000, now_ns,
+                           tag=request.tenant)
+            self.sidehist["side_wait"].observe(wait_us)
         if self.breaker.state != CircuitBreaker.CLOSED:
             # oversized scans ride the DEFAULT device (the stream
             # engine is not lane-pinned), whose health the PRIMARY
@@ -952,61 +973,97 @@ class Batcher:
             # Healthy sibling lanes don't help here (reviewer catch:
             # an any-lane-closed gate let this worker scan a wedged
             # default device).
-            self._resolve_oversized(ts, request, fut, None)
-            return False
-        # the request's summed spans, {name: [ns, CPU ns]}, filled on
-        # both side threads, one after the other
+            for ts, request, _plan, fut in batch:
+                self._resolve_oversized(ts, request, fut, None)
+            return []
+        # the batch's summed spans, {name: [ns, CPU ns]}, filled on
+        # both side threads, one after the other, and observed by each
+        # of its requests
         spent: Dict[str, List[int]] = {}
         hold = functools.partial(self._side_hold, spent)
         engine = self.stream_engine
+        taken, metas, scans = [], [], []
         try:
-            with flight.span(EV_SIDE_SCAN, arg=len(body)) as sp_scan:
-                # DoS-bounded inflate + extraction, OFF the lock.  The
-                # stream's own unpackers stay off: what it is fed IS
-                # unpacked, and a second inflate, base64 or gRPC pass
-                # over it would scan rows the batched path never has
-                scanned = unpack_body(body, headers, request.parsers_off)
-                meta = replace(request, body=b"",
-                               parsers_off=frozenset(request.parsers_off)
-                               | _STREAM_UNPACKERS)
+            with flight.span(EV_SIDE_SCAN, arg=sum(
+                    len(item[2][1]) for item in batch)) as sp_scan:
+                for entry in batch:
+                    ts, request, (_kind, body, headers), fut = entry
+                    try:
+                        # DoS-bounded inflate + extraction, OFF the
+                        # lock.  The stream's own unpackers stay off:
+                        # what it is fed IS unpacked, and a second
+                        # inflate, base64 or gRPC pass over it would
+                        # scan rows the batched path never has
+                        scanned = unpack_body(body, headers,
+                                              request.parsers_off)
+                        meta = replace(request, body=b"", parsers_off=(
+                            frozenset(request.parsers_off)
+                            | _STREAM_UNPACKERS))
+                    except Exception:
+                        self._resolve_oversized(ts, request, fut, None)
+                        continue
+                    taken.append(entry)
+                    scans.append(scanned)
+                    metas.append(meta)
+                if not taken:
+                    return []
                 with hold():
-                    h = engine.begin(meta, confirm_request=request)
+                    streams = [engine.begin(meta, confirm_request=e[1])
+                               for meta, e in zip(metas, taken)]
                     with flight.span(EV_SIDE_PREFILTER, into=spent):
-                        h.base_hits = self.pipeline.prefilter([meta])[0]
-                for i in range(0, len(scanned), self.OVERSIZE_CHUNK):
-                    engine.scan(h.feed(scanned[i:i + self.OVERSIZE_CHUNK]),
-                                hold=hold, spent=spent)
-                engine.scan(h.flush(), hold=hold, spent=spent)
-            self.sidehist["side_scan"].observe(sp_scan.us, sp_scan.cpu_us)
+                        base_hits = self.pipeline.prefilter(metas)
+                for h, row in zip(streams, base_hits):
+                    h.base_hits = row
+                step = self.OVERSIZE_CHUNK
+                for i in range(0, max(max(map(len, scans)), 1), step):
+                    items = []
+                    for h, sc in zip(streams, scans):
+                        if i < len(sc):
+                            items += h.feed(sc[i:i + step])
+                        if i == max(len(sc) - 1, 0) // step * step:
+                            items += h.flush()    # its last chunk's call
+                    engine.scan(items, hold=hold, spent=spent)
+            for _ in taken:
+                self.sidehist["side_scan"].observe(sp_scan.us,
+                                                   sp_scan.cpu_us)
         except Exception:
-            self._resolve_oversized(ts, request, fut, None)
-            return False
-        self._oversized_fin_q.put((ts, request, fut, h, spent))
-        return True
+            for ts, request, _plan, fut in taken:
+                self._resolve_oversized(ts, request, fut, None)
+            return []
+        self._oversized_fin_q.put((
+            [(ts, request, fut, h)
+             for (ts, request, _plan, fut), h in zip(taken, streams)],
+            spent))
+        return taken
 
-    def _finish_oversized(self, ts: float, request: Request,
-                          fut: "Future[Verdict]", h: StreamState,
+    def _finish_oversized(self, entries: List[Tuple],
                           spent: Dict[str, List[int]]) -> None:
-        """Confirm one scanned request and resolve it (the side lane's
-        finish thread), outside the swap lock, while the scan thread
-        scans the next request.  The walk of this batch of one goes to
-        a walker process (``lone_to_walker``) and this thread blocks on
-        the pipe with the interpreter lock released: a body past 16 KiB
-        unpacked walks for tens of ms against a hop of one or two, and
-        walked here that Python would hold the lock the scan thread's
-        every call into JAX waits for.  Inline at ``--confirm-workers
-        1`` or while no walker holds the generation."""
+        """Confirm a scanned batch, ``(ts, request, fut, stream)`` each,
+        and resolve it (the side lane's finish thread), outside the
+        swap lock, while the scan thread scans the next batch.  One
+        ``StreamEngine.finish_all``: one mask, one confirm launch whose
+        shares go out one request a share from the last walkers (the
+        side lane's deal), the walks side by side while this thread
+        blocks on the pipes with the interpreter lock released — a body
+        past 16 KiB unpacked walks for tens of ms against a hop of one
+        or two, and walked here that Python would hold the lock the
+        scan thread's every call into JAX waits for — and one fold.
+        Inline at ``--confirm-workers 1`` or while no walker holds the
+        generation, one walk after another."""
         try:
             with flight.span(EV_SIDE_CONFIRM) as sp_confirm:
-                v = self.stream_engine.finish(
-                    h, hold=functools.partial(self._side_hold, spent),
-                    lone_to_walker=True, spent=spent)
-            self.sidehist["side_confirm"].observe(sp_confirm.us,
-                                                  sp_confirm.cpu_us)
-            self._observe_side(spent)
+                verdicts = self.stream_engine.finish_all(
+                    [e[3] for e in entries],
+                    hold=functools.partial(self._side_hold, spent),
+                    side_lane=True)
+            for _ts, _request, _fut, h in entries:
+                self.sidehist["side_confirm"].observe(sp_confirm.us,
+                                                      sp_confirm.cpu_us)
+                self._observe_side(spent, h.walk_ns)
         except Exception:
-            v = None
-        self._resolve_oversized(ts, request, fut, v)
+            verdicts = [None] * len(entries)
+        for (ts, request, fut, _h), v in zip(entries, verdicts):
+            self._resolve_oversized(ts, request, fut, v)
 
     def _resolve_oversized(self, ts: float, request: Request,
                            fut: "Future[Verdict]",
@@ -1292,18 +1349,19 @@ class Batcher:
         # items still queued on the side lane would strand their futures
         # (connection handlers block forever) — resolve them fail-open
         # (round-3 review)
-        # (the scan stage's items carry their future last, the finish
-        # stage's third)
-        for side_q, fut_at in ((self._oversized_q, 3),
-                               (self._oversized_fin_q, 2)):
+        # (the scan stage's items carry their future last; the finish
+        # stage's are batches, whose entries carry it third)
+        for side_q, batched in ((self._oversized_q, False),
+                                (self._oversized_fin_q, True)):
             while True:
                 try:
                     item = side_q.get_nowait()
                 except queue.Empty:
                     break
-                self.pipeline.stats.count_fail_open()
-                _safe_set(item[fut_at],
-                          _fail_open_verdict(item[1].request_id))
+                for entry in (item[0] if batched else [item]):
+                    self.pipeline.stats.count_fail_open()
+                    _safe_set(entry[2 if batched else 3],
+                              _fail_open_verdict(entry[1].request_id))
 
     # ------------------------------------------------------------ loop
 

@@ -113,6 +113,10 @@ METRIC_HELP = {
         "past it)",
     "ipt_oversized_bytes_total":
         "body bytes, as they arrived, of the rerouted requests, by kind",
+    "ipt_oversized_batches_total":
+        "batches the oversized side lane scanned and confirmed together: "
+        "the rerouted requests waiting when its scan thread was free "
+        "(a lone one is a batch of one)",
     "ipt_stream_waves_total":
         "stream-engine scan waves launched (one scan_bytes_jit program "
         "each: 16384 steps while a call's longest row has that many "
@@ -534,6 +538,8 @@ class ServeLoop:
             "# TYPE ipt_oversized_bytes_total counter",
             *('ipt_oversized_bytes_total{kind="%s"} %d' % kv
               for kv in s.oversized_bytes.items()),
+            "# TYPE ipt_oversized_batches_total counter",
+            "ipt_oversized_batches_total %d" % s.oversized_batches,
             "# TYPE ipt_stream_waves_total counter",
             "ipt_stream_waves_total %d" % self.batcher.stream_engine.waves,
             "# TYPE ipt_stream_wave_rows_total counter",

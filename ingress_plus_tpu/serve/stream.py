@@ -90,6 +90,11 @@ WIDE_L = 16384          # the wide one (the batched path's top row tier):
                         # the device's time a byte is the same at either
                         # width, a wave's host part is paid once a wave;
                         # each width is one executable per row tier
+ROW_CAP = 32            # the most rows one wave carries: the largest
+                        # row tier whose step costs at most 1.25x the
+                        # 8-row tier's on the v5e (PERF.md §5,
+                        # tools/wave_bench.py); more rows go out as
+                        # further row sets
 DEFAULT_BODY_CAP = 1 << 20   # raw bytes kept for the confirm stage
 DEFAULT_SCAN_CAP = 16 << 20  # bytes scanned per stream (DoS bound): the
                              # reference bounds body inspection the same
@@ -192,6 +197,9 @@ class StreamState:
         self.aborted = False
         self.error = False
         self.t0 = time.perf_counter()
+        # the walk of the confirm share this stream's request went out
+        # in (ns), set by StreamEngine.finish_all
+        self.walk_ns = 0
         # unpack stage (SURVEY.md §3.3): gzip by Content-Encoding here,
         # by magic-byte sniff on the first chunk in feed(); base64
         # opportunistically (the decoder self-deactivates on the first
@@ -330,13 +338,14 @@ def _side_span(code: int, spent: Optional[Dict[str, List[int]]]):
 class StreamEngine:
     """Chunk-batch scanner + stream finisher.  Two callers, both under
     the batcher's swap lock: its dispatch thread (wire streams: holds
-    the lock around the whole step) and its oversized side worker, which
+    the lock around the whole step) and its oversized side lane, which
+    scans and finishes the rerouted requests waiting as one batch,
     passes ``hold`` and takes the lock once a wave, for the generation
     check and the counters (the launch itself runs outside it).
 
     What is scanned and what is confirmed: ``scan`` sees exactly the
     bytes that were fed (through the incremental unpack and variant
-    chain); ``finish`` confirms on ``StreamState.confirm_request`` where
+    chain); ``finish_all`` confirms on ``StreamState.confirm_request`` where
     the caller gave one, else on the request with the accumulated raw
     body in place.  The side lane feeds the batched path's own scan
     stream (``unpack_body`` of the whole body, scan-only segments
@@ -344,8 +353,9 @@ class StreamEngine:
     stages see what the batched path's stages see."""
 
     #: row tiers ``warm`` compiles, powers of two from 8: a side-lane
-    #: body's rows are its needed body variants (five: 8 rows); a wire
-    #: stream's are those times up to three sources (body, base64,
+    #: body's rows are its needed body variants (five: 8 rows), and the
+    #: bodies of a side-lane batch share a wave (up to ``ROW_CAP``); a
+    #: wire stream's are those times up to three sources (body, base64,
     #: gRPC: 16), and two wire streams may share a wave (32)
     WARM_MAX_ROWS = 32
 
@@ -392,7 +402,7 @@ class StreamEngine:
     def begin(self, request: Request,
               confirm_request: Optional[Request] = None) -> StreamState:
         """``confirm_request``: the caller holds the whole request (the
-        batcher's oversized reroute) — ``finish`` confirms on it, and
+        batcher's oversized reroute) — ``finish_all`` confirms on it, and
         what is fed is only scanned, never accumulated."""
         p = self.pipeline
         si = STREAM_INDEX[getattr(request, "body_stream", "body")]
@@ -425,12 +435,15 @@ class StreamEngine:
         then CHUNK_L; a shorter row rides a wave with ``lengths`` short
         of its width).  Items for the same (stream, variant) are
         concatenated in arrival order (state carry makes that exact).
+        At most ``ROW_CAP`` rows share a wave: more go out as further
+        row sets, each with its waves and carry, launched one set after
+        another before the one wait for the device.
         ``hold``: a context-manager factory entered once a wave (the
         side worker's hold of the swap lock: the version check, the
         tables of that generation and the counters; the launch follows
         outside it, and nothing waits for the device before the last
         wave); None where the caller holds the lock already.  ``spent``:
-        the side lane's sums for the request (``flight.span(into=)``),
+        the side lane's sums for its batch (``flight.span(into=)``),
         to which each wave's call into JAX adds a ``side_launch`` span
         and the copies back at the end a ``side_fetch``; None (a wire
         stream) opens neither.  Returns the waves launched."""
@@ -463,60 +476,68 @@ class StreamEngine:
             groups.setdefault(key, []).append(r)
         rows = [g[0] for g in groups.values()]
         followers = {id(g[0]): g[1:] for g in groups.values()}
-        # one row set for the whole call: a row that runs out of bytes
-        # rides on with a length short of the wave's width, then 0
-        # (padded steps are the identity on state and match), so the
-        # carry stays ON THE DEVICE from wave to wave and comes back to
-        # the host once, after the last one; nothing in between waits
-        # for the device
-        B = 8
-        while B < len(rows):
-            B *= 2
+        # one carry a row set of at most ROW_CAP rows, launched one set
+        # after another: a row that runs out of bytes rides on with a
+        # length short of the wave's width, then 0 (padded steps are the
+        # identity on state and match), so each set's carry stays ON THE
+        # DEVICE from wave to wave and comes back to the host once,
+        # after every set's last wave; nothing in between waits for the
+        # device
         W = rows[0][0].state.shape[1]
-        state = np.zeros((B, W), np.uint32)
-        match = np.zeros_like(state)
-        for j, (st, vi, _data) in enumerate(rows):
-            state[j] = st.state[vi]
-            match[j] = st.match[vi]
-        waves = off = 0
-        pending = max(len(r[2]) for r in rows)
-        while off < pending:
-            L = wave_width(pending - off)
-            chunks = [bytes(r[2][off:off + L]) for r in rows]
-            tokens, lengths = pad_rows(
-                chunks + [b""] * (B - len(rows)), max_len=L, round_to=L)
-            with hold():
-                p = self.pipeline
-                if any(r[0].version != p.ruleset.version for r in rows):
-                    # swapped since this scan began: only a caller that
-                    # takes the lock per wave can see it, and its rows
-                    # are one stream's
-                    for r in rows:
-                        for st, _vi, _ in (r, *followers[id(r)]):
-                            st.error = True
-                    return waves
-                tables = p.engine.tables.scan
-                self.waves += 1
-                self.wave_rows += sum(1 for c in chunks if c)
-                self.wave_bytes += sum(len(c) for c in chunks)
-                self.wave_steps += L
-            # launched outside the hold: the program runs on the tables
-            # of the generation just checked whatever is installed while
-            # it does, and on this machine the call into JAX returns
-            # only after milliseconds, which a batched cycle must not
-            # wait out
-            with _side_span(EV_SIDE_LAUNCH, spent):
-                match, state = scan_bytes_jit(tables, tokens, lengths,
-                                              state, match)
-            waves += 1
-            off += L
+        carries = []
+        waves = 0
+        for at in range(0, len(rows), ROW_CAP):
+            rset = rows[at:at + ROW_CAP]
+            B = 8
+            while B < len(rset):
+                B *= 2
+            state = np.zeros((B, W), np.uint32)
+            match = np.zeros_like(state)
+            for j, (st, vi, _data) in enumerate(rset):
+                state[j] = st.state[vi]
+                match[j] = st.match[vi]
+            off = 0
+            pending = max(len(r[2]) for r in rset)
+            while off < pending:
+                L = wave_width(pending - off)
+                chunks = [bytes(r[2][off:off + L]) for r in rset]
+                tokens, lengths = pad_rows(
+                    chunks + [b""] * (B - len(rset)), max_len=L, round_to=L)
+                with hold():
+                    p = self.pipeline
+                    if any(r[0].version != p.ruleset.version for r in rows):
+                        # swapped since this scan began: only a caller
+                        # that takes the lock per wave can see it, and
+                        # every stream of the call fails open, its
+                        # carry computed on the tables that are gone
+                        for r in rows:
+                            for st, _vi, _ in (r, *followers[id(r)]):
+                                st.error = True
+                        return waves
+                    tables = p.engine.tables.scan
+                    self.waves += 1
+                    self.wave_rows += sum(1 for c in chunks if c)
+                    self.wave_bytes += sum(len(c) for c in chunks)
+                    self.wave_steps += L
+                # launched outside the hold: the program runs on the
+                # tables of the generation just checked whatever is
+                # installed while it does, and on this machine the call
+                # into JAX returns only after milliseconds, which a
+                # batched cycle must not wait out
+                with _side_span(EV_SIDE_LAUNCH, spent):
+                    match, state = scan_bytes_jit(tables, tokens, lengths,
+                                                  state, match)
+                waves += 1
+                off += L
+            carries.append((rset, match, state))
         with _side_span(EV_SIDE_FETCH, spent):
-            m_out = np.asarray(match)
-            s_out = np.asarray(state)
-        for j, r in enumerate(rows):
-            for st, vi, _ in (r, *followers[id(r)]):
-                st.state[vi] = s_out[j]
-                st.match[vi] = m_out[j]
+            carries = [(rset, np.asarray(m), np.asarray(st))
+                       for rset, m, st in carries]
+        for rset, m_out, s_out in carries:
+            for j, r in enumerate(rset):
+                for st, vi, _ in (r, *followers[id(r)]):
+                    st.state[vi] = s_out[j]
+                    st.match[vi] = m_out[j]
         return waves
 
     # ---------------------------------------------------------- finish
@@ -529,45 +550,61 @@ class StreamEngine:
                        fail_open=True, elapsed_us=int(
                            (time.perf_counter() - st.t0) * 1e6))
 
-    def finish(self, st: StreamState, hold=None,
-               lone_to_walker: bool = False,
-               spent: Optional[Dict[str, List[int]]] = None) -> Verdict:
-        """Fold the stream's match words into rule hits and confirm.
-        ``hold`` as in :meth:`scan`: given, the confirm walk and the
-        wait for it run OUTSIDE the lock and only the mask and the
-        single-threaded fold take it; a ruleset swapped in meanwhile
-        fails the stream open at the fold.  ``lone_to_walker``: the
-        walk of this batch of one goes to a walker process
-        (``ConfirmPool.deal``) and this thread blocks on its pipe, the
-        interpreter lock released — the oversized side lane asks it,
-        so that its body's walk of tens of ms leaves the lock to its
-        scan thread.  Otherwise (a wire stream), and where no walker
-        holds the generation, the walk is inline on this thread.
-        ``spent`` as in :meth:`scan`: the walk's own time is added to it
-        as ``side_walk`` (a walker's, as it reports it; inline, this
-        thread's ``confirm_walk`` span)."""
+    def finish(self, st: StreamState) -> Verdict:
+        """Fold one stream's match words into rule hits and confirm
+        (a wire stream, on the dispatch thread, which holds the swap
+        lock): the batch of one of :meth:`finish_all`."""
+        return self.finish_all([st])[0]
+
+    def finish_all(self, sts: Sequence[StreamState], hold=None,
+                   side_lane: bool = False) -> List[Verdict]:
+        """Fold each stream's match words into rule hits on the host,
+        then mask, confirm and fold them as ONE batch.  ``hold`` as in
+        :meth:`scan`: given, the confirm walks and the wait for them run
+        OUTSIDE the lock and only the mask and the single-threaded fold
+        take it; a ruleset swapped in meanwhile fails every stream of
+        the batch open at the fold.  ``side_lane``: the side lane's deal
+        (``ConfirmPool.deal``), the batch's requests one a share from
+        the last walkers, so that their walks of tens of ms run side by
+        side and this thread blocks on the pipes with the interpreter
+        lock released — the oversized side lane asks it, so that its
+        bodies' walks leave the lock to its scan thread; then each
+        stream's ``walk_ns`` is the walk of its own share (a walker's,
+        as it reports it; inline, the batch's ``confirm_walk`` span),
+        also put on the ring as a ``side_walk`` span.  Otherwise (a wire
+        stream), and where no walker holds the generation, the batch is
+        walked inline on this thread.  A stream that errored, or whose
+        generation is gone, fails open alone."""
         hold = hold or nullcontext
         p = self.pipeline
-        req = st.request
-        if st.error or st.version != p.ruleset.version:
-            return self._failed_open(st)
+        out: List[Optional[Verdict]] = [None] * len(sts)
+        idx, live = [], []
+        for i, st in enumerate(sts):
+            if st.error or st.version != p.ruleset.version:
+                out[i] = self._failed_open(st)
+            else:
+                idx.append(i)
+                live.append(st)
+        if not live:
+            return out
         cr = p.ruleset
         bt = cr.tables
         R = cr.n_rules
-        body_hits = np.zeros((R,), dtype=bool)
-        applies_any = np.zeros((R,), dtype=bool)
-        for vi, (_v, sv, _src) in enumerate(st.variants):
-            rr = factors_to_rules(bt, matches_to_factors(bt, st.match[vi]))
-            applies = cr.rule_sv_mask[:, sv]
-            body_hits |= rr & applies
-            applies_any |= applies
-        # rules with no prefilter factors must always reach confirm when
-        # any applicable row was scanned (mirrors engine.detect_rows)
-        body_hits |= (bt.rule_nfactors == 0) & applies_any
-
-        hits = body_hits
-        if st.base_hits is not None:
-            hits = hits | st.base_hits
+        hits = np.zeros((len(live), R), dtype=bool)
+        for k, st in enumerate(live):
+            applies_any = np.zeros((R,), dtype=bool)
+            for vi, (_v, sv, _src) in enumerate(st.variants):
+                rr = factors_to_rules(bt, matches_to_factors(
+                    bt, st.match[vi]))
+                applies = cr.rule_sv_mask[:, sv]
+                hits[k] |= rr & applies
+                applies_any |= applies
+            # rules with no prefilter factors must always reach confirm
+            # when any applicable row was scanned (mirrors
+            # engine.detect_rows)
+            hits[k] |= (bt.rule_nfactors == 0) & applies_any
+            if st.base_hits is not None:
+                hits[k] |= st.base_hits
         # the confirm twin: the request as the caller holds it, else
         # the accumulated (capped) raw body in the stream's request.
         # parsers_off carries over either way: the confirm stage
@@ -576,29 +613,40 @@ class StreamEngine:
         # dataclasses.replace keeps every other field AND the concrete
         # type (a Response reroutes through here too — its confirm twin
         # must stay a Response so resp_* streams rebuild)
-        confirm_req = (st.confirm_request if st.confirm_request is not None
-                       else replace(req, body=bytes(st.acc)))
+        confirm_reqs = [st.confirm_request if st.confirm_request is not None
+                        else replace(st.request, body=bytes(st.acc))
+                        for st in live]
+
+        def _all_failed_open() -> List[Verdict]:
+            for i, st in zip(idx, live):
+                out[i] = self._failed_open(st)
+            return out
+
         with hold():
-            if st.version != p.ruleset.version:
-                return self._failed_open(st)
-            hits = p.mask_hits([req], hits[None])
-        # the walk of a body of tens of KB lasts tens of ms: it, and a
-        # walker's answer, are waited for outside the hold, so a
+            if cr.version != self.pipeline.ruleset.version:
+                return _all_failed_open()
+            hits = p.mask_hits([st.request for st in live], hits)
+        # the walks of bodies of tens of KB last tens of ms: they, and
+        # the walkers' answers, are waited for outside the hold, so a
         # batched cycle never waits them out
-        cjob = p.finalize_launch([confirm_req], hits, lone_to_walker)
+        cjob = p.finalize_launch(confirm_reqs, hits, side_lane)
         join_confirm(p, cjob)
-        if spent is not None:
-            # ring only, back-dated from the answer: it ran elsewhere
-            now = time.monotonic_ns()
-            flight.span_at(EV_SIDE_WALK, now - cjob.walk_ns, now)
-            spent.setdefault("side_walk", [0, 0])[0] += cjob.walk_ns
+        now = time.monotonic_ns()
+        for st, walk_ns in zip(live, cjob.share_walk_ns):
+            st.walk_ns = walk_ns
+            if side_lane:
+                # ring only, back-dated from the answer: it ran elsewhere
+                flight.span_at(EV_SIDE_WALK, now - walk_ns, now)
         with hold():
-            if st.version != self.pipeline.ruleset.version:
-                return self._failed_open(st)
-            v = p.finalize_join(cjob, st.t0)[0]
-            p.stats.requests += 1
-        # scan/confirm caps were hit: the verdict is based on a prefix —
-        # surface it the fail-open way (pass-and-flag, never silently)
-        if st.truncated and not v.attack:
-            v.fail_open = True
-        return v
+            if cr.version != self.pipeline.ruleset.version:
+                return _all_failed_open()
+            verdicts = p.finalize_join(cjob, min(st.t0 for st in live))
+            p.stats.requests += len(live)
+        for i, st, v in zip(idx, live, verdicts):
+            # scan/confirm caps were hit: the verdict is based on a
+            # prefix — surface it the fail-open way (pass-and-flag,
+            # never silently)
+            if st.truncated and not v.attack:
+                v.fail_open = True
+            out[i] = v
+        return out

@@ -772,7 +772,7 @@ EV_CONFIRM = 8     # one confirm share's candidate walk, tag=worker, arg=n_reque
 EV_FINALIZE = 9    # finalize join + single-threaded fold (dispatch)
 EV_MIRROR = 10     # rollout shadow mirroring of resolved verdicts
 EV_STREAM = 11     # stream-step scan work (pinned lane worker)
-EV_OVERSIZED = 12  # oversized side-lane body scan, tag=tenant
+EV_OVERSIZED = 12  # oversized side-lane batch scan, tag=requests, arg=body bytes
 EV_SUBMIT = 13     # instant: admission, tag=req-id hash, arg=tenant
 EV_VERDICT = 14    # instant: verdict resolved, tag=req-id hash, arg=lane
 EV_SHADOW = 15     # shadow-lane candidate scan (shadow thread)
@@ -790,7 +790,7 @@ EV_LANE_SCAN = 26  # one lane share's scan, submit → result on the host, tag=l
 EV_SCAN_WALL = 27  # a mesh cycle's scan: first share's submit → last result
 EV_CONFIRM_IPC = 28  # a confirm share's hop: send → answer less the walker's walk, tag=worker
 EV_SIDE_WAIT = 29  # side lane: submit → the worker takes the request (back-dated)
-EV_SIDE_SCAN = 30  # side lane: begin → last wave and flush, the head's prefilter inside, arg=body bytes
+EV_SIDE_SCAN = 30  # side lane: a batch's unpacks → last wave and flush, the heads' prefilter inside, arg=body bytes
 EV_SIDE_CONFIRM = 31  # side lane: finish's confirm walk and fold
 EV_SIDE_LOCK = 32  # side lane: one hold of the batcher's swap lock
 EV_SIDE_PREFILTER = 33  # side lane: the head's prefilter (inside a hold)

@@ -292,13 +292,13 @@ def test_shares_are_dealt_no_wider_than_they_must(n, workers, shares):
 
 @pytest.mark.parametrize("n,workers,holding,dealt", [
     (1, 8, 8, [7]), (1, 8, 1, [0]), (1, 8, 0, []), (1, 2, 2, [1]),
-    (1, 1, 0, []), (0, 8, 8, []), (5, 8, 8, [0, 1, 2, 3, 4]),
-    (2, 8, 1, [])])
+    (1, 1, 0, []), (0, 8, 8, []), (5, 8, 8, [3, 4, 5, 6, 7]),
+    (2, 8, 1, [0])])
 def test_the_side_lanes_lone_walk_needs_one_walker_holding_the_pack(
         n, workers, holding, dealt):
-    """``lone_to_walker``: a batch of one goes to the last walker that
-    holds the generation (none: inline, [] here; an inline pool: the
-    same); a larger batch is dealt as ever."""
+    """``side_lane``, the side lane's deal: a batch goes out one request
+    a share to the last walkers that hold the generation, one such
+    being enough (none: inline, [] here; an inline pool: the same)."""
     pool = ConfirmPool(n_workers=1)
 
     class _W:
@@ -315,7 +315,7 @@ def test_the_side_lanes_lone_walk_needs_one_walker_holding_the_pack(
     # the installs a stub cannot take are left out: ready = holding
     pool._post_installs = lambda pl: (
         [w for w in pool._workers if 7 in w.held], [])
-    got = pool.deal(_P, n, lone_to_walker=True)
+    got = pool.deal(_P, n, side_lane=True)
     assert [w.worker_index for w in got] == dealt
     if n == 1 and holding:
         assert pool.deal(_P, 1) == []      # what every other caller gets
@@ -348,7 +348,7 @@ def test_lone_walks_and_batched_shares_deal_from_two_threads_at_once(pair):
         for k in range(rounds):
             i = k % len(lones)
             (got,) = join_confirm(pooled, launch_confirm(
-                pooled, lones[i:i + 1], rh_l[i:i + 1], lone_to_walker=True))
+                pooled, lones[i:i + 1], rh_l[i:i + 1], side_lane=True))
             if _rt(got) != want_l[i]:
                 errors.append("lone %d" % i)
 

@@ -12,10 +12,14 @@ wide/narrow) and across a 64 KiB chunk boundary, and stretched over each
 by thousands of bytes that a rule's chain deletes.  The reference shares
 no code with the program.  Every case runs twice: with the inline
 confirm (``--confirm-workers 1``) and with walker processes, to which
-the side lane hands its lone walk.  Where that walk runs and how it
-fails (a walker killed or wedged under it, a hot swap during it) is held
-on a small pack.  The wave plan itself is held, on the engine alone, to
-an all-narrow plan and to one unbroken scan.
+the side lane hands its walks.  The rerouted requests that wait are
+served as one batch (their rows share each wave, their walks go out one
+a share): batches of two to four against the reference, and the streams
+of a batch against each scanned alone.  Where the walks run and how they
+fail (a walker killed or wedged under one, an unpack that raises, a hot
+swap during a scan or a walk) is held on a small pack.  The wave plan
+itself is held, on the engine alone, to an all-narrow plan and to one
+unbroken scan.
 
 ROADMAP F1 is the named regression: the side lane used to confirm on the
 unpacked copy of the body, whose url-decoded segment the confirm twin
@@ -24,6 +28,7 @@ payload).
 """
 
 import os
+import queue
 import random
 import signal
 import sys
@@ -163,29 +168,66 @@ def build_over(size: int, ctype: str, payload: str, target,
     raise AssertionError("payload not laid over %d" % target)
 
 
+def decoded(wire_request: WireRequest, req_id: int):
+    """(frame, the request the server decodes from it)."""
+    from ingress_plus_tpu.serve.protocol import decode_request
+
+    frame = encode_request(wire_request, req_id=req_id)
+    _id, mode, req = decode_request(frame[8:])
+    req.mode = mode
+    return frame, req
+
+
+def triple(v):
+    return (bool(v.attack), bool(v.blocked), sorted(int(r) for r in v.rule_ids))
+
+
+def reference(dep, frame: bytes):
+    _idx, request = plainwaf.decode_frame(frame)
+    want = plainwaf.verdict(dep, request)
+    return (want[0], want[1], sorted(want[2]))
+
+
 def served_and_reference(lane, wire_request: WireRequest, req_id: int = 7,
                          held: bool = True):
     """(served, reference) verdicts of one frame; a rerouted request's
     walk went to a walker process wherever the lane has them and
     (``held``) they hold the pack."""
-    from ingress_plus_tpu.serve.protocol import decode_request
-
     batcher, dep = lane
     pool = batcher.pipeline.confirm_pool
-    frame = encode_request(wire_request, req_id=req_id)
-    _id, mode, req = decode_request(frame[8:])
-    req.mode = mode
+    frame, req = decoded(wire_request, req_id)
     rerouted, walked = batcher.stats.oversized_rerouted, pool.requests_process
     v = batcher.submit(req).result(timeout=300)
     assert not v.fail_open
     rerouted = batcher.stats.oversized_rerouted - rerouted
     assert pool.requests_process - walked == (
         rerouted if held and not pool.inline else 0)
-    _idx, request = plainwaf.decode_frame(frame)
-    want = plainwaf.verdict(dep, request)
-    return ((bool(v.attack), bool(v.blocked),
-             sorted(int(r) for r in v.rule_ids)),
-            (want[0], want[1], sorted(want[2])))
+    return triple(v), reference(dep, frame)
+
+
+def submit_together(batcher, requests):
+    """Submit rerouted requests so that the side lane takes them as ONE
+    batch: its scan thread is held off its queue until all of them wait
+    there.  Returns their futures."""
+    q = batcher._oversized_q
+    gate = threading.Event()
+    real_get = q.get
+
+    def held_get(block=True, timeout=None):
+        if not gate.wait(timeout if block else 0):
+            raise queue.Empty
+        return real_get(block, timeout)
+
+    q.get = held_get
+    try:
+        # a get already waiting on the queue returns within its 0.1 s
+        time.sleep(0.3)
+        futs = [batcher.submit(r) for r in requests]
+        assert _wait_for(lambda: q.qsize() == len(requests), 60)
+    finally:
+        gate.set()
+        del q.get
+    return futs
 
 
 # unpack: the body is under the threshold and unpacks past it (JSON:
@@ -350,12 +392,103 @@ def test_four_oversized_in_flight_shed_nothing(lane):
     assert batcher.pipeline.load_controller.level == 0
 
 
+# ------------------------------------------- rerouted bodies as one batch
+
+#: the bodies of a batch: JSON and form, of both reroute kinds, one with
+#: a payload across the 64 KiB chunk edge (its batch's longest), one with
+#: a payload mid-body, the rest benign
+BATCH = [(CHUNK + 6_000, "json", SQLI, CHUNK), (20_000, "form", "", "mid"),
+         (11_000, "json", "", "mid"), (30_000, "form", "<script>alert(1)",
+                                       "mid")]
+
+
+@pytest.mark.parametrize("k", [2, 3, 4])
+def test_bodies_queued_together_are_each_the_references_verdict(lane, k):
+    """k rerouted bodies taken as one batch (their rows share each wave,
+    their walks go out together): every verdict is the reference's for
+    its own frame, so no row's bytes or carry reached another's, and
+    the benign ones block nothing.  On walkers, each request is a share
+    of its own, dealt from the last walkers."""
+    batcher, dep = lane
+    pool = batcher.pipeline.confirm_pool
+    frames, reqs = zip(*(decoded(build(*b), req_id=100 + i)
+                         for i, b in enumerate(BATCH[:k])))
+    batches, rerouted = (batcher.stats.oversized_batches,
+                         batcher.stats.oversized_rerouted)
+    walked = pool.requests_process
+    verdicts = [f.result(timeout=600)
+                for f in submit_together(batcher, reqs)]
+    assert batcher.stats.oversized_batches == batches + 1
+    assert batcher.stats.oversized_rerouted == rerouted + k
+    assert not any(v.fail_open for v in verdicts)
+    for frame, v, (size, ctype, payload, _at) in zip(frames, verdicts,
+                                                     BATCH):
+        want = reference(dep, frame)
+        assert triple(v) == want, "%d B %s" % (size, ctype)
+        assert want[0] == bool(payload)
+        assert v.blocked == bool(payload)
+    if pool.inline:
+        assert pool.requests_process == walked
+    else:
+        n = len(pool._workers)
+        assert pool.requests_process == walked + k
+        assert {v.confirm_worker for v in verdicts} == {
+            w.worker_index for w in pool._workers[-min(k, n):]}
+
+
+def test_four_bodies_after_warm_share_waves_and_compile_none(lane):
+    """A batch of four bodies launches the wave shapes ``warm`` made
+    (their rows fit the warmed row tiers) and no other, and shares its
+    waves: fewer than the four would launch one after another."""
+    from ingress_plus_tpu.ops.scan import scan_bytes_jit
+
+    batcher, _dep = lane
+    eng = batcher.stream_engine
+    assert eng.warmed
+    compiled = scan_bytes_jit._cache_size()
+    reqs = [decoded(build(18_000 + 4_000 * i, ("json", "form")[i % 2], "",
+                          "mid"), req_id=200 + i)[1] for i in range(4)]
+    alone = sum(sum(planned_waves(len(unpack_body(r.body, r.headers))))
+                for r in reqs)
+    w0 = eng.waves
+    verdicts = [f.result(timeout=600)
+                for f in submit_together(batcher, reqs)]
+    assert not any(v.fail_open or v.attack for v in verdicts)
+    assert scan_bytes_jit._cache_size() == compiled
+    assert max(planned_waves(len(unpack_body(r.body, r.headers)))[0]
+               for r in reqs) <= eng.waves - w0 < alone
+
+
+def test_the_batch_counter_counts_batches_and_a_lone_request_is_one(lane):
+    """``ipt_oversized_batches_total``: one a batch the scan thread
+    takes, a request served alone being a batch of one; each request of
+    a batch observes each side stage once."""
+    from ingress_plus_tpu.utils.trace import SIDE_STAGES
+
+    batcher, _dep = lane
+    st = batcher.stats
+    batches, rerouted = st.oversized_batches, st.oversized_rerouted
+    seen = {n: batcher.sidehist[n].total for n in SIDE_STAGES}
+    served_and_reference(lane, build(17_000, "json", "", "mid"))
+    assert (st.oversized_batches, st.oversized_rerouted) == (
+        batches + 1, rerouted + 1)
+    reqs = [decoded(build(17_000 + 100 * i, "form", "", "mid"),
+                    req_id=300 + i)[1] for i in range(3)]
+    for f in submit_together(batcher, reqs):
+        assert not f.result(timeout=600).fail_open
+    assert (st.oversized_batches, st.oversized_rerouted) == (
+        batches + 2, rerouted + 4)
+    assert {n: batcher.sidehist[n].total - seen[n]
+            for n in SIDE_STAGES} == dict.fromkeys(SIDE_STAGES, 4)
+
+
 COUNTERS = ('ipt_oversized_rerouted_total{kind="raw"}',
             'ipt_oversized_rerouted_total{kind="unpack"}',
             'ipt_oversized_bytes_total{kind="raw"}',
             'ipt_oversized_bytes_total{kind="unpack"}',
             "ipt_stream_waves_total", "ipt_stream_wave_rows_total",
-            "ipt_stream_wave_bytes_total", "ipt_stream_wave_steps_total")
+            "ipt_stream_wave_bytes_total", "ipt_stream_wave_steps_total",
+            "ipt_oversized_batches_total")
 
 
 @pytest.fixture(scope="module")
@@ -661,6 +794,120 @@ def test_a_hot_swap_during_the_walk_fails_the_request_open(monkeypatch,
         b.close()
 
 
+def small_batch(n: int):
+    """``n`` raw reroutes of the small pack: an attack, then benign."""
+    return [oversized((ATTACK if i == 0 else BENIGN + b" %d" % i),
+                      "b%d" % i) for i in range(n)]
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_a_body_whose_unpack_raises_fails_open_alone(monkeypatch, pool):
+    """One body of a batch whose unpack raises is failed open, flagged;
+    the others of its batch are scanned and confirmed as ever."""
+    from ingress_plus_tpu.serve import batcher as batcher_mod
+
+    real_unpack = batcher_mod.unpack_body
+
+    def _unpack(body, *args, **kw):
+        if b"POISON" in body:
+            raise ValueError("unpack failed")
+        return real_unpack(body, *args, **kw)
+
+    monkeypatch.setattr(batcher_mod, "unpack_body", _unpack)
+    b = small_batcher(workers=POOLS[pool])
+    try:
+        reqs = small_batch(3)
+        reqs.insert(1, oversized(b"POISON " + BENIGN, "poison"))
+        verdicts = [f.result(timeout=180) for f in submit_together(b, reqs)]
+        assert b.stats.oversized_batches == 1
+        assert [v.fail_open for v in verdicts] == [False, True, False, False]
+        assert [v.attack for v in verdicts] == [True, False, False, False]
+        assert b.pipeline.stats.fail_open == 1
+        assert not b._oversized_by_tenant          # every slot released
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("when", ["scan", "walk"])
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_a_hot_swap_during_a_batch_fails_every_request_open(monkeypatch,
+                                                            pool, when):
+    """A ruleset installed while a batch scans (seen at a wave's version
+    check) or while its walks are out (seen at the fold) fails every
+    request of the batch open, flagged; the next batch is served by the
+    new pack."""
+    from ingress_plus_tpu.compiler.ruleset import compile_ruleset
+    from ingress_plus_tpu.compiler.seclang import parse_seclang
+    from ingress_plus_tpu.serve import stream
+
+    b = small_batcher(workers=POOLS[pool])
+    try:
+        old = b.pipeline
+
+        def _swap():
+            t = threading.Thread(target=b.swap_ruleset, args=(
+                compile_ruleset(parse_seclang(SQLI_RULE)),))
+            t.start()
+            t.join(120)
+            assert not t.is_alive()
+
+        if when == "scan":
+            eng, real_scan = b.stream_engine, b.stream_engine.scan
+
+            def _swap_then_scan(items, **kw):
+                del eng.scan
+                _swap()
+                return real_scan(items, **kw)
+
+            eng.scan = _swap_then_scan
+        else:
+            real_join = stream.join_confirm
+
+            def _swap_then_join(pl, job):
+                monkeypatch.setattr(stream, "join_confirm", real_join)
+                _swap()
+                return real_join(pl, job)
+
+            monkeypatch.setattr(stream, "join_confirm", _swap_then_join)
+        verdicts = [f.result(timeout=180)
+                    for f in submit_together(b, small_batch(3))]
+        assert b.pipeline is not old
+        assert all(v.fail_open and not v.attack for v in verdicts)
+        assert old.confirm_pool.requests_process == (
+            3 if when == "walk" and not old.confirm_pool.inline else 0)
+        verdicts = [f.result(timeout=180)
+                    for f in submit_together(b, small_batch(2))]
+        assert [(v.attack, v.fail_open) for v in verdicts] == [
+            (True, False), (False, False)]
+        assert {v.generation for v in verdicts} == {
+            b.pipeline.generation_tag}
+    finally:
+        b.close()
+
+
+def test_the_side_lanes_deal_takes_the_last_walkers_a_batch_the_first():
+    """On four walkers: a side-lane batch of two goes out a request a
+    share to the last two walkers, so that their walks run side by
+    side, and a batched dispatch's shares go to the first ones."""
+    b = small_batcher(workers=4)
+    try:
+        pl = b.pipeline
+        pool = pl.confirm_pool
+        idx = [w.worker_index for w in pool._workers]
+        assert [w.worker_index for w in pool.deal(pl, 2, side_lane=True)] \
+            == idx[2:]
+        assert [w.worker_index for w in pool.deal(pl, 2)] == idx[:2]
+        assert [w.worker_index for w in pool.deal(pl, 6, side_lane=True)] \
+            == idx
+        verdicts = [f.result(timeout=180)
+                    for f in submit_together(b, small_batch(2))]
+        assert [v.attack for v in verdicts] == [True, False]
+        assert sorted(v.confirm_worker for v in verdicts) == idx[2:]
+        assert pool.requests_process == 2 and pool.requests_inline == 0
+    finally:
+        b.close()
+
+
 # ------------------------------------------------ the plan, engine alone
 
 @pytest.fixture(scope="module")
@@ -725,6 +972,61 @@ def scanned_carry(engine, body: bytes):
         engine.scan(st.feed(body[i:i + CHUNK]))
     engine.scan(st.flush())
     return st.variants, st.state, st.match
+
+
+def scanned_together(engine, bodies):
+    """(state, match) of each body's stream fed as the side lane feeds a
+    batch: one ``StreamEngine.scan`` call a 64 KiB chunk index holding
+    that chunk of every body that has one, the flush with its last."""
+    from ingress_plus_tpu.serve.normalize import Request
+
+    sts = [engine.begin(Request(method="POST", uri="/u", request_id="p%d" % i,
+                                parsers_off=frozenset(
+                                    ("gzip", "base64", "json"))))
+           for i in range(len(bodies))]
+    for i in range(0, max(len(b) for b in bodies), CHUNK):
+        items = []
+        for st, body in zip(sts, bodies):
+            if i < len(body):
+                items += st.feed(body[i:i + CHUNK])
+            if i == (len(body) - 1) // CHUNK * CHUNK:
+                items += st.flush()
+        engine.scan(items)
+    return [(st.state, st.match) for st in sts]
+
+
+#: (size, plant, the byte it lies over) of the bodies of a batch
+TOGETHER = [(CHUNK + 5_000, 1, CHUNK), (9_000, 0, 4_000),
+            (2 * CHUNK + 100, 3, 2 * CHUNK + 50), (30_000, 2, WIDE_L)]
+
+
+@pytest.mark.parametrize("row_cap", [8, 32])
+@pytest.mark.parametrize("k", [2, 3, 4])
+def test_streams_scanned_together_carry_what_each_carries_alone(
+        small_engine, monkeypatch, k, row_cap):
+    """The streams of a batch share each scan call, their rows side by
+    side in a wave (or, past ``ROW_CAP`` rows, in further row sets):
+    every row's state and match after the batch's calls are exactly
+    those of its body scanned alone, and each body's factor is
+    matched."""
+    import numpy as np
+
+    from ingress_plus_tpu.serve import stream
+
+    rng = random.Random(k)
+    bodies = [random_body(rng, size, "mixed", PLANTS[plant], at)
+              for size, plant, at in TOGETHER[:k]]
+    monkeypatch.setattr(stream, "ROW_CAP", row_cap)
+    eng = small_engine
+    w0 = eng.waves
+    together = scanned_together(eng, bodies)
+    waves = eng.waves - w0
+    for body, (state, match) in zip(bodies, together):
+        _v, a_state, a_match = scanned_carry(eng, body)
+        assert np.array_equal(state, a_state)
+        assert np.array_equal(match, a_match)
+        assert match.any()
+    assert waves < eng.waves - w0 - waves       # fewer than one by one
 
 
 # (body bytes, the byte the plant lies over): every kind of boundary of

@@ -1,20 +1,23 @@
-"""What one stream-engine scan wave costs on the chip, by width, alone and
-beside a thread that never yields the interpreter lock (PERF.md §5-§6,
-PR 35: the side lane's scan thread runs beside the finish thread's inline
-confirm walk).
+"""What one stream-engine scan wave costs on the chip, by width and by
+rows, alone and beside a thread that never yields the interpreter lock
+(PERF.md §5-§6: the side lane's scan thread beside its finish thread;
+the per-step cost by rows sets ``serve/stream.py ROW_CAP``).
 
-    chiprun --chips 1 -- env PYTHONPATH=. python tools/wave_bench.py
+    env PYTHONPATH=. python tools/wave_bench.py [--rows 8 16 32]
+        [--live N] [--widths 2048 16384]
 
-Three readings per width (2,048 / 8,192 / 16,384 / 32,768 steps, eight
-rows, 131,072 steps in all, the carry on the device from wave to wave):
-the program alone with its tokens resident on the device; as
-``StreamEngine.scan`` launches it (tokens from the host every wave), with
-the time spent inside the calls into JAX; and the same beside a busy
-Python thread.  Then ``StreamEngine`` itself over a 90,000 B stream fed
-64 KiB at a time.  A timing from a CPU run of this file says nothing
-about the chip: it fails without an accelerator.
+For each row count (default 8) and width (default 2,048 / 8,192 / 16,384
+/ 32,768 steps), 131,072 steps in all with the carry on the device from
+wave to wave, three readings: the program alone with its tokens resident
+on the device; as ``StreamEngine.scan`` launches it (tokens from the host
+every wave), with the time spent inside the calls into JAX; and the same
+beside a busy Python thread.  ``--live``: the rows that carry bytes, the
+rest padding (default: every row).  Then ``StreamEngine`` itself over a
+90,000 B stream fed 64 KiB at a time.  A timing from a CPU run of this
+file says nothing about the chip: it fails without an accelerator.
 """
 
+import argparse
 import json
 import os
 import threading
@@ -40,10 +43,15 @@ from ingress_plus_tpu.serve.stream import StreamEngine        # noqa: E402
 
 TOTAL = 131072
 WIDTHS = (2048, 8192, 16384, 32768)
-ROWS, LIVE = 8, 2
 
 
 def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, nargs="+", default=[8])
+    ap.add_argument("--live", type=int, default=0,
+                    help="rows that carry bytes (default: all)")
+    ap.add_argument("--widths", type=int, nargs="+", default=list(WIDTHS))
+    args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform == "cpu":
         raise SystemExit("no accelerator: a CPU timing is not a device metric")
@@ -55,15 +63,16 @@ def main() -> None:
     rng = np.random.default_rng(35)
     out = {}
 
-    def wave_input(L):
-        rows = [bytes(rng.integers(32, 127, L, dtype=np.uint8))
-                for _ in range(LIVE)]
-        return pad_rows(rows + [b""] * (ROWS - LIVE), max_len=L, round_to=L)
+    def wave_input(L, rows, live):
+        data = [bytes(rng.integers(32, 127, L, dtype=np.uint8))
+                for _ in range(live)]
+        return pad_rows(data + [b""] * (rows - live), max_len=L, round_to=L)
 
-    def resident(L):
-        tokens, lengths = (jax.device_put(x) for x in wave_input(L))
-        state = jax.device_put(np.zeros((ROWS, W), np.uint32))
-        match = jax.device_put(np.zeros((ROWS, W), np.uint32))
+    def resident(L, rows, live):
+        tokens, lengths = (jax.device_put(x)
+                           for x in wave_input(L, rows, live))
+        state = jax.device_put(np.zeros((rows, W), np.uint32))
+        match = jax.device_put(np.zeros((rows, W), np.uint32))
         jax.block_until_ready((tokens, lengths, state, match))
         t0 = time.perf_counter()
         for _ in range(TOTAL // L):
@@ -72,9 +81,9 @@ def main() -> None:
         jax.block_until_ready((match, state))
         return (time.perf_counter() - t0) * 1e3
 
-    def launched(L):
-        tokens, lengths = wave_input(L)
-        state = np.zeros((ROWS, W), np.uint32)
+    def launched(L, rows, live):
+        tokens, lengths = wave_input(L, rows, live)
+        state = np.zeros((rows, W), np.uint32)
         match = np.zeros_like(state)
         t0 = time.perf_counter()
         in_calls = 0.0
@@ -101,12 +110,15 @@ def main() -> None:
         engine.scan(st.flush())
         return (time.perf_counter() - t0) * 1e3
 
-    for L in WIDTHS:
-        resident(L)
-        ms = min(resident(L) for _ in range(3))
-        out["resident L=%d" % L] = {"ms": round(ms, 2),
-                                    "us_per_step": round(ms * 1e3 / TOTAL, 3)}
-        print("resident", L, out["resident L=%d" % L], flush=True)
+    for rows in args.rows:
+        live = args.live or rows
+        for L in args.widths:
+            resident(L, rows, live)
+            ms = min(resident(L, rows, live) for _ in range(3))
+            key = "resident rows=%d live=%d L=%d" % (rows, live, L)
+            out[key] = {"ms": round(ms, 2),
+                        "us_per_step": round(ms * 1e3 / TOTAL, 3)}
+            print(key, out[key], flush=True)
 
     stop = threading.Event()
 
@@ -121,13 +133,16 @@ def main() -> None:
         if mode != "alone":
             thread = threading.Thread(target=busy, daemon=True)
             thread.start()
-        for L in WIDTHS:
-            launched(L)
-            ms, in_calls = min(launched(L) for _ in range(3))
-            out["%s L=%d" % (mode, L)] = {
-                "ms": round(ms, 2), "ms_in_calls_into_jax": round(in_calls, 2),
-                "waves": TOTAL // L}
-            print(mode, L, out["%s L=%d" % (mode, L)], flush=True)
+        for rows in args.rows:
+            live = args.live or rows
+            for L in args.widths:
+                launched(L, rows, live)
+                ms, in_calls = min(launched(L, rows, live) for _ in range(3))
+                key = "%s rows=%d live=%d L=%d" % (mode, rows, live, L)
+                out[key] = {"ms": round(ms, 2),
+                            "ms_in_calls_into_jax": round(in_calls, 2),
+                            "waves": TOTAL // L}
+                print(key, out[key], flush=True)
         engine_ms()
         ts = sorted(engine_ms() for _ in range(5))
         out["%s engine 90000 B" % mode] = {
