@@ -155,6 +155,10 @@ class PipelineStats:
     confirm_hangs: int = 0
     confirm_memo_hits: int = 0
     confirm_memo_misses: int = 0
+    # the cross-cycle verdict cache's (--confirm-cache), where it stands
+    # in for the memo: candidate-rule lookups it answered / walked
+    confirm_cache_hits: int = 0
+    confirm_cache_misses: int = 0
 
     #: the admission-shared counters (fail_open / degraded / shed /
     #: scorer_diff) are bumped from every thread that can fail a
@@ -1421,10 +1425,17 @@ class DetectionPipeline:
                 cand_hits, all_confirmed, all_blocked,
                 confirmed_rows=confirmed_rows,
                 rule_ns=(ridx_all, rns_all) if ridx_all else None)
+        hits, misses = cjob.memo_hits, cjob.memo_misses
         if cjob.memo is not None:
-            stats.confirm_memo_hits += cjob.memo.hits
-            stats.confirm_memo_misses += cjob.memo.misses
-        stats.confirm_memo_hits += cjob.memo_hits
-        stats.confirm_memo_misses += cjob.memo_misses
+            hits += cjob.memo.hits
+            misses += cjob.memo.misses
+        # under --confirm-cache every walk, inline or in a walker, looks
+        # up the cache in place of a per-cycle memo
+        if self.confirm_cache is not None:
+            stats.confirm_cache_hits += hits
+            stats.confirm_cache_misses += misses
+        else:
+            stats.confirm_memo_hits += hits
+            stats.confirm_memo_misses += misses
         stats.confirmed_rule_hits += sum(len(v.rule_ids) for v in verdicts)
         return verdicts

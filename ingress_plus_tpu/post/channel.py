@@ -19,6 +19,7 @@ from ingress_plus_tpu.post.export import Exporter
 from ingress_plus_tpu.post.queue import Hit, HitQueue
 from ingress_plus_tpu.post.topk import SpaceSaving
 from ingress_plus_tpu.serve.normalize import Request
+from ingress_plus_tpu.utils.trace import EV_POST_RECORD, Histogram, flight
 
 _CLIENT_HEADERS = ("x-real-ip", "x-forwarded-for", "x-client-ip")
 
@@ -52,6 +53,9 @@ class PostChannel:
         # shed/degraded verdicts — the tenant-guard's quarantine and
         # fair-admission decisions, visible to postanalytics
         self.top_shed_tenants = SpaceSaving(capacity=32)
+        #: the `post_record` stage: one request's record, on the event
+        #: loop (``ipt_stage_us{stage="post_record"}``)
+        self.record_hist = Histogram()
         self.exporter = Exporter(
             self.queue, spool_dir=spool_dir, http_url=http_url,
             interval_s=interval_s,
@@ -61,6 +65,7 @@ class PostChannel:
             on_export=self.counters.record_export_events)
 
     def record(self, request: Request, verdict) -> None:
+        t0_ns = time.monotonic_ns()
         degraded = bool(getattr(verdict, "degraded", False))
         self.counters.record(
             attack=verdict.attack, blocked=verdict.blocked,
@@ -87,6 +92,9 @@ class PostChannel:
             # lightweight stubs) — matches/elapsed are optional there
             elapsed_us=int(getattr(verdict, "elapsed_us", 0)),
             matches=tuple(getattr(verdict, "matches", ()))))
+        t1_ns = time.monotonic_ns()
+        flight.span_at(EV_POST_RECORD, t0_ns, t1_ns, cycle=0)
+        self.record_hist.observe((t1_ns - t0_ns) // 1000)
 
     def start(self) -> None:
         self.exporter.start()

@@ -32,7 +32,7 @@ from ingress_plus_tpu.post.aggregate import aggregate_attacks
 from ingress_plus_tpu.post.brute import BruteDetector
 from ingress_plus_tpu.post.queue import HitQueue
 from ingress_plus_tpu.utils import faults
-from ingress_plus_tpu.utils.trace import EV_EXPORT, flight
+from ingress_plus_tpu.utils.trace import EV_POST_EXPORT, Histogram, flight
 
 
 class Exporter:
@@ -76,6 +76,9 @@ class Exporter:
         self.spool_dropped_files = 0
         self.spool_dropped_bytes = 0
         self.spool_dropped_records = 0
+        #: the `post_export` stage: one flush of the exporter thread,
+        #: drain to delivery (``ipt_stage_us{stage="post_export"}``)
+        self.flush_hist = Histogram()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         if self.spool_dir:
@@ -204,14 +207,16 @@ class Exporter:
         wait = self.interval_s
         flight.register_thread("exporter")
         while not self._stop.wait(wait):
-            flight.begin(EV_EXPORT, cycle=0)
-            try:
-                self.flush_once()
-            except Exception:
-                self.export_errors += 1
-                self.consecutive_failures += 1
-            finally:
-                flight.end(EV_EXPORT, cycle=0)
+            # the flush holds the interpreter lock beside the serve
+            # threads for as long as it aggregates: a span of its own
+            with flight.span(EV_POST_EXPORT, cycle=0) as sp:
+                try:
+                    self.flush_once()
+                except Exception:
+                    self.export_errors += 1
+                    self.consecutive_failures += 1
+            if flight.enabled:
+                self.flush_hist.observe(sp.us)
             wait = self.next_wait_s()
             self.backoff_s = wait if self.consecutive_failures else 0.0
 

@@ -153,6 +153,16 @@ METRIC_HELP = {
     "ipt_confirm_regex_evals_total": "confirm re.search evaluations",
     "ipt_confirm_memo_hits_total": "per-cycle flood-memo hits",
     "ipt_confirm_memo_misses_total": "per-cycle flood-memo misses",
+    "ipt_confirm_cache_total":
+        "cross-cycle verdict cache lookups of candidate rules, by "
+        "result, summed over the walker processes and the inline walk "
+        "(--confirm-cache only)",
+    "ipt_post_hits_total":
+        "requests recorded to postanalytics (every answered request)",
+    "ipt_post_dropped_total":
+        "postanalytics hits the full queue dropped, oldest first",
+    "ipt_post_attacks_exported_total":
+        "attack records postanalytics delivered to its sink",
     "ipt_tenant_queue_depth": "per-tenant fair-queue depth",
     "ipt_tenant_admitted_total": "requests admitted per tenant",
     "ipt_tenant_shed_total": "requests shed per tenant",
@@ -811,6 +821,12 @@ class ServeLoop:
             for stage, hist in (*self.batcher.subhist.items(),
                                 *self.batcher.sidehist.items()):
                 lines += hist.prometheus("ipt_stage_us", {"stage": stage})
+            if self.post is not None:
+                for stage, hist in (
+                        ("post_record", self.post.record_hist),
+                        ("post_export", self.post.exporter.flush_hist)):
+                    lines += hist.prometheus("ipt_stage_us",
+                                             {"stage": stage})
             # the same spans' thread CPU: sum less CPU is time off the
             # CPU (the interpreter lock, a blocking call, preemption)
             lines.append("# TYPE ipt_stage_cpu_us_total counter")
@@ -877,8 +893,18 @@ class ServeLoop:
             'ipt_confirm_regex_evals_total{version="%s"} %d'
             % (pipeline.rule_stats.version, qr["regex_evals"]),
         ]
+        if getattr(pipeline, "confirm_cache", None) is not None:
+            lines += [
+                "# TYPE ipt_confirm_cache_total counter",
+                'ipt_confirm_cache_total{result="hit"} %d'
+                % p.confirm_cache_hits,
+                'ipt_confirm_cache_total{result="miss"} %d'
+                % p.confirm_cache_misses,
+            ]
         if self.post is not None:
             lines += [
+                "# TYPE ipt_post_hits_total counter",
+                "ipt_post_hits_total %d" % self.post.queue.total,
                 "# TYPE ipt_post_queue_depth gauge",
                 "ipt_post_queue_depth %d" % len(self.post.queue),
                 "# TYPE ipt_post_dropped_total counter",

@@ -776,7 +776,7 @@ EV_OVERSIZED = 12  # oversized side-lane batch scan, tag=requests, arg=body byte
 EV_SUBMIT = 13     # instant: admission, tag=req-id hash, arg=tenant
 EV_VERDICT = 14    # instant: verdict resolved, tag=req-id hash, arg=lane
 EV_SHADOW = 15     # shadow-lane candidate scan (shadow thread)
-EV_EXPORT = 16     # postanalytics export flush attempt
+EV_POST_EXPORT = 16  # postanalytics: one export flush (drain, aggregate, brute, deliver)
 EV_WATCHDOG = 17   # instant: watchdog released futures, arg=count
 EV_SCAN_PACK = 18  # pad/pack of rows into tier buckets (host), arg=rows
 EV_SCAN_LAUNCH = 19  # host→device transfers + enqueue of every program
@@ -798,6 +798,7 @@ EV_SIDE_LAUNCH = 34  # side lane: one wave's call into JAX (scan_bytes_jit)
 EV_SIDE_FETCH = 35  # side lane: a scan call's copies of match and state back
 EV_SIDE_LOCK_WAIT = 36  # side lane: asking for the swap lock -> holding it
 EV_SIDE_WALK = 37  # side lane: the walker's own walk (back-dated, ring only)
+EV_POST_RECORD = 38  # postanalytics: one request's record (back-dated, ring only)
 
 EVENT_NAMES: Dict[int, str] = {
     EV_CYCLE: "cycle", EV_DRAIN: "drain_idle", EV_QUEUE: "queue_wait",
@@ -806,7 +807,7 @@ EVENT_NAMES: Dict[int, str] = {
     "confirm_walk", EV_FINALIZE: "finalize_join", EV_MIRROR: "mirror",
     EV_STREAM: "stream_step", EV_OVERSIZED: "oversized",
     EV_SUBMIT: "submit", EV_VERDICT: "verdict", EV_SHADOW: "shadow_scan",
-    EV_EXPORT: "export", EV_WATCHDOG: "watchdog_release",
+    EV_POST_EXPORT: "post_export", EV_WATCHDOG: "watchdog_release",
     EV_SCAN_PACK: "scan_pack", EV_SCAN_LAUNCH: "scan_launch",
     EV_SCAN_WAIT: "scan_wait", EV_CONFIRM_FOLD: "confirm_fold",
     EV_HANDOFF: "lane_handoff", EV_REPLY: "reply", EV_GC: "gc",
@@ -816,7 +817,7 @@ EVENT_NAMES: Dict[int, str] = {
     EV_SIDE_CONFIRM: "side_confirm", EV_SIDE_LOCK: "side_lock",
     EV_SIDE_PREFILTER: "side_prefilter", EV_SIDE_LAUNCH: "side_launch",
     EV_SIDE_FETCH: "side_fetch", EV_SIDE_LOCK_WAIT: "side_lock_wait",
-    EV_SIDE_WALK: "side_walk",
+    EV_SIDE_WALK: "side_walk", EV_POST_RECORD: "post_record",
 }
 
 #: span codes whose elapsed µs accumulate per cycle id for the batcher's
